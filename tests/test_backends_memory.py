@@ -27,6 +27,71 @@ def test_device_auto_and_benchmark():
     assert gflops > 0
 
 
+def test_tpu_backend_needs_the_tpu_and_auto_says_what_it_found():
+    """An explicit ``tpu`` never degrades, and ``auto`` answers from the
+    one question the kernels ask too (``backends.on_tpu``)."""
+    from veles_tpu import backends
+    assert not backends.on_tpu()            # tests run on the CPU
+    with pytest.raises(RuntimeError, match="default platform is 'cpu'"):
+        Device(backend="tpu")
+    assert backends.AutoDevice.pick() == "cpu"
+
+
+def test_auto_device_does_not_swallow_a_jax_that_cannot_start(
+        monkeypatch):
+    from veles_tpu import backends
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+    monkeypatch.setattr(backends, "on_tpu", broken)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        Device(backend="auto")
+
+
+def test_caches_are_placed_from_outside_or_in_the_checkout(
+        monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR places every cache and nothing sets
+    another JAX cache directory; unset, everything lies under the
+    checkout's fixed .cache/."""
+    import os
+    import jax
+    from veles_tpu import backends
+    from veles_tpu.config import root
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv(backends.CACHE_DIR_ENV, raising=False)
+    assert backends.cache_root() == os.path.join(repo, ".cache")
+    assert backends.cache_dir("veles_autotune") == os.path.join(
+        repo, ".cache", "veles_autotune")
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv(backends.CACHE_DIR_ENV, placed)
+    assert backends.cache_root() == placed
+    assert backends.cache_dir("veles_executables") == os.path.join(
+        placed, "veles_executables")
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(root.common.engine, "compilation_cache_dir",
+                        str(tmp_path / "elsewhere"), raising=False)
+    try:
+        assert backends.apply_compilation_cache_config() == placed
+        assert jax.config.jax_compilation_cache_dir == before
+        assert not (tmp_path / "elsewhere").exists()
+    finally:
+        root.common.engine.compilation_cache_dir = None
+
+
+def test_children_sharing_the_tpu_are_refused_at_start():
+    """Several local children on a TPU host cannot work (one process
+    per chip, no chip assignment yet): the launchers ask this before
+    they spawn, and get that sentence instead of a hang.  Any number of
+    CPU children is fine."""
+    from veles_tpu.backends import refuse_children_sharing_the_tpu
+    refuse_children_sharing_the_tpu(8, "test", {"JAX_PLATFORMS": "cpu"})
+    refuse_children_sharing_the_tpu(1, "test", {"JAX_PLATFORMS": "tpu"})
+    with pytest.raises(RuntimeError,
+                       match="a TPU chip belongs to one process"):
+        refuse_children_sharing_the_tpu(2, "test",
+                                        {"JAX_PLATFORMS": "tpu,cpu"})
+
+
 def test_numpy_device():
     dev = NumpyDevice()
     assert not dev.exists

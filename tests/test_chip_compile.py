@@ -1,0 +1,226 @@
+"""What the chip's compiler says, asked from a machine without the chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``), so every
+hand kernel of the main path is compiled at real widths for a v5e on
+every test run: a block shape the Pallas TPU lowering refuses, or a
+kernel that outgrows VMEM, fails here and costs no chip time.  Interpret
+mode cannot see either.  A compile that passes is not a chip run
+(``chip_smoke.py`` is); nothing here executes.
+
+The last test rehearses ``chip_smoke.py`` itself on the CPU at tiny
+sizes: its whole control flow runs, and it must end in failure, because
+only a TPU may make it say ok.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from veles_tpu import backends  # noqa: E402
+from veles_tpu.znicz import (flash_attention as fa, gemm, lrn,  # noqa: E402
+                             paged_attention as pa)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+try:
+    _TOPOLOGY = topologies.get_topology_desc(platform="tpu",
+                                             topology_name="v5e:2x2")
+except Exception as exc:  # noqa: BLE001 — no libtpu, no description
+    _TOPOLOGY, _WHY_NOT = None, "%s: %s" % (type(exc).__name__, exc)
+
+needs_topology = pytest.mark.skipif(
+    _TOPOLOGY is None,
+    reason="the v5e:2x2 topology cannot be described here"
+           + ("" if _TOPOLOGY is not None else " (%s)" % _WHY_NOT))
+
+
+@pytest.fixture()
+def v5e(monkeypatch):
+    """One described v5e chip; kernels out of interpret mode; JAX's
+    persistent cache off (an entry compiled for a described chip is
+    written but cannot be read back without one, and warns)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(backends, "on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(_TOPOLOGY.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _compile(fn, *structs):
+    """The compiled program's text; raises what the chip's compiler
+    would raise."""
+    text = jax.jit(fn).lower(*structs).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+# (tag, B, H, D, block_size, max_blocks, pool dtype): the smoke server's
+# geometry (chip_smoke.py DECODE_GEOMETRY over the d256/8-head flagship)
+# and one a deployment would run (16 heads of 128, 128-token pages)
+_PAGED = [("smoke-f32", 16, 8, 32, 16, 16, jnp.float32),
+          ("smoke-int8", 16, 8, 32, 16, 16, jnp.int8),
+          ("real-bf16", 16, 16, 128, 128, 16, jnp.bfloat16)]
+
+
+@needs_topology
+@pytest.mark.parametrize("entry", ["decode", "prefill", "verify"])
+@pytest.mark.parametrize("geometry", _PAGED, ids=[g[0] for g in _PAGED])
+def test_paged_attention_compiles_for_v5e(v5e, geometry, entry):
+    _, b, h, d, bs, nb, dtype = geometry
+    quantized = dtype == jnp.int8
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    n_pool = b * nb + 1
+    qdt = jnp.float32 if quantized else dtype
+    pool = s((n_pool, bs, h, d), dtype)
+    scales = [s((n_pool, h), jnp.float32)] * 2 if quantized else []
+
+    def kw(*sc):
+        return dict(zip(("k_scales", "v_scales"), sc))
+    table, lengths = s((b, nb), jnp.int32), s((b,), jnp.int32)
+    scalar = s((), jnp.int32)
+    if entry == "decode":
+        _compile(lambda q, k, v, t, n, *sc: pa.paged_attention(
+            q, k, v, t, n, **kw(*sc)),
+            s((b, h, d), qdt), pool, pool, table, lengths, *scales)
+    elif entry == "prefill":
+        _compile(lambda q, k, v, row, start, n, *sc:
+                 pa.paged_prefill_attention(q, k, v, row, start, n,
+                                            **kw(*sc)),
+                 s((32, h, d), qdt), pool, pool, s((nb,), jnp.int32),
+                 scalar, scalar, *scales)
+    else:
+        _compile(lambda q, k, v, t, n, *sc: pa.paged_verify_attention(
+            q, k, v, t, n, **kw(*sc)),
+            s((b, 4, h, d), qdt), pool, pool, table, lengths, *scales)
+
+
+# (B, T, H, D, dtype, window)
+_FLASH = [(2, 2048, 8, 64, jnp.float32, None),
+          (2, 2048, 8, 64, jnp.bfloat16, None),
+          (1, 4096, 16, 128, jnp.bfloat16, None),
+          (1, 16384, 8, 64, jnp.float32, 512)]
+
+
+@needs_topology
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize(
+    "shape", _FLASH,
+    ids=["B%d-T%d-H%d-D%d-%s-w%s" % (b, t, h, d, jnp.dtype(dt).name, w)
+         for b, t, h, d, dt, w in _FLASH])
+def test_flash_attention_compiles_for_v5e(v5e, shape, grad):
+    b, t, h, d, dtype, window = shape
+    q = jax.ShapeDtypeStruct((b, t, h, d), dtype, sharding=v5e)
+
+    def attend(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window)
+    if not grad:
+        _compile(attend, q, q, q)
+        return
+    text = _compile(jax.grad(
+        lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), q, q, q)
+    # forward, dq and dk/dv kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+@needs_topology
+@pytest.mark.parametrize("level", [0, 1, 2])
+def test_precise_matmul_compiles_for_v5e(v5e, level):
+    a = jax.ShapeDtypeStruct((1024, 1024), jnp.float32, sharding=v5e)
+    _compile(lambda a, b: gemm.precise_matmul(a, b, level, False), a, a)
+
+
+@needs_topology
+def test_quantized_matmul_compiles_for_v5e(v5e):
+    a = jax.ShapeDtypeStruct((1024, 1024), jnp.float32, sharding=v5e)
+    w = jax.ShapeDtypeStruct((1024, 1024), jnp.int8, sharding=v5e)
+    s = jax.ShapeDtypeStruct((1024,), jnp.float32, sharding=v5e)
+    _compile(lambda a, w, s: gemm.quantized_matmul(a, w, s,
+                                                   interpret=False),
+             a, w, s)
+
+
+@needs_topology
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+def test_pallas_lrn_compiles_for_v5e(v5e, grad):
+    x = jax.ShapeDtypeStruct((128, 55, 55, 96), jnp.float32, sharding=v5e)
+
+    def run(x):
+        return lrn.pallas_lrn(x, 5, 1e-4, 0.75, 2.0)
+    _compile(jax.grad(lambda x: run(x).sum()) if grad else run, x)
+
+
+_REHEARSAL = """
+import sys
+sys.path.insert(0, %(repo)r)
+import chip_smoke as cs
+cs.ALEXNET.update(batch=4, side=67, n_classes=10, n_train=8, n_valid=4,
+                  epochs=1)
+cs.TRAINER_RUNS = (("fused", "float32"), ("scan", "bfloat16"))
+cs.FLAGSHIP.update(stages=1, experts=2, d=16, heads=2, hidden=32, vocab=32)
+cs.DECODE_GEOMETRY.update(max_batch=2, block_size=4, max_prompt_len=4,
+                          max_new_tokens=4)
+cs.DECODE_REQUESTS = ((3, 4), (4, 2))
+cs.RING.update(t=32, heads=1, d=8)
+cs.FLASH_WINDOW = 8
+cs.MNIST.update(max_batch=2, requests=(1, 2))
+sys.exit(cs.run(backend="cpu"))
+"""
+
+
+def test_chip_smoke_rehearsal_on_cpu_runs_every_phase_and_fails():
+    """The control flow of ``chip_smoke.py`` end to end at tiny sizes on
+    the CPU: every phase does its work and passes its functional checks
+    (training lowers the loss, the servers answer over HTTP and agree
+    with their references), and the run still ends non-zero with
+    ``"ok": false``, on the device checks alone."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "-c", _REHEARSAL % {"repo": REPO}], env=env,
+        cwd=REPO, capture_output=True, text=True, timeout=600)
+    lines = proc.stdout.strip().splitlines()
+    assert proc.returncode == 1, (proc.returncode, proc.stderr[-2000:])
+    verdict = json.loads(lines[-1])
+    assert verdict == {"ok": False, "device": {
+        "platform": "cpu", "kind": verdict["device"]["kind"],
+        "count": verdict["device"]["count"]}}
+    phases = {ln.split()[0][len("phase="):]: ln for ln in lines
+              if ln.startswith("phase=")}
+    assert list(phases) == ["trainer", "kernels", "decode_server",
+                            "export_serve"]
+    for name, line in phases.items():
+        assert " FAILED " in line, line
+        failed = line.split(" failed=[", 1)[1]
+        # nothing functional failed: only "is it the TPU" checks did
+        assert "raised" not in failed, line
+        assert all("TPU device" in what or "tpu_custom_call" in what
+                   for what in failed.rstrip("]").split("; ")), line
+    assert "loss on a fixed minibatch fell" in phases["trainer"]
+    assert "served tokens are the teacher-forced reference's argmax" \
+        in phases["decode_server"]
+    assert "served predictions" in phases["export_serve"]
+    # and with nothing steered, the script stops before its first phase
+    gate = subprocess.run([sys.executable, os.path.join(REPO,
+                                                        "chip_smoke.py")],
+                          env=env, cwd=REPO, capture_output=True,
+                          text=True, timeout=120)
+    assert gate.returncode == 2
+    assert json.loads(gate.stdout.strip().splitlines()[-1])["ok"] is False
+    assert "phase=" not in gate.stdout

@@ -192,20 +192,22 @@ def test_aot_step_matches_jit_and_keeps_interfaces(tmp_path):
     assert step2.cache_hit is True
 
 
-def test_aot_step_falls_back_on_any_surprise(tmp_path, monkeypatch):
+def test_aot_step_surfaces_a_failed_compile(tmp_path, monkeypatch):
+    """A step that cannot be compiled raises: a quiet second path
+    through plain jit would hide a program the device refused."""
     import jax
     cache = cc.CompileCache(str(tmp_path))
 
     def boom(*a, **k):
-        raise RuntimeError("cache exploded")
+        raise RuntimeError("compiler refused")
 
     monkeypatch.setattr(cache, "get_or_compile", boom)
-    jitted = jax.jit(lambda x: x * 2)
-    step = cc.AotStep(jitted, cache, "test.step")
+    step = cc.AotStep(jax.jit(lambda x: x * 2), cache, "test.step")
     x = numpy.arange(4, dtype=numpy.float32)
-    numpy.testing.assert_allclose(numpy.asarray(step(x)), x * 2)
-    assert step._fallback                       # one-way, permanent
-    numpy.testing.assert_allclose(numpy.asarray(step(x)), x * 2)
+    with pytest.raises(RuntimeError, match="compiler refused"):
+        step(x)
+    with pytest.raises(RuntimeError, match="compiler refused"):
+        step(x)                                 # and keeps raising
 
 
 # -- serving scheduler integration -------------------------------------------

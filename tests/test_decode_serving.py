@@ -81,6 +81,25 @@ def test_generate_matches_cachefree_oracle(scheduler, oracle):
         assert result["ttft_s"] > 0
 
 
+def test_reference_logits_is_the_oracle_teacher_forced(model, oracle):
+    """``reference_logits`` over prompt + oracle tokens: the argmax of
+    row ``t`` is the oracle's token after ``tokens[:t + 1]``, and a row
+    never sees what follows it — so one padded call checks a whole
+    answer (what ``chip_smoke.py`` does on the chip)."""
+    from veles_tpu.znicz.samples.flagship import reference_logits
+    prompt, n = [5, 1, 30, 7], 6
+    tokens = oracle(prompt, n)
+    seq = prompt + tokens
+    logits = numpy.asarray(reference_logits(model.params, seq))
+    assert logits.shape == (len(seq), model.vocab)
+    served = logits[len(prompt) - 1:-1].argmax(axis=-1)
+    assert served.tolist() == tokens
+    padded = numpy.asarray(reference_logits(model.params,
+                                            seq + [0, 9, 3]))
+    numpy.testing.assert_allclose(padded[:len(seq)], logits, rtol=1e-5,
+                                  atol=1e-5)
+
+
 def test_zero_steady_state_recompiles(scheduler):
     """compiles is flat across waves of ragged traffic — one warm
     executable serves every admit/retire mix."""

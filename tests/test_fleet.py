@@ -35,6 +35,18 @@ from veles_tpu.fleet import Fleet
 
 # -- RestartBackoff (shared respawn policy) -----------------------------------
 
+def test_supervisor_refuses_replicas_that_would_share_the_tpu():
+    """N replicas, one chip, no chip assignment (ROADMAP R6): start()
+    says so before it spawns anything, instead of N children hanging
+    on a device the first one holds."""
+    from veles_tpu.fleet.supervisor import ReplicaSupervisor
+    sup = ReplicaSupervisor({"lat": "sleep:0.001"}, replicas=2,
+                            env=dict(os.environ, JAX_PLATFORMS="tpu"))
+    with pytest.raises(RuntimeError, match="more children than chips"):
+        sup.start()
+    assert all(h.proc is None for h in sup._replicas.values())
+
+
 def test_restart_backoff_schedule_deterministic():
     """base·factor^streak, capped, budget-bounded — rng pinned to the
     midpoint so jitter contributes exactly nothing."""

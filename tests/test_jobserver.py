@@ -182,6 +182,16 @@ def test_worker_pool_custom_command_template():
         master.close()
 
 
+def test_worker_pool_refuses_workers_that_would_share_the_tpu():
+    """`--workers N` on a TPU host: N trial processes, one chip.  The
+    pool refuses at start; a remote command template is not this
+    host's business and is left alone (previous test)."""
+    import pytest
+    with pytest.raises(RuntimeError, match="more children than chips"):
+        WorkerPool(("127.0.0.1", 1), n=2,
+                   env=dict(os.environ, JAX_PLATFORMS="tpu"))
+
+
 def test_execute_payload_unknown_kind():
     out = execute_payload({"kind": "nope"})
     assert out["rc"] == -2 and "unknown payload kind" in out["error"]

@@ -157,13 +157,9 @@ def test_quantized_matmul_bitwise_vs_staged_oracle():
         quantize_weight(w, "int4")
 
 
-def test_fp8_weight_path_gated_on_jaxlib():
+def test_fp8_weight_path():
     w = jnp.asarray(numpy.random.default_rng(4)
                     .standard_normal((8, 8)), jnp.float32)
-    if fp8_dtype() is None:
-        with pytest.raises(ValueError, match="float8"):
-            quantize_weight(w, "fp8")
-        return
     w_q, scales = quantize_weight(w, "fp8")
     assert w_q.dtype == fp8_dtype()
     out = quantized_matmul(

@@ -147,7 +147,11 @@ def write_idx_gz(path, arr):
     header = struct.pack(">I", (code << 8) | len(dims))
     header += struct.pack(">" + "I" * len(dims), *dims)
     with open(path, "wb") as raw:
-        with gzip.GzipFile(fileobj=raw, mode="wb", mtime=0) as f:
+        # level 8, not the default 9: level 9's byte stream for the test
+        # images happens to contain a run that whole-word text searches
+        # of the tree (grep -rniw) report as a match in a binary file
+        with gzip.GzipFile(fileobj=raw, mode="wb", compresslevel=8,
+                           mtime=0) as f:
             f.write(header)
             f.write(arr.tobytes())
 

@@ -42,6 +42,8 @@ import numpy
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from veles_tpu.backends import cache_dir as fixed_cache_dir  # noqa: E402
+
 DEFAULT_SIZES = (1, 2, 3, 5, 8)
 
 
@@ -1050,8 +1052,7 @@ def run_fleet_bench(replicas=3, clients=None, seconds=2.0,
         tmp = tempfile.mkdtemp(prefix="fleet_bench_")
         package = build_mnist_package(os.path.join(tmp, "mnist_pkg.zip"))
     if cache_dir is None:
-        cache_dir = os.path.join(tmp or tempfile.mkdtemp(
-            prefix="fleet_bench_"), "compile_cache")
+        cache_dir = fixed_cache_dir("veles_executables")
     from veles_tpu.export.loader import PackageLoader
     sample_shape = tuple(PackageLoader(package)
                          .model_metadata["input"]["sample_shape"])
@@ -1186,7 +1187,6 @@ def run_fleet_prefix_bench(replicas=2, users=None, seconds=5.0,
     across replicas so each set fits.  Both phases run a FRESH fleet
     over the same compile cache; the bar is affinity beating baseline
     on BOTH the prefix-hit rate and TTFT p99."""
-    import shutil
     from veles_tpu.fleet import Fleet
     from veles_tpu.kvtier import PREFIX_HEADER, prefix_key_header
     from veles_tpu.serving.toydecode import ToyDecodeModel
@@ -1196,10 +1196,8 @@ def run_fleet_prefix_bench(replicas=2, users=None, seconds=5.0,
     spec = ("toydecode:vocab=97,pdelay=0.002,max_batch=4,block=%d,"
             "max_prompt=16,max_new=8,chunk=8,prefix=1,num_blocks=%d,"
             "tier_host=%d" % (block, num_blocks, 32 << 20))
-    tmp = None
     if cache_dir is None:
-        tmp = tempfile.mkdtemp(prefix="fleet_prefix_")
-        cache_dir = os.path.join(tmp, "compile_cache")
+        cache_dir = fixed_cache_dir("veles_executables")
     # distinct 8-token system prefixes (2 full blocks each)
     prefixes = [[(7 * u + j) % 97 for j in range(8)]
                 for u in range(users)]
@@ -1278,26 +1276,22 @@ def run_fleet_prefix_bench(replicas=2, users=None, seconds=5.0,
     out = {"fp_replicas": replicas, "fp_users": users,
            "fp_offered_rps": offered_rps, "fp_seconds": seconds,
            "fp_num_blocks": num_blocks}
-    try:
-        for mode, res in (("baseline", phase(False)),
-                          ("affinity", phase(True))):
-            q = _quantiles_ms(res["ttfts"])
-            served = max(res["ok"], 1)
-            out["fp_%s_ok" % mode] = res["ok"]
-            out["fp_%s_shed" % mode] = res["shed"]
-            out["fp_%s_failed" % mode] = res["failed"]
-            out["fp_%s_mismatch" % mode] = res["mismatch"]
-            out["fp_%s_prefix_hits" % mode] = res["prefix_hits"]
-            out["fp_%s_hit_rate" % mode] = round(
-                res["prefix_hits"] / served, 4)
-            out["fp_%s_ttft_p50_ms" % mode] = q.get("p50_ms")
-            out["fp_%s_ttft_p99_ms" % mode] = q.get("p99_ms")
-            out["fp_%s_affinity_hits" % mode] = res["affinity_hits"]
-            out["fp_%s_affinity_fallbacks" % mode] = \
-                res["affinity_fallbacks"]
-    finally:
-        if tmp:
-            shutil.rmtree(tmp, ignore_errors=True)
+    for mode, res in (("baseline", phase(False)),
+                      ("affinity", phase(True))):
+        q = _quantiles_ms(res["ttfts"])
+        served = max(res["ok"], 1)
+        out["fp_%s_ok" % mode] = res["ok"]
+        out["fp_%s_shed" % mode] = res["shed"]
+        out["fp_%s_failed" % mode] = res["failed"]
+        out["fp_%s_mismatch" % mode] = res["mismatch"]
+        out["fp_%s_prefix_hits" % mode] = res["prefix_hits"]
+        out["fp_%s_hit_rate" % mode] = round(
+            res["prefix_hits"] / served, 4)
+        out["fp_%s_ttft_p50_ms" % mode] = q.get("p50_ms")
+        out["fp_%s_ttft_p99_ms" % mode] = q.get("p99_ms")
+        out["fp_%s_affinity_hits" % mode] = res["affinity_hits"]
+        out["fp_%s_affinity_fallbacks" % mode] = \
+            res["affinity_fallbacks"]
     base_p99 = out.get("fp_baseline_ttft_p99_ms")
     aff_p99 = out.get("fp_affinity_ttft_p99_ms")
     out["fleet_prefix_hit_rate_gain"] = round(
@@ -1356,8 +1350,7 @@ def run_chaos_bench(replicas=3, package=None, offered_rps=40.0,
         tmp = tempfile.mkdtemp(prefix="chaos_bench_")
         package = build_mnist_package(os.path.join(tmp, "mnist_pkg.zip"))
     if cache_dir is None:
-        cache_dir = os.path.join(tmp or tempfile.mkdtemp(
-            prefix="chaos_bench_"), "compile_cache")
+        cache_dir = fixed_cache_dir("veles_executables")
     from veles_tpu.export.loader import PackageLoader
     sample_shape = tuple(PackageLoader(package)
                          .model_metadata["input"]["sample_shape"])

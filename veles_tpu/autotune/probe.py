@@ -559,6 +559,10 @@ def main(argv=None):
             out["score"] = round(out["cand_s"] / out["ref_s"], 4)
         out["cand_s"] = round(out.get("cand_s", 0.0), 6)
         out["ref_s"] = round(out.get("ref_s", 0.0), 6)
+        # the record's key: the tuner parent stays off JAX (one process
+        # per chip) and takes the environment from here
+        from veles_tpu.autotune.store import environment_fingerprint
+        out["fingerprint"] = environment_fingerprint()
     except Exception:  # noqa: BLE001 — the line must always print
         out = {"ok": False, "site": args.site, "config": config,
                "error": traceback.format_exc(limit=3).strip()[-500:]}

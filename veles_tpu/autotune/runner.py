@@ -6,6 +6,10 @@ pattern): a Mosaic compile that wedges, an OOM, or a crash kills one
 candidate, never the tuning run — and each candidate compiles in a
 pristine process so no warm JAX state flatters late candidates.
 
+The parent never initialises a JAX backend: a chip belongs to one
+process at a time, so each probe holds it in turn and reports the
+environment fingerprint its record is keyed by.
+
 Isolation is a full PROCESS GROUP: children start in their own session
 (``start_new_session=True``) and a timeout kills the whole group with
 SIGKILL — a hung Pallas compile, a SIGSTOP'd child, or a grandchild the
@@ -196,6 +200,7 @@ def tune_site(site, ctx=None, *, store=None, timeout=120.0, env=None,
             baseline_s=winner.get("ref_s"),
             best_s=winner.get("cand_s"),
             candidates_tried=len(results),
+            fingerprint=winner.get("fingerprint"),
             extra={"viable": len(viable),
                    "gate_failures": sum(
                        1 for r in results

@@ -124,9 +124,16 @@ class TuningStore:
     # -- write ---------------------------------------------------------------
     def put(self, site, shape_class, config, *, default, speedup,
             gate="passed", baseline_s=None, best_s=None,
-            candidates_tried=None, extra=None):
-        """Atomically persist a measured winner; returns the record."""
-        fingerprint = environment_fingerprint()
+            candidates_tried=None, extra=None, fingerprint=None):
+        """Atomically persist a measured winner; returns the record.
+
+        ``fingerprint`` is the environment the winner was MEASURED in,
+        as its probe reported it.  The tuner passes it so that it never
+        asks JAX for the devices itself: a chip belongs to one process,
+        and a parent that held it would starve the next site's probes.
+        None = this process's own environment."""
+        if fingerprint is None:
+            fingerprint = environment_fingerprint()
         env = dict(kv.split("=", 1) for kv in fingerprint.split(";")
                    if "=" in kv)
         record = {

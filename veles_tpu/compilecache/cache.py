@@ -19,8 +19,9 @@ the key and misses cleanly.
 :class:`AotStep` is the training-side adapter: a first-call AOT wrapper
 around a jitted step function that lowers against the concrete call's
 shapes, runs ``get_or_compile``, and executes the loaded executable
-thereafter — with a one-way fallback to the plain jit path on ANY
-surprise, so enabling the cache can never change training results.
+thereafter.  A bad cache ENTRY costs a recompile (above); a step that
+fails to lower, compile or run raises — there is no second path that
+could hide a program the device refused.
 """
 
 import logging
@@ -43,8 +44,9 @@ CACHE_DIR_ENV = "VELES_COMPILE_CACHE_DIR"
 MAX_BYTES_ENV = "VELES_COMPILE_CACHE_MAX_BYTES"
 
 #: store blob format version — bump on layout change (old entries then
-#: quarantine-and-recompile once, which is the upgrade path)
-_FORMAT = 1
+#: quarantine-and-recompile once, which is the upgrade path).
+#: 2: entries name the devices their executable runs on
+_FORMAT = 2
 
 
 class CompileCache:
@@ -106,9 +108,16 @@ class CompileCache:
             entry = pickle.loads(blob)
             if entry["format"] != _FORMAT or entry["key"] != key:
                 raise ValueError("entry format/key mismatch")
+            import jax
             from jax.experimental import serialize_executable
+            # load onto the devices the executable was compiled for:
+            # left to its default, deserialize_and_load spreads a
+            # one-device program over EVERY local device and its first
+            # call then fails ("expected N shards")
+            by_id = {d.id: d for d in jax.devices()}
             loaded = serialize_executable.deserialize_and_load(
-                *entry["exe"])
+                *entry["exe"],
+                execution_devices=[by_id[i] for i in entry["devices"]])
         except Exception as exc:  # noqa: BLE001 — ANY bad entry: miss
             self.store.quarantine(key, reason=str(exc)[:120])
             if key not in self._quarantined:
@@ -131,8 +140,10 @@ class CompileCache:
         try:
             from jax.experimental import serialize_executable
             exe = serialize_executable.serialize(compiled)
+            devices = [d.id for d in
+                       compiled.runtime_executable().local_devices()]
             blob = pickle.dumps({"format": _FORMAT, "key": key,
-                                 "name": str(name),
+                                 "name": str(name), "devices": devices,
                                  "compile_seconds":
                                      round(float(compile_seconds), 4),
                                  "exe": exe},
@@ -240,10 +251,9 @@ class AotStep:
     executable comes from :meth:`CompileCache.get_or_compile`, and
     every later call runs it directly.
 
-    Safety: on ANY failure — lowering, cache, or executing the loaded
-    executable — the wrapper permanently falls back to the wrapped
-    ``jax.jit`` function (logged once).  Enabling the cache can slow a
-    step down to exactly the old path, never change its result.
+    Lowering, compiling and running raise like the wrapped function
+    would: only a bad cache entry is absorbed, inside
+    :meth:`CompileCache.load_or_compile`, at the cost of a recompile.
 
     Interface parity with ``jax.jit`` functions where the codebase
     relies on it: ``__wrapped__`` (scan/mesh steps re-jit from the raw
@@ -257,7 +267,6 @@ class AotStep:
         self._name = name
         self._key_extra = key_extra
         self._compiled = None
-        self._fallback = False
         self.cache_hit = None       # None until the first call decides
         wrapped = getattr(jitted, "__wrapped__", None)
         if wrapped is not None:
@@ -304,19 +313,8 @@ class AotStep:
             key_extra=self._key_extra)
 
     def __call__(self, *args):
-        if not self._fallback:
-            try:
-                if self._compiled is None:
-                    self._ensure_compiled(args)
-                import jax
-                return self._compiled(
-                    *jax.tree_util.tree_map(self._leaf_harden, args))
-            except Exception as exc:  # noqa: BLE001 — never change
-                # results: hand the call to the plain jit path for good
-                self._fallback = True
-                self._compiled = None
-                log.warning("compile cache: AOT path for %r disabled "
-                            "(%s: %s); falling back to jax.jit",
-                            self._name, type(exc).__name__,
-                            str(exc)[:200])
-        return self._jitted(*args)
+        import jax
+        if self._compiled is None:
+            self._ensure_compiled(args)
+        return self._compiled(
+            *jax.tree_util.tree_map(self._leaf_harden, args))

@@ -34,6 +34,7 @@ import sys
 import threading
 import time
 
+from ..backends import refuse_children_sharing_the_tpu
 from ..compilecache import inject_env as _cache_inject_env
 from ..distributed import RestartBackoff
 from ..logger import events
@@ -190,6 +191,8 @@ class ReplicaSupervisor:
     def start(self):
         """Spawn every replica (concurrently — they warm in parallel)
         and register each with the router as it announces."""
+        refuse_children_sharing_the_tpu(
+            len(self._replicas), "fleet supervisor", self._child_env())
         with self._lock:
             for handle in self._replicas.values():
                 self._spawn(handle)

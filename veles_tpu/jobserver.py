@@ -39,6 +39,7 @@ import sys
 import threading
 import time
 
+from .backends import refuse_children_sharing_the_tpu
 from .observability import trace as _trace
 
 _SENTINEL_TIMEOUT = 0.1
@@ -392,6 +393,10 @@ class WorkerPool:
         self._cap_warned = False
         self._procs = [None] * n
         self._closing = threading.Event()
+        if not command:         # local workers; a remote template's
+            # hosts are not this one's
+            refuse_children_sharing_the_tpu(n, "jobserver worker pool",
+                                            env)
         for i in range(n):
             self._spawn(i)
         self._monitor = threading.Thread(target=self._watch, daemon=True,

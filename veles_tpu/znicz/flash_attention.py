@@ -37,9 +37,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import backends
+
 
 def _interpret():
-    return jax.default_backend() != "tpu"
+    return not backends.on_tpu()
 
 
 DEFAULT_BLOCK_Q = 256
@@ -193,7 +195,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             # per query row in HBM instead of a 128x lane broadcast (a
             # single in-VMEM relayout per Q block — negligible next to
             # the saved HBM write traffic)
-            lse_ref[0] = lse.reshape(block_q // _STAT_LANES, _STAT_LANES)
+            lse_ref[0, 0] = lse.reshape(block_q // _STAT_LANES,
+                                        _STAT_LANES)
         else:
             lse_ref[0] = jnp.broadcast_to(lse, (block_q, _STAT_LANES))
 
@@ -240,9 +243,14 @@ def _flash_fwd_bh(q, k, v, scale, causal, block_q, block_k, vma=None,
                         + j, 0, n_k - 1), 0)
     kspec = pl.BlockSpec((1, block_k, d), k_index)
     if compact:
-        lse_spec = pl.BlockSpec((1, block_q // _STAT_LANES, _STAT_LANES),
-                                lambda b, i, j: (b, i, 0))
-        lse_shape = (bh, t // _STAT_LANES, _STAT_LANES)
+        # one [block_q // 128, 128] slab per Q block, as the LAST TWO
+        # dims of a 4-D array: a block must either tile (8, 128) or
+        # span its array's last two dims, and 256 // 128 = 2 rows do
+        # neither inside a [BH, T // 128, 128] array
+        rows = block_q // _STAT_LANES
+        lse_spec = pl.BlockSpec((1, 1, rows, _STAT_LANES),
+                                lambda b, i, j: (b, i, 0, 0))
+        lse_shape = (bh, n_q, rows, _STAT_LANES)
     else:
         lse_spec = pl.BlockSpec((1, block_q, _STAT_LANES),
                                 lambda b, i, j: (b, i, 0))

@@ -26,7 +26,7 @@ compensation recovers.  ``jax.config`` keeps XLA's algebraic rewrites
 away from the compensation expressions (XLA does not reassociate floats
 by default).
 
-The jnp/XLA fallback for remote-compile backends stays in
+The jnp/XLA form of the same trade is
 ``backends.Device.PRECISION_LEVELS`` (the MXU pass-decomposition knob);
 this kernel is the opt-in exact-summation path
 (``root.common.engine.precise_gemm`` or ``All2All(precise_gemm=N)``).
@@ -37,9 +37,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import backends
+
 
 def _interpret_default():
-    return jax.default_backend() != "tpu"
+    return not backends.on_tpu()
 
 
 def _accumulate_plain(p, acc_ref, _c1_ref, _c2_ref):
@@ -157,10 +159,7 @@ def _matmul_impl(a, b, level, interpret, block_m=None, block_n=None,
         out_shape=jax.ShapeDtypeStruct(
             (a.shape[0], b.shape[1]), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] * 3,
-        # CompilerParams was TPUCompilerParams before jax 0.5
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams",
-                                        None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)
@@ -186,9 +185,8 @@ _FP8_E4M3_MAX = 448.0
 
 
 def fp8_dtype():
-    """The jaxlib's storage fp8 dtype, or None when this jaxlib has
-    none (callers gate the fp8 weight path on this)."""
-    return getattr(jnp, "float8_e4m3fn", None)
+    """The storage fp8 dtype of the weight path."""
+    return jnp.float8_e4m3fn
 
 
 def quantize_weight(w, dtype="int8"):
@@ -211,12 +209,8 @@ def quantize_weight(w, dtype="int8"):
         q = jnp.clip(jnp.round(w / scales[None, :]), -127, 127)
         return q.astype(jnp.int8), scales.astype(jnp.float32)
     if dtype == "fp8":
-        f8 = fp8_dtype()
-        if f8 is None:
-            raise ValueError(
-                "this jaxlib exposes no float8 dtype; use dtype='int8'")
         scales = jnp.where(amax > 0, amax / _FP8_E4M3_MAX, 1.0)
-        return (w / scales[None, :]).astype(f8), \
+        return (w / scales[None, :]).astype(fp8_dtype()), \
             scales.astype(jnp.float32)
     raise ValueError("unknown weight dtype %r (want 'int8'|'fp8')"
                      % (dtype,))
@@ -284,9 +278,7 @@ def quantized_matmul(a, w_q, scales, block_m=None, block_n=None,
         out_shape=jax.ShapeDtypeStruct(
             (a.shape[0], w_q.shape[1]), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams",
-                                        None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, w_q, s2)

@@ -25,10 +25,12 @@ canonical TPU paged-attention gather):
   sum block by block.
 
 K and V each cross HBM exactly once (same DMA bill as a fused single
-sweep), and because the softmax is dense the kernel is **bitwise equal
-to the dense reference** — no online-softmax rescale drift — which is
-what the tier-1 parity tests assert (interpret mode on CPU, compiled on
-TPU).  Blocks past a sequence's length are skipped entirely: compute
+sweep), and because the softmax is dense there is no online-softmax
+rescale drift: in interpret mode on the CPU the kernel reproduces the
+dense reference to the last place or close to it, which is what the
+tier-1 parity tests assert.  Compiled for the TPU the contract is a
+tolerance, not bit equality (``chip_smoke.py`` checks it there).  Blocks
+past a sequence's length are skipped entirely: compute
 AND DMA stay O(length), so a ragged batch costs its true token count,
 not ``B * max_context``.
 
@@ -38,13 +40,14 @@ trash block (never allocated to a live sequence).
 
 Quantized pools (ISSUE 18): the same entry points accept int8 K/V
 pools plus per-(block, head) f32 scale arrays (``k_scales``/``v_scales``,
-``[num_blocks]`` — one symmetric scale per PHYSICAL pool block).  The
-scales ride as two extra scalar-prefetch operands and each K/V tile is
-dequantized on the VMEM row right after its DMA (``int8 -> f32 *
-scale[pid]``), so HBM traffic on the hot loop is the int8 bytes; the
+``[num_blocks, H]`` — one symmetric scale per PHYSICAL pool block and
+head).  The scales ride as two extra blocked operands, fetched through
+the same page-table entry as their tile, and each K/V tile is
+dequantized on the VMEM slab right after its DMA (``int8 -> f32 *
+scale``), so HBM traffic on the hot loop is the int8 bytes; the
 dense-softmax structure, trash-block handling and page-table
 indirection are untouched, and the quantized dense reference stages the
-same dequant elementwise so parity stays bitwise.
+same dequant elementwise.
 """
 
 import functools
@@ -53,6 +56,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .. import backends
 
 __all__ = ["DEFAULT_BLOCK_SIZE", "paged_attention",
            "paged_attention_reference", "paged_prefill_attention",
@@ -70,7 +75,7 @@ DEFAULT_BLOCK_SIZE = 8
 
 
 def _interpret():
-    return jax.default_backend() != "tpu"
+    return not backends.on_tpu()
 
 
 def required_blocks(length, block_size):
@@ -110,13 +115,31 @@ def dequantize_pool(q, scales):
             * scales.astype(jnp.float32)[:, None, :, None])
 
 
-def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   s_scr, m_scr, l_scr, acc_scr, *, block_size,
-                   n_blocks, scale):
+def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+                   block_size, n_blocks, scale, quantized):
+    """One grid step = one K (sweep 1) or V (sweep 2) pool block of one
+    sequence, ALL heads at once: the blocks are ``[block_size, H, D]``
+    slabs, so their last two dims span the pool's and the TPU lowering
+    accepts them at any page size (a per-head ``(1, D)`` slice of the
+    ``(H, D)`` plane is refused: it neither tiles (8, 128) nor spans
+    it).  Scores live as ``[n_blocks, block_size, H]`` with heads on
+    lanes.  Over int8 pools the per-(block, head) scales arrive as two
+    more blocked operands, ``[H, 1]`` columns picked by the same page
+    table entry as their tile, and each tile is dequantized on the VMEM
+    slab right after its DMA."""
     from jax.experimental import pallas as pl
 
-    b, j = pl.program_id(0), pl.program_id(2)
+    if quantized:
+        ks_ref, vs_ref, o_ref, s_scr, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, s_scr, m_scr, l_scr, acc_scr = rest
+        ks_ref = vs_ref = None
+    b, j = pl.program_id(0), pl.program_id(1)
     length = len_ref[b]
+
+    def tile(ref, scale_ref):
+        x = ref[0].astype(jnp.float32)                    # [bs, H, D]
+        return x if scale_ref is None else x * scale_ref[0][None]
 
     @pl.when(j == 0)
     def _init():
@@ -125,105 +148,42 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # -- sweep 1 (j < n_blocks): score K blocks into the scratch row ---------
+    # -- sweep 1 (j < n_blocks): score K blocks into the scratch rows --------
     @pl.when(jnp.logical_and(j < n_blocks, j * block_size < length))
     def _score():
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # [D]
-        kb = k_ref[0, :, 0].astype(jnp.float32)           # [bs, D]
-        s = jnp.sum(q[None, :] * kb, axis=-1)             # [bs]
+        q = q_ref[0].astype(jnp.float32) * scale          # [H, D]
+        s = jnp.sum(q[None] * tile(k_ref, ks_ref), axis=-1)   # [bs, H]
         pos = j * block_size + lax.broadcasted_iota(
-            jnp.int32, (block_size, 1), 0)[:, 0]
+            jnp.int32, s.shape, 0)
         s = jnp.where(pos < length, s, _NEG_INF)
         s_scr[j] = s
-        m_scr[0, 0] = jnp.maximum(m_scr[0, 0], jnp.max(s))
+        m_scr[...] = jnp.maximum(m_scr[...],
+                                 jnp.max(s, axis=0, keepdims=True))
 
     # -- boundary: dense softmax over the whole scratch row ------------------
     @pl.when(j == n_blocks)
     def _normalize():
-        m = m_scr[0, 0]
+        m = m_scr[...]                                    # [1, H]
         safe_m = jnp.where(jnp.isneginf(m), 0.0, m)
-        p = jnp.where(jnp.isneginf(s_scr[...]), 0.0,
-                      jnp.exp(s_scr[...] - safe_m))
+        s = s_scr[...]
+        p = jnp.where(jnp.isneginf(s), 0.0, jnp.exp(s - safe_m[None]))
         s_scr[...] = p
-        l_scr[0, 0] = jnp.sum(p)
+        l_scr[...] = jnp.sum(jnp.sum(p, axis=0), axis=0, keepdims=True)
 
     # -- sweep 2 (j >= n_blocks): weighted V accumulation --------------------
     jv = j - n_blocks
 
     @pl.when(jnp.logical_and(j >= n_blocks, jv * block_size < length))
     def _accumulate():
-        vb = v_ref[0, :, 0].astype(jnp.float32)           # [bs, D]
-        p = s_scr[jv]                                     # [bs]
+        p = s_scr[jv]                                     # [bs, H]
         acc_scr[...] = acc_scr[...] + jnp.sum(
-            p[:, None] * vb, axis=0, keepdims=True)
+            p[:, :, None] * tile(v_ref, vs_ref), axis=0)
 
     @pl.when(j == 2 * n_blocks - 1)
     def _finish():
-        l = l_scr[0, 0]
+        l = l_scr[0]                                      # [H]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[0] / safe_l).astype(o_ref.dtype)
-
-
-def _decode_kernel_quant(pt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref,
-                         v_ref, o_ref, s_scr, m_scr, l_scr, acc_scr, *,
-                         block_size, n_blocks, scale):
-    """The decode kernel over int8 pools: identical sweep/softmax
-    structure, but each K/V tile is dequantized on the VMEM row right
-    after its DMA with the per-(block, head) scale read off the two
-    extra scalar-prefetch operands (``ks_ref``/``vs_ref``, indexed by
-    the PHYSICAL block id the page table routed this grid step to and
-    this grid step's head)."""
-    from jax.experimental import pallas as pl
-
-    b, hh, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    length = len_ref[b]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        s_scr[...] = jnp.full_like(s_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    # -- sweep 1: dequantize the K tile, score into the scratch row ----------
-    @pl.when(jnp.logical_and(j < n_blocks, j * block_size < length))
-    def _score():
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # [D]
-        kb = k_ref[0, :, 0].astype(jnp.float32) \
-            * ks_ref[pt_ref[b, j], hh]                    # [bs, D]
-        s = jnp.sum(q[None, :] * kb, axis=-1)             # [bs]
-        pos = j * block_size + lax.broadcasted_iota(
-            jnp.int32, (block_size, 1), 0)[:, 0]
-        s = jnp.where(pos < length, s, _NEG_INF)
-        s_scr[j] = s
-        m_scr[0, 0] = jnp.maximum(m_scr[0, 0], jnp.max(s))
-
-    # -- boundary: dense softmax over the whole scratch row ------------------
-    @pl.when(j == n_blocks)
-    def _normalize():
-        m = m_scr[0, 0]
-        safe_m = jnp.where(jnp.isneginf(m), 0.0, m)
-        p = jnp.where(jnp.isneginf(s_scr[...]), 0.0,
-                      jnp.exp(s_scr[...] - safe_m))
-        s_scr[...] = p
-        l_scr[0, 0] = jnp.sum(p)
-
-    # -- sweep 2: dequantize the V tile, weighted accumulation ---------------
-    jv = j - n_blocks
-
-    @pl.when(jnp.logical_and(j >= n_blocks, jv * block_size < length))
-    def _accumulate():
-        vb = v_ref[0, :, 0].astype(jnp.float32) \
-            * vs_ref[pt_ref[b, jv], hh]                   # [bs, D]
-        p = s_scr[jv]                                     # [bs]
-        acc_scr[...] = acc_scr[...] + jnp.sum(
-            p[:, None] * vb, axis=0, keepdims=True)
-
-    @pl.when(j == 2 * n_blocks - 1)
-    def _finish():
-        l = l_scr[0, 0]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[0] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / safe_l[:, None]).astype(o_ref.dtype)
 
 
 def _check_quant_args(k_pool, v_pool, k_scales, v_scales):
@@ -282,69 +242,52 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, scale=None,
     quantized = _check_quant_args(k_pool, v_pool, k_scales, v_scales)
     nb = page_table.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    scratch_shapes = [
-        pltpu.VMEM((nb, bs), jnp.float32),    # score / prob row
-        pltpu.VMEM((1, 1), jnp.float32),      # running max
-        pltpu.VMEM((1, 1), jnp.float32),      # softmax denominator
-        pltpu.VMEM((1, d), jnp.float32),      # output accumulator
-    ]
-    if quantized:
-        # the f32 structure with two extra scalar-prefetch operands
-        # (per-block K/V scales) and in-VMEM dequant after each DMA
-        kernel = functools.partial(_decode_kernel_quant, block_size=bs,
-                                   n_blocks=nb, scale=float(scale))
-        k_index = lambda b_, h_, j, pt, ln, ks, vs: (  # noqa: E731
-            pt[b_, jnp.minimum(j, nb - 1)], 0, h_, 0)
-        v_index = lambda b_, h_, j, pt, ln, ks, vs: (  # noqa: E731
-            pt[b_, jnp.clip(j - nb, 0, nb - 1)], 0, h_, 0)
-        q_index = lambda b_, h_, j, pt, ln, ks, vs: (  # noqa: E731
-            b_, h_, 0)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(b, h, 2 * nb),
-            in_specs=[
-                pl.BlockSpec((1, 1, d), q_index),
-                pl.BlockSpec((1, bs, 1, d), k_index),
-                pl.BlockSpec((1, bs, 1, d), v_index),
-            ],
-            out_specs=pl.BlockSpec((1, 1, d), q_index),
-            scratch_shapes=scratch_shapes,
-        )
-        return pl.pallas_call(
-            kernel, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-            interpret=_interpret(),
-        )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-          k_scales.astype(jnp.float32), v_scales.astype(jnp.float32),
-          q, k_pool, v_pool)
     kernel = functools.partial(_decode_kernel, block_size=bs,
-                               n_blocks=nb, scale=float(scale))
+                               n_blocks=nb, scale=float(scale),
+                               quantized=quantized)
     # index maps see the prefetched page table: sweep 1 follows it for
     # K, sweep 2 for V; the off-sweep operand pins to an already-mapped
     # block (clipped id) so no DMA reads out of range
-    k_index = lambda b_, h_, j, pt, ln: (  # noqa: E731
-        pt[b_, jnp.minimum(j, nb - 1)], 0, h_, 0)
-    v_index = lambda b_, h_, j, pt, ln: (  # noqa: E731
-        pt[b_, jnp.clip(j - nb, 0, nb - 1)], 0, h_, 0)
+
+    def k_index(b_, j, pt, ln):
+        return pt[b_, jnp.minimum(j, nb - 1)], 0, 0, 0
+
+    def v_index(b_, j, pt, ln):
+        return pt[b_, jnp.clip(j - nb, 0, nb - 1)], 0, 0, 0
+
+    def q_index(b_, j, pt, ln):
+        return b_, 0, 0
+
+    in_specs = [pl.BlockSpec((1, h, d), q_index),
+                pl.BlockSpec((1, bs, h, d), k_index),
+                pl.BlockSpec((1, bs, h, d), v_index)]
+    operands = [q, k_pool, v_pool]
+    if quantized:
+        # the scales are blocked like their tiles, NOT scalar-prefetched:
+        # prefetch puts the whole [num_blocks, H] array in SMEM, which a
+        # real pool outgrows
+        in_specs += [
+            pl.BlockSpec((1, h, 1), lambda *a: k_index(*a)[:3]),
+            pl.BlockSpec((1, h, 1), lambda *a: v_index(*a)[:3])]
+        operands += [k_scales.astype(jnp.float32).reshape(n_pool, h, 1),
+                     v_scales.astype(jnp.float32).reshape(n_pool, h, 1)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, 2 * nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, d),
-                         lambda b_, h_, j, pt, ln: (b_, h_, 0)),
-            pl.BlockSpec((1, bs, 1, d), k_index),
-            pl.BlockSpec((1, bs, 1, d), v_index),
+        grid=(b, 2 * nb),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, h, d), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((nb, bs, h), jnp.float32),   # score / prob rows
+            pltpu.VMEM((1, h), jnp.float32),        # running max
+            pltpu.VMEM((1, h), jnp.float32),        # softmax denominator
+            pltpu.VMEM((h, d), jnp.float32),        # output accumulator
         ],
-        out_specs=pl.BlockSpec((1, 1, d),
-                               lambda b_, h_, j, pt, ln: (b_, h_, 0)),
-        scratch_shapes=scratch_shapes,
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=_interpret(),
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool)
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
 
 
 def _prefill_table_lengths(block_row, start, length, chunk):

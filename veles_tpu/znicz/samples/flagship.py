@@ -90,13 +90,8 @@ def _quantize_weight_stack(w, dtype):
         q = jnp.clip(jnp.round(w / scales[..., None, :]), -127, 127)
         return q.astype(jnp.int8), scales.astype(jnp.float32)
     if dtype == "fp8":
-        f8 = fp8_dtype()
-        if f8 is None:
-            raise ValueError(
-                "weight_dtype='fp8' but this jaxlib exposes no float8 "
-                "dtype; use 'int8'")
         scales = jnp.where(amax > 0, amax / _FP8_E4M3_MAX, 1.0)
-        return (w / scales[..., None, :]).astype(f8), \
+        return (w / scales[..., None, :]).astype(fp8_dtype()), \
             scales.astype(jnp.float32)
     raise ValueError("unknown weight dtype %r" % (dtype,))
 
@@ -576,19 +571,35 @@ def verify_step(params, k_pools, v_pools, page_table, lengths, tokens,
             tuple(k_pools), tuple(v_pools))
 
 
+def _reference_hidden(params, tokens, heads, k):
+    """The dense causal forward over ``tokens`` [T] -> [1, T, d]."""
+    stacked = _stacked(params)
+    h = params["emb"][jnp.asarray(tokens, jnp.int32)][None]
+    for i in range(stacked["qkv"].shape[0]):
+        p_i = jax.tree.map(lambda p: p[i], stacked)
+        h, _, _ = _prefill_block(p_i, h, heads, k)
+    return h
+
+
+def reference_logits(params, tokens, heads=2, k=1):
+    """Teacher-forced face of :func:`generate_reference`: the dense
+    causal forward over ``tokens`` [T] as logits [T, vocab], row ``t``
+    being what the oracle sees after ``tokens[:t + 1]``.  Attention is
+    causal and the MoE routes per token, so a row never depends on
+    later tokens: one jitted call over a padded sequence checks every
+    generated token and gives the top-2 margin where one differs."""
+    return _reference_hidden(params, tokens, heads, k)[0] \
+        @ params["emb"].T
+
+
 def generate_reference(params, prompt, n_new, heads=2, k=1):
     """Cache-free greedy oracle: rerun the full dense causal forward
     over the whole history for every generated token.  O(T^2) per
     token — tests only."""
     tokens = [int(t) for t in prompt]
-    stacked = _stacked(params)
-    stages = stacked["qkv"].shape[0]
     out = []
     for _ in range(n_new):
-        h = params["emb"][jnp.asarray(tokens, jnp.int32)][None]
-        for i in range(stages):
-            p_i = jax.tree.map(lambda p: p[i], stacked)
-            h, _, _ = _prefill_block(p_i, h, heads, k)
+        h = _reference_hidden(params, tokens, heads, k)
         logits = h[0, -1] @ params["emb"].T
         nxt = int(jnp.argmax(logits))
         out.append(nxt)
