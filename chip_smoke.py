@@ -117,32 +117,6 @@ class Phase:
             raise SmokeFailure(what)
 
 
-class CompileMonitor:
-    """Seconds JAX spent tracing, lowering and compiling, and its
-    persistent-cache traffic, from JAX's own monitoring events."""
-
-    _DURATIONS = ("/jax/core/compile/jaxpr_trace_duration",
-                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                  "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        from jax import monitoring
-        self.compile_seconds = 0.0
-        self.cache_hits = self.cache_misses = 0
-        monitoring.register_event_duration_secs_listener(self._duration)
-        monitoring.register_event_listener(self._event)
-
-    def _duration(self, name, seconds, **_):
-        if name in self._DURATIONS:
-            self.compile_seconds += seconds
-
-    def _event(self, name, **_):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif name == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
-
-
 def _on_tpu(tree):
     """Every array of ``tree`` lives on a TPU device.  Hard-wired, like
     every device check here: whatever backend a rehearsal steers the
@@ -691,6 +665,7 @@ def run(backend=BACKEND, multichip=False):
     from veles_tpu.backends import (apply_compilation_cache_config,
                                     cache_root)
     from veles_tpu.config import root
+    from veles_tpu.observability.compiles import CompileMonitor
     monitor = CompileMonitor()
     # the one cache directory: $JAX_COMPILATION_CACHE_DIR when the machine
     # sets it (JAX is already there), else the checkout's .cache/

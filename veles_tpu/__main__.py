@@ -31,6 +31,7 @@ import sys
 
 from .config import root, fix_config, set_config_by_path
 from .launcher import Launcher
+from .logger import events
 
 
 def _parse_value(text):
@@ -179,6 +180,12 @@ def make_parser():
                         "step split, recompile count, examples/sec into "
                         "/metrics and the result JSON; equivalent to "
                         "root.common.observability.profile=True)")
+    p.add_argument("--profiler-port", type=int, default=None, metavar="N",
+                   help="serve the JAX profiler on port N "
+                        "(jax.profiler.start_server): capture a trace of "
+                        "the running trainer with XProf or TensorBoard; "
+                        "the program's veles.* spans and the layer scopes "
+                        "are in it")
     p.add_argument("--no-fix-config", action="store_true",
                    help="keep Range placeholders (genetic optimizer use)")
     from .cmdline import contribute_arguments
@@ -254,6 +261,10 @@ class Main:
     def _load(self, factory, **kwargs):
         """Build the workflow (or restore it from ``--snapshot``); returns
         (workflow, was_restored)."""
+        with events.timed("main.load"):
+            return self._build(factory, **kwargs)
+
+    def _build(self, factory, **kwargs):
         args = self.args
         if args.snapshot:
             if args.mesh or args.model_axis or args.mode or args.tp_mode:
@@ -316,7 +327,8 @@ class Main:
             return self.workflow
         if args.profile:
             root.common.observability.profile = True
-        self.launcher.initialize(**kwargs)
+        with events.timed("main.initialize"):
+            self.launcher.initialize(**kwargs)
         if args.visualize:
             self.workflow.generate_graph(args.visualize)
         if args.dry_run == "init":
@@ -391,7 +403,8 @@ class Main:
         from . import prng
         prng.get(0).seed(parse_seed(seed))
         self.launcher = Launcher(backend=args.backend,
-                                 result_file=args.result_file)
+                                 result_file=args.result_file,
+                                 profiler_port=args.profiler_port)
         if not hasattr(module, "run"):
             raise SystemExit(
                 "workflow module %r does not define run(load, main)"

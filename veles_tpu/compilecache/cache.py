@@ -7,7 +7,8 @@ executable (``jax.experimental.serialize_executable`` — milliseconds)
 or **compiled** fresh and persisted for the next process.  Every
 outcome is observable: ``veles_compile_cache_{hits,misses,bytes,
 seconds_saved}_total`` in the process-global MetricsRegistry and
-``compile.cache_hit`` / ``compile.miss`` trace spans.
+``veles.compile.cache_hit`` / ``veles.compile.miss`` spans
+(``events.timed``: in the ring, the event file and a running profile).
 
 Failure policy — the cache may only ever cost a recompile, never a
 crash or a wrong result: a truncated/undeserializable entry is
@@ -27,7 +28,6 @@ could hide a program the device refused.
 import logging
 import os
 import pickle
-import time
 
 from ..config import root
 from ..logger import events
@@ -91,49 +91,48 @@ class CompileCache:
         loaded = self._try_load(key, name)
         if loaded is not None:
             return loaded, True
-        t0 = time.perf_counter()
-        compiled = lowered.compile()
-        dt = time.perf_counter() - t0
+        with events.timed("compile.miss", fn=str(name),
+                          key=key[:16]) as span:
+            compiled = lowered.compile()
         self._c_misses.inc()
-        events.span("compile.miss", dt, fn=name, key=key[:16])
-        self._persist(key, compiled, dt, name)
+        self._persist(key, compiled, span.seconds, name)
         return compiled, False
 
     def _try_load(self, key, name):
         blob = self.store.get(key)
         if blob is None:
             return None
-        t0 = time.perf_counter()
-        try:
-            entry = pickle.loads(blob)
-            if entry["format"] != _FORMAT or entry["key"] != key:
-                raise ValueError("entry format/key mismatch")
-            import jax
-            from jax.experimental import serialize_executable
-            # load onto the devices the executable was compiled for:
-            # left to its default, deserialize_and_load spreads a
-            # one-device program over EVERY local device and its first
-            # call then fails ("expected N shards")
-            by_id = {d.id: d for d in jax.devices()}
-            loaded = serialize_executable.deserialize_and_load(
-                *entry["exe"],
-                execution_devices=[by_id[i] for i in entry["devices"]])
-        except Exception as exc:  # noqa: BLE001 — ANY bad entry: miss
-            self.store.quarantine(key, reason=str(exc)[:120])
-            if key not in self._quarantined:
-                self._quarantined.add(key)
-                log.warning("compile cache: entry %s for %r was corrupt "
-                            "(%s: %s); recompiling", key[:16], name,
-                            type(exc).__name__, str(exc)[:200])
-            return None
-        dt = time.perf_counter() - t0
+        with events.timed("compile.cache_hit", fn=str(name), key=key[:16],
+                          bytes=len(blob)) as span:
+            try:
+                entry = pickle.loads(blob)
+                if entry["format"] != _FORMAT or entry["key"] != key:
+                    raise ValueError("entry format/key mismatch")
+                import jax
+                from jax.experimental import serialize_executable
+                # load onto the devices the executable was compiled for:
+                # left to its default, deserialize_and_load spreads a
+                # one-device program over EVERY local device and its first
+                # call then fails ("expected N shards")
+                by_id = {d.id: d for d in jax.devices()}
+                loaded = serialize_executable.deserialize_and_load(
+                    *entry["exe"],
+                    execution_devices=[by_id[i] for i in entry["devices"]])
+            except Exception as exc:  # noqa: BLE001 — ANY bad entry: miss
+                span.count(corrupt=1)   # a hit that ended in a recompile
+                self.store.quarantine(key, reason=str(exc)[:120])
+                if key not in self._quarantined:
+                    self._quarantined.add(key)
+                    log.warning("compile cache: entry %s for %r was "
+                                "corrupt (%s: %s); recompiling", key[:16],
+                                name, type(exc).__name__, str(exc)[:200])
+                return None
+        dt = span.seconds
         self._c_hits.inc()
         self._c_bytes.inc(len(blob))
         self._c_saved.inc(max(0.0,
                               float(entry.get("compile_seconds", 0.0))
                               - dt))
-        events.span("compile.cache_hit", dt, fn=name, key=key[:16],
-                    bytes=len(blob))
         return loaded
 
     def _persist(self, key, compiled, compile_seconds, name):

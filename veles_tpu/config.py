@@ -328,7 +328,6 @@ root.update({
             "min_tensor_bytes": 65536,
         },
         "trace": {"enabled": False, "file": None},
-        "timings": set(),
         "random_seed": 1234,
     },
 })

@@ -25,6 +25,7 @@ import sys
 import time
 
 from .config import root
+from .logger import events
 from .observability import trace as _trace
 
 
@@ -87,6 +88,10 @@ class Launcher:
             # serve() reuses a live server on the same port
             from .web_status import serve
             self.status_server = serve(int(status_port))
+        #: ``--profiler-port``: where ``initialize`` starts JAX's profiler
+        #: server, the operator's door to a trace of the running trainer
+        self.profiler_port = kwargs.pop("profiler_port", None)
+        self._profiler_server = None
         self._extra = kwargs
 
     # -- lifecycle -----------------------------------------------------------
@@ -100,6 +105,10 @@ class Launcher:
             raise ValueError("no workflow attached (call add_workflow)")
         if self.device is None:
             self.device = Device(backend=self.backend)
+        if self.profiler_port is not None and self._profiler_server is None:
+            import jax
+            self._profiler_server = jax.profiler.start_server(
+                int(self.profiler_port))
         self.workflow.initialize(device=self.device, **kwargs)
         if root.common.observability.get("profile", False) and \
                 not self.stealth:
@@ -119,7 +128,8 @@ class Launcher:
         # one span context per run: every event the run emits (unit
         # spans, train.step, serving) then shares a trace_id — fresh
         # unless a parent process's context was adopted at construction
-        with _trace.span_context():
+        with _trace.span_context(), events.timed(
+                "main.run", workflow=self.workflow.name):
             try:
                 self.workflow.run()
             finally:
@@ -136,6 +146,10 @@ class Launcher:
         if self.status_server is not None:
             self.status_server.stop()
             self.status_server = None
+        if self._profiler_server is not None:
+            import jax
+            jax.profiler.stop_server()
+            self._profiler_server = None
 
     # -- results -------------------------------------------------------------
     def gather_results(self):

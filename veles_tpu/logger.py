@@ -8,15 +8,24 @@ file** (one event object per line, ``ph`` B/E/X/i phases) — loadable in
 Perfetto/chrome://tracing next to jax-profiler traces, greppable, and
 zero-dependency — instead of a Mongo collection.
 
-Enable via config::
+Enable the file via config::
 
     root.common.trace.enabled = True
     root.common.trace.file = "events.jsonl"      # default: events dir
 
-or ``Unit.execute`` emits per-run spans automatically when enabled.
+The program times a region in ONE way, ``with events.timed(name):``
+(:class:`Span`).  Such a span is always kept in a bounded in-memory
+ring and in per-name totals, is mirrored to the JSONL file when that is
+enabled, and is a ``jax.profiler.TraceAnnotation`` named ``veles.<name>``
+whenever JAX is loaded, so that a profile taken by anyone (the
+benchmark's ``--trace 1``, ``--profiler-port``, a builder) shows the
+program's spans on the device operations' own clock.  This module
+imports no JAX: it looks it up in ``sys.modules``.
 """
 
 import atexit
+import collections
+import itertools
 import json
 import logging
 import os
@@ -84,17 +93,107 @@ class Logger:
         self.logger.error(msg, *args)
 
 
-class EventLog:
-    """Chrome-trace JSONL writer (the Mongo events replacement).
+#: what the program's spans are called on the profiler's timeline, in
+#: the ring and in the totals: ``veles.`` + the name the site gives (the
+#: JSONL file and the ``span_sink`` keep the site's own name)
+SPAN_PREFIX = "veles."
+#: spans the ring holds; the oldest is dropped for the newest
+RING_CAPACITY = 8192
 
-    Phases: ``begin``/``end`` spans, ``single`` instants, and ``span``
-    complete events with explicit duration — mapping to trace-viewer
-    ``B``/``E``/``i``/``X``."""
+
+class Span:
+    """One timed region of the program: what ``events.timed`` returns and
+    what the ring holds.
+
+    ``name`` (with :data:`SPAN_PREFIX`), ``info`` (what the site said
+    about the region), ``seq`` (a process-wide sequence number),
+    ``parent`` (the ``seq`` of the enclosing span of the same thread, or
+    None), ``thread``, ``work`` (the unit of work the thread was on when
+    the span closed, see :meth:`EventLog.set_work`: the epoch for the
+    trainer, else the active ``trace_id``), ``start_ns``
+    (``time.time_ns()``: the wall clock, which a profile's clock differs
+    from by a constant) and ``duration_ns`` (from ``perf_counter_ns``, so
+    a stepped wall clock cannot make it negative)."""
+
+    __slots__ = ("name", "info", "seq", "parent", "thread", "work",
+                 "start_ns", "duration_ns", "counts", "_log", "_t0",
+                 "_annotation")
+
+    def __init__(self, log, name, info):
+        self._log = log
+        self.name = SPAN_PREFIX + name
+        self.info = info
+        self.counts = None
+        self.seq = self.parent = self.thread = self.work = None
+        self.start_ns = self.duration_ns = None
+        self._annotation = None
+
+    def count(self, **counts):
+        """Work done inside the region, said from inside it (a count is
+        often known only there): kept in ``info`` and SUMMED in the
+        per-name totals, so that seconds per step or per image are ratios
+        of numbers taken at one place."""
+        if self.counts is None:
+            self.counts = {}
+        self.counts.update(counts)
+
+    @property
+    def seconds(self):
+        return self.duration_ns / 1e9
+
+    def _begin(self):
+        """Number the span and find its parent; returns the thread's
+        stack of open spans."""
+        stack = self._log._stack()
+        self.parent = stack[-1].seq if stack else None
+        self.seq = next(self._log._seq)
+        return stack
+
+    def __enter__(self):
+        log = self._log
+        self._begin().append(self)
+        annotation = log._annotation_type()
+        if annotation is not None:
+            # one flag check when no profile is being taken
+            self._annotation = annotation(self.name, **self.info)
+            self._annotation.__enter__()
+        self.start_ns = time.time_ns()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.duration_ns = time.perf_counter_ns() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
+        stack = self._log._stack()
+        while stack and stack.pop() is not self:
+            pass
+        self._log._close(self)
+        return False    # an exception closes the span and goes on
+
+
+class EventLog:
+    """The program's spans: a bounded ring and per-name totals in memory
+    (always), a Chrome-trace JSONL file (when enabled), the profiler's
+    timeline (when a profile is being taken).
+
+    ``timed`` is how a region is timed.  ``span(name, seconds)`` reports
+    a region after the fact, for the sites that still time themselves.
+    JSONL phases: ``begin``/``end`` spans, ``single`` instants, and
+    ``span`` complete events with explicit duration — mapping to
+    trace-viewer ``B``/``E``/``i``/``X``."""
 
     _PH = {"begin": "B", "end": "E", "single": "i", "span": "X"}
 
-    def __init__(self, path=None):
+    def __init__(self, path=None, capacity=RING_CAPACITY):
         self._path = path
+        self._ring = collections.deque(maxlen=capacity)
+        self._totals = {}
+        self._ring_lock = threading.Lock()
+        self._seq = itertools.count(1)
+        self._local = threading.local()
+        self._annotation = None
         self._file = None
         self._lock = threading.Lock()
         self.path = None
@@ -139,16 +238,127 @@ class EventLog:
             "args": {"unix_time_s": time.time()}}) + "\n")
         atexit.register(self.close)
 
+    # -- timed regions -------------------------------------------------------
+    def timed(self, name, **info):
+        """Context manager around a region of the program::
+
+            with events.timed("step.run", cls="train", epoch=3) as span:
+                ...
+                span.count(steps=32, images=8192)
+
+        ``info`` values are strings or numbers (they become the
+        profiler's event arguments).  An exception inside the region
+        closes the span and propagates."""
+        return Span(self, name, info)
+
+    def span(self, name, seconds, **info):
+        """Complete span ending now, lasting ``seconds``: for a site that
+        timed itself.  It is in the ring, the totals, the file and the
+        sink, but not on a profiler's timeline (that cannot be told
+        afterwards)."""
+        done = Span(self, name, info)
+        done._begin()
+        done.duration_ns = int(seconds * 1e9)
+        done.start_ns = time.time_ns() - done.duration_ns
+        self._close(done)
+
+    def instant(self, name, **info):
+        """Something that happened now: a span of no length, in the ring
+        and on the profiler's timeline like any other, a ``single`` event
+        in the file."""
+        now = Span(self, name, info)
+        now._begin()
+        annotation = self._annotation_type()
+        if annotation is not None:
+            with annotation(now.name, **info):
+                pass
+        now.start_ns, now.duration_ns = time.time_ns(), 0
+        self._close(now)
+
+    def set_work(self, work):
+        """Name the unit of work this thread is on from now on (the
+        trainer: the epoch number).  Every span closed on the thread
+        carries it, the enclosing ones too, until the next call; a thread
+        that never calls this carries the active ``trace_id``."""
+        self._local.work = work
+
+    def spans(self):
+        """The ring, oldest first (a copy)."""
+        with self._ring_lock:
+            return list(self._ring)
+
+    def totals(self):
+        """``{name: {"count", "seconds", "longest", <count>: sum, ...}}``
+        since the process started (or ``reset``): the ring forgets, this
+        does not."""
+        with self._ring_lock:
+            return {name: dict(total)
+                    for name, total in self._totals.items()}
+
+    def _stack(self):
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def _annotation_type(self):
+        """``jax.profiler.TraceAnnotation`` once somebody has imported
+        JAX, else None (this module never imports it)."""
+        if self._annotation is None:
+            jax = sys.modules.get("jax")
+            self._annotation = getattr(
+                getattr(jax, "profiler", None), "TraceAnnotation", None)
+        return self._annotation
+
+    def _close(self, span):
+        """A finished span goes to the ring, the totals, the sink and the
+        file."""
+        span.thread = threading.get_ident()
+        work = span.work = getattr(self._local, "work", None)
+        if work is None:
+            ctx = _trace.current()
+            span.work = ctx.trace_id if ctx is not None else None
+        if span.counts:
+            span.info.update(span.counts)
+        seconds = span.duration_ns / 1e9
+        with self._ring_lock:
+            self._ring.append(span)
+            total = self._totals.get(span.name)
+            if total is None:
+                total = self._totals[span.name] = {
+                    "count": 0, "seconds": 0.0, "longest": 0.0}
+            total["count"] += 1
+            total["seconds"] += seconds
+            total["longest"] = max(total["longest"], seconds)
+            for key, value in (span.counts or {}).items():
+                total[key] = total.get(key, 0) + value
+        info, enabled = span.info, self.enabled
+        if enabled:
+            info = dict(info, seq=span.seq)
+            if span.parent is not None:
+                info["parent_seq"] = span.parent
+            if work is not None:    # a trace_id is written by event()
+                info.setdefault("work", work)
+        name = span.name[len(SPAN_PREFIX):]
+        if span.duration_ns:
+            self._emit(name, "span", seconds, info, enabled)
+        else:
+            self._emit(name, "single", None, info, enabled)
+
+    # -- the file and the sink -----------------------------------------------
     def event(self, name, kind="single", duration=None, **info):
-        """Record one event; no-op unless tracing is enabled (the
-        ``span_sink`` mirror fires regardless — it is memory-only)."""
+        """Record one event in the file; no-op unless tracing is enabled
+        (the ``span_sink`` mirror fires regardless — it is memory-only)."""
+        self._emit(name, kind, duration, info, self.enabled)
+
+    def _emit(self, name, kind, duration, info, enabled):
         sink = self.span_sink
         if sink is not None:
             try:
                 sink(name, kind, duration, info)
             except Exception:  # noqa: BLE001 — diagnostics never raise
                 pass
-        if not self.enabled:
+        if not enabled:
             return
         ctx = _trace.current()
         with self._lock:
@@ -173,10 +383,6 @@ class EventLog:
                 record["args"] = info
             self._file.write(json.dumps(record) + "\n")
 
-    def span(self, name, seconds, **info):
-        """Complete span ending now, lasting ``seconds``."""
-        self.event(name, "span", duration=seconds, **info)
-
     def close(self):
         with self._lock:
             if self._file is not None:
@@ -193,6 +399,9 @@ class EventLog:
             self._path = None
             self.path = None
             self._t0 = time.perf_counter()
+        with self._ring_lock:
+            self._ring.clear()
+            self._totals.clear()
 
 
 #: process-global event log (reference: per-node Mongo duplication)

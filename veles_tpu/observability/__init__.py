@@ -1,6 +1,6 @@
 """Unified observability core.
 
-Three pieces, one picture (the reference platform's shared event stream
+Four pieces, one picture (the reference platform's shared event stream
 plus web status server, rebuilt TPU-native):
 
 - :mod:`~veles_tpu.observability.registry` — the process-global
@@ -15,6 +15,13 @@ plus web status server, rebuilt TPU-native):
   per-process ``events-*.jsonl`` files from a distributed run share one
   ``trace_id`` and merge into a single Perfetto timeline
   (``tools/merge_traces.py``).
+
+- :mod:`~veles_tpu.observability.compiles` — :class:`CompileMonitor`:
+  what JAX compiled, from its own monitoring events, each backend
+  compile an instant ``veles.compile`` among the spans.
+
+The spans themselves (``events.timed``: ring, totals, the profiler's
+timeline) live in :mod:`veles_tpu.logger`.
 
 ``registry`` and ``trace`` are stdlib-only and import nothing from
 veles_tpu (so ``logger``/``units`` can use them cycle-free); the

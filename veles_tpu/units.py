@@ -21,9 +21,8 @@ tensor path is collapsed into jitted step functions by the accelerated layer
 the host-side control plane.
 """
 
-import time
-
 from .config import root
+from .logger import events
 from .mutable import Bool, link_attribute
 from .pickling import Lockable
 from .registry import UnitRegistry
@@ -230,14 +229,16 @@ class Unit(Lockable, IDistributable, metaclass=UnitRegistry):
             wf.warning_run_after_stop(self)
             return
         if not bool(self.gate_skip):
-            t0 = time.monotonic()
-            self.run()
-            dt = time.monotonic() - t0
+            name = self.__class__.__name__
+            # the one clock of a unit's run: the span (always in the
+            # ring; in the JSONL file and on a profiler's timeline when
+            # those are on) and, from the same measurement, the per-unit
+            # accumulators that print_stats reads
+            with events.timed("unit." + self.name, cls=name) as span:
+                self.run()
+            dt = span.seconds
             self.timers["run"] += dt
             self.timers["runs"] += 1
-            name = self.__class__.__name__
-            if name in root.common.get("timings", set()):
-                print("%s: run %.3f ms" % (self.name, dt * 1e3))
             if root.common.observability.get("unit_metrics", False):
                 # opt-in: every unit run lands in the process-global
                 # registry (one histogram series per unit name) — the
@@ -248,13 +249,6 @@ class Unit(Lockable, IDistributable, metaclass=UnitRegistry):
                     "Per-unit run() wall time",
                     ("unit", "cls")).labels(
                     unit=self.name, cls=name).observe(dt)
-            from .logger import events
-            if events.enabled:
-                # per-run span into the JSONL event stream (the Mongo
-                # event replacement — reference logger.py:264-289 wrapped
-                # run the same way); events.enabled also honors the
-                # VELES_TRACE_DIR env switch, not just the config flag
-                events.span(self.name, dt, cls=name)
         if self.stopped and not isinstance(self, Container):
             return  # unit declared itself done; FireStarter can revive it
         self.run_dependent(schedule)

@@ -17,6 +17,7 @@ import hashlib
 import json
 import sys
 
+from .logger import events
 from .plumbing import StartPoint, EndPoint
 from .result_provider import IResultProvider
 from .units import Container
@@ -114,28 +115,32 @@ class Workflow(Container):
         satisfied" — it is retried after the others (reference
         workflow.py:303-350 deferred init).
         """
-        super().initialize(**kwargs)
-        self.device = device
-        order = self._dependency_order()
-        pending = collections.deque(order)
-        retries = 0
-        max_retries = len(pending) ** 2 + 10
-        while pending:
-            unit = pending.popleft()
-            if unit is self:
-                continue
-            unit.verify_demands()
-            deferred = unit.initialize(device=device, **kwargs)
-            if deferred:
-                pending.append(unit)
-                retries += 1
-                if retries > max_retries:
-                    raise RuntimeError(
-                        "initialization deadlock; still pending: %s" %
-                        ([u.name for u in pending]))
-        for unit in order:
-            unit.reset_gates()
-        self._is_finished_ = False
+        with events.timed("workflow.initialize", workflow=self.name):
+            super().initialize(**kwargs)
+            self.device = device
+            order = self._dependency_order()
+            pending = collections.deque(order)
+            retries = 0
+            max_retries = len(pending) ** 2 + 10
+            while pending:
+                unit = pending.popleft()
+                if unit is self:
+                    continue
+                unit.verify_demands()
+                # one span per attempt: a deferred unit shows each retry
+                with events.timed("unit.%s.initialize" % unit.name,
+                                  cls=unit.__class__.__name__):
+                    deferred = unit.initialize(device=device, **kwargs)
+                if deferred:
+                    pending.append(unit)
+                    retries += 1
+                    if retries > max_retries:
+                        raise RuntimeError(
+                            "initialization deadlock; still pending: %s" %
+                            ([u.name for u in pending]))
+            for unit in order:
+                unit.reset_gates()
+            self._is_finished_ = False
         return self
 
     def _dependency_order(self):

@@ -20,6 +20,7 @@ import numpy
 
 from ..compilecache import AotStep, default_cache
 from ..config import root
+from ..logger import events
 from ..memory import Array
 from ..result_provider import IResultProvider
 from ..units import Unit
@@ -27,6 +28,16 @@ from .. import loader as loader_mod
 from .all2all import All2AllSoftmax
 from .evaluator import EvaluatorSoftmax, EvaluatorMSE
 from . import solvers
+
+
+def scope_names(units):
+    """The ``jax.named_scope`` of each unit of a chain: the unit's own
+    name, with its index where two units share one.  A trace then names
+    the device's operations by layer (``conv2``, ``transpose(jvp(conv2))``
+    for its backward pass) instead of by instruction and shape."""
+    names = [u.name for u in units]
+    return [name if names.count(name) == 1 else "%s%d" % (name, i)
+            for i, name in enumerate(names)]
 
 
 class FusedTrainStep(Unit, IResultProvider):
@@ -53,6 +64,7 @@ class FusedTrainStep(Unit, IResultProvider):
         self.minibatch_size = None
         self.minibatch_class = None
         self.last_minibatch = None
+        self.epoch_number = None
         # evaluator-compatible metric surface:
         self.n_err = Array(numpy.zeros(1, numpy.int64))
         self.metrics = Array(numpy.zeros(3, numpy.float64))
@@ -88,6 +100,9 @@ class FusedTrainStep(Unit, IResultProvider):
         self.link_attrs(loader, "minibatch_data", "minibatch_labels",
                         "minibatch_size", "minibatch_class",
                         "last_minibatch")
+        if hasattr(loader, "epoch_number"):
+            # the unit of work the step's spans are filed under
+            self.link_attrs(loader, "epoch_number")
         if hasattr(loader, "minibatch_targets"):
             self.link_attrs(loader, "minibatch_targets")
         return self
@@ -121,6 +136,8 @@ class FusedTrainStep(Unit, IResultProvider):
         forwards = self.forwards
         gds = self.gd_units
         loss_kind = self.loss_kind
+        # trace-time names only: nothing of them runs on the device
+        scopes = scope_names(forwards)
         softmax_head = isinstance(forwards[-1], All2AllSoftmax)
         has_stochastic = any(f.stochastic for f in forwards)
 
@@ -145,27 +162,30 @@ class FusedTrainStep(Unit, IResultProvider):
                                               "threefry2x32")
                 key = jax.random.key(seed, impl=impl)
             for i, fwd in enumerate(forwards[:-1]):
-                if train and fwd.stochastic:
-                    h = fwd.apply_train(params[i], h,
-                                        jax.random.fold_in(key, i))
-                else:
-                    h = fwd.apply(params[i], h)
+                with jax.named_scope(scopes[i]):
+                    if train and fwd.stochastic:
+                        h = fwd.apply_train(params[i], h,
+                                            jax.random.fold_in(key, i))
+                    else:
+                        h = fwd.apply(params[i], h)
             last = forwards[-1]
-            if with_logits and softmax_head:
-                return last.apply_logits(params[-1], h)
-            return last.apply(params[-1], h)
+            with jax.named_scope(scopes[-1]):
+                if with_logits and softmax_head:
+                    return last.apply_logits(params[-1], h)
+                return last.apply(params[-1], h)
 
         def loss_fn(params, x, labels_or_targets, mask, seed=None):
             out = net_apply(params, x, True, seed)
             # the loss itself is f32: bf16 log-sum-exp/reduction noise
             # would feed straight into the gradients' scale
-            out = out.astype(jnp.float32)
-            if loss_kind == "softmax":
-                data_loss = EvaluatorSoftmax.loss_from_logits(
-                    out, labels_or_targets, mask)
-            else:
-                data_loss = EvaluatorMSE.loss_from_output(
-                    out, labels_or_targets, mask)
+            with jax.named_scope("loss"):
+                out = out.astype(jnp.float32)
+                if loss_kind == "softmax":
+                    data_loss = EvaluatorSoftmax.loss_from_logits(
+                        out, labels_or_targets, mask)
+                else:
+                    data_loss = EvaluatorMSE.loss_from_output(
+                        out, labels_or_targets, mask)
             return data_loss, out
 
         n_classes = int(self.forwards[-1].output.shape[-1]) \
@@ -181,6 +201,7 @@ class FusedTrainStep(Unit, IResultProvider):
                 (n_classes, n_classes), numpy.int32)
         self._cm_dev_ = None    # device-resident running total (flush)
 
+        @jax.named_scope("metrics")
         def accumulate(macc, out, labels_or_targets, mask):
             """Fold one step's outputs into the device-resident metric
             accumulator.  Matches the graph evaluators' side-channels:
@@ -231,18 +252,19 @@ class FusedTrainStep(Unit, IResultProvider):
             for i, gd in enumerate(gds):
                 layer_p, layer_o = {}, {}
                 for name, p in params[i].items():
-                    g = grads[i][name]
-                    decay, l1l2, ortho = gd.decay_for(name)
-                    g = solvers.regularized_grad(g, p, decay, l1l2, jnp,
-                                                 ortho)
-                    # lr_scale: DYNAMIC schedule knob (LearningRateAdjuster)
-                    # — an argument, not a constant, so per-epoch decay
-                    # never retraces the step
-                    delta, st = gd.solver.update(
-                        g, p, opt[i][name], gd.lr_for(name) * lr_scale,
-                        jnp)
-                    layer_p[name] = p + delta
-                    layer_o[name] = st
+                    with jax.named_scope("update/" + scopes[i]):
+                        g = grads[i][name]
+                        decay, l1l2, ortho = gd.decay_for(name)
+                        g = solvers.regularized_grad(g, p, decay, l1l2,
+                                                     jnp, ortho)
+                        # lr_scale: DYNAMIC schedule knob
+                        # (LearningRateAdjuster) — an argument, not a
+                        # constant, so per-epoch decay never retraces
+                        delta, st = gd.solver.update(
+                            g, p, opt[i][name],
+                            gd.lr_for(name) * lr_scale, jnp)
+                        layer_p[name] = p + delta
+                        layer_o[name] = st
                 new_params.append(layer_p)
                 new_opt.append(layer_o)
             out = observable(out)
@@ -282,14 +304,16 @@ class FusedTrainStep(Unit, IResultProvider):
 
             def train_step_g(data, y_all, params, opt, macc, idx, size,
                              seed, lr_scale):
-                x = jnp.take(data, idx, axis=0)
-                y = jnp.take(y_all, idx, axis=0)
+                with jax.named_scope("gather"):
+                    x = jnp.take(data, idx, axis=0)
+                    y = jnp.take(y_all, idx, axis=0)
                 return train_step(params, opt, macc, x, y, size, seed,
                                   lr_scale)
 
             def eval_step_g(data, y_all, params, macc, idx, size):
-                x = jnp.take(data, idx, axis=0)
-                y = jnp.take(y_all, idx, axis=0)
+                with jax.named_scope("gather"):
+                    x = jnp.take(data, idx, axis=0)
+                    y = jnp.take(y_all, idx, axis=0)
                 return eval_step(params, macc, x, y, size)
 
             self._train_step_g_ = jax.jit(train_step_g,
@@ -359,7 +383,19 @@ class FusedTrainStep(Unit, IResultProvider):
 
     def run(self):
         size = int(self.minibatch_size)
-        train = self.minibatch_class == loader_mod.TRAIN
+        cls = self.minibatch_class
+        events.set_work(self.epoch_number)
+        with events.timed("step.run", cls=loader_mod.CLASS_NAME[cls],
+                          epoch=self.epoch_number) as span:
+            span.count(steps=1, images=size)
+            self._run_minibatch(size, cls == loader_mod.TRAIN)
+            if bool(self.last_minibatch):
+                self._flush_metrics()
+                self.sync_weights()
+
+    def _run_minibatch(self, size, train):
+        """Hand one minibatch to the jitted step (``step.dispatch``: the
+        argument hand-over and the enqueue; the device runs on)."""
         if getattr(self, "_use_gather_", False):
             # a MinibatchPrefetcher stages idx/size on device ahead of
             # the step (the H2D overlapped the previous step's compute);
@@ -373,44 +409,47 @@ class FusedTrainStep(Unit, IResultProvider):
                 self._seed_counter = (self._seed_counter + 1) % 0x7FFF0000
                 seed_arg = (self._staged_seed_arg() if staged is not None
                             else self._seed_counter)
-                (self._params_, self._opt_, self._macc_, loss, out) = \
-                    self._train_step_g_(
+            with events.timed("step.dispatch"):
+                if train:
+                    (self._params_, self._opt_, self._macc_, loss, out) = \
+                        self._train_step_g_(
+                            self._data_dev_, self._y_dev_, self._params_,
+                            self._opt_, self._macc_, idx, size_arg,
+                            seed_arg, float(self.lr_scale))
+                else:
+                    self._macc_, loss, out = self._eval_step_g_(
                         self._data_dev_, self._y_dev_, self._params_,
-                        self._opt_, self._macc_, idx, size_arg,
-                        seed_arg, float(self.lr_scale))
+                        self._macc_, idx, size_arg)
+        else:
+            x = self.minibatch_data.devmem
+            if self.loss_kind == "softmax":
+                y = self.minibatch_labels.devmem
             else:
-                self._macc_, loss, out = self._eval_step_g_(
-                    self._data_dev_, self._y_dev_, self._params_,
-                    self._macc_, idx, size_arg)
-            self.loss = loss
-            self.output.devmem = out
-            if bool(self.last_minibatch):
-                self._flush_metrics()
-                self.sync_weights()
-            return
-        x = self.minibatch_data.devmem
-        if self.loss_kind == "softmax":
-            y = self.minibatch_labels.devmem
-        else:
-            y = self.minibatch_targets.devmem
-        if train:
-            self._seed_counter = (self._seed_counter + 1) % 0x7FFF0000
-            (self._params_, self._opt_, self._macc_, loss, out) = \
-                self._train_step_(self._params_, self._opt_, self._macc_,
-                                  x, y, size, self._seed_counter,
-                                  float(self.lr_scale))
-        else:
-            self._macc_, loss, out = self._eval_step_(
-                self._params_, self._macc_, x, y, size)
+                y = self.minibatch_targets.devmem
+            if train:
+                self._seed_counter = (self._seed_counter + 1) % 0x7FFF0000
+            with events.timed("step.dispatch"):
+                if train:
+                    (self._params_, self._opt_, self._macc_, loss, out) = \
+                        self._train_step_(
+                            self._params_, self._opt_, self._macc_, x, y,
+                            size, self._seed_counter, float(self.lr_scale))
+                else:
+                    self._macc_, loss, out = self._eval_step_(
+                        self._params_, self._macc_, x, y, size)
         self.loss = loss           # device scalars; pulled lazily
         self.output.devmem = out
-        if bool(self.last_minibatch):
-            self._flush_metrics()
-            self.sync_weights()
 
     def _flush_metrics(self):
         """Pull the device accumulator into the evaluator-compatible
-        Arrays (one sync per class boundary, not per step)."""
+        Arrays (one sync per class boundary, not per step) and start a
+        fresh one."""
+        with events.timed("step.flush_metrics"):
+            self._pull_metrics()
+            self._macc_ = self._macc_init()
+
+    def _pull_metrics(self):
+        """The blocking read of the accumulator's scalars."""
         import jax
         if self.loss_kind == "softmax":
             n_err, cm, maxerr = self._macc_
@@ -443,15 +482,15 @@ class FusedTrainStep(Unit, IResultProvider):
             m[0] += float(sse)
             m[1] = max(m[1], float(mx))
             m[2] = min(m[2], float(mn))
-        self._macc_ = self._macc_init()
 
     def sync_weights(self):
         """Reflect the fused params back into the forward units' Arrays.
         Copies on device (cheap, once per epoch) — the fused buffers get
         donated by the next step and must not be aliased externally."""
         import jax.numpy as jnp
-        for fwd, p in zip(self.forwards, self._params_):
-            fwd.set_params({k: jnp.array(v) for k, v in p.items()})
+        with events.timed("step.sync_weights"):
+            for fwd, p in zip(self.forwards, self._params_):
+                fwd.set_params({k: jnp.array(v) for k, v in p.items()})
 
     def sync_solver_state(self):
         """Pull the fused optimizer state into the GD units' picklable

@@ -151,7 +151,7 @@ def pallas_lrn(x, n, alpha, beta, k, block_rows=None):
     out = pl.pallas_call(
         kernel, grid=grid, in_specs=[spec], out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype),
-        interpret=_pallas_interpret())(flat)
+        interpret=_pallas_interpret(), name="lrn_forward")(flat)
     return out.reshape(x.shape)
 
 
@@ -178,7 +178,7 @@ def _pallas_lrn_bwd(n, alpha, beta, k, block_rows, x, g):
     dx = pl.pallas_call(
         kernel, grid=grid, in_specs=[spec, spec], out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype),
-        interpret=_pallas_interpret())(flat, gflat)
+        interpret=_pallas_interpret(), name="lrn_backward")(flat, gflat)
     return (dx.reshape(x.shape),)
 
 

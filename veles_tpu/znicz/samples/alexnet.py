@@ -27,43 +27,59 @@ from ..standard_workflow import StandardWorkflow
 _LR = {"learning_rate": 0.01, "gradient_moment": 0.9,
        "weights_decay": 0.0005}
 
+# a layer's "name" is its unit's name, and so its scope in a device trace
 root.alexnet.update({
     "loader": {"minibatch_size": 128, "normalization_type": "none"},
     "layers": [
-        {"type": "conv_str", "->": {"n_kernels": 96, "kx": 11, "ky": 11,
+        {"name": "conv1",
+         "type": "conv_str", "->": {"n_kernels": 96, "kx": 11, "ky": 11,
                                     "sliding": (4, 4),
                                     "weights_stddev": 0.01}, "<-": _LR},
-        {"type": "norm", "->": {"alpha": 1e-4, "beta": 0.75, "n": 5,
+        {"name": "lrn1",
+         "type": "norm", "->": {"alpha": 1e-4, "beta": 0.75, "n": 5,
                                 "k": 2.0}},
-        {"type": "max_pooling", "->": {"kx": 3, "ky": 3,
+        {"name": "pool1",
+         "type": "max_pooling", "->": {"kx": 3, "ky": 3,
                                        "sliding": (2, 2)}},
-        {"type": "conv_str", "->": {"n_kernels": 256, "kx": 5, "ky": 5,
+        {"name": "conv2",
+         "type": "conv_str", "->": {"n_kernels": 256, "kx": 5, "ky": 5,
                                     "padding": 2,
                                     "weights_stddev": 0.01}, "<-": _LR},
-        {"type": "norm", "->": {"alpha": 1e-4, "beta": 0.75, "n": 5,
+        {"name": "lrn2",
+         "type": "norm", "->": {"alpha": 1e-4, "beta": 0.75, "n": 5,
                                 "k": 2.0}},
-        {"type": "max_pooling", "->": {"kx": 3, "ky": 3,
+        {"name": "pool2",
+         "type": "max_pooling", "->": {"kx": 3, "ky": 3,
                                        "sliding": (2, 2)}},
-        {"type": "conv_str", "->": {"n_kernels": 384, "kx": 3, "ky": 3,
+        {"name": "conv3",
+         "type": "conv_str", "->": {"n_kernels": 384, "kx": 3, "ky": 3,
                                     "padding": 1,
                                     "weights_stddev": 0.01}, "<-": _LR},
-        {"type": "conv_str", "->": {"n_kernels": 384, "kx": 3, "ky": 3,
+        {"name": "conv4",
+         "type": "conv_str", "->": {"n_kernels": 384, "kx": 3, "ky": 3,
                                     "padding": 1,
                                     "weights_stddev": 0.01}, "<-": _LR},
-        {"type": "conv_str", "->": {"n_kernels": 256, "kx": 3, "ky": 3,
+        {"name": "conv5",
+         "type": "conv_str", "->": {"n_kernels": 256, "kx": 3, "ky": 3,
                                     "padding": 1,
                                     "weights_stddev": 0.01}, "<-": _LR},
-        {"type": "max_pooling", "->": {"kx": 3, "ky": 3,
+        {"name": "pool5",
+         "type": "max_pooling", "->": {"kx": 3, "ky": 3,
                                        "sliding": (2, 2)}},
-        {"type": "all2all_str", "->": {"output_sample_shape": 4096,
+        {"name": "fc6",
+         "type": "all2all_str", "->": {"output_sample_shape": 4096,
                                        "weights_stddev": 0.005},
          "<-": _LR},
-        {"type": "dropout", "->": {"dropout_ratio": 0.5}},
-        {"type": "all2all_str", "->": {"output_sample_shape": 4096,
+        {"name": "dropout6",
+         "type": "dropout", "->": {"dropout_ratio": 0.5}},
+        {"name": "fc7",
+         "type": "all2all_str", "->": {"output_sample_shape": 4096,
                                        "weights_stddev": 0.005},
          "<-": _LR},
-        {"type": "dropout", "->": {"dropout_ratio": 0.5}},
-        {"type": "softmax", "->": {"output_sample_shape": 1000,
+        {"name": "dropout7",
+         "type": "dropout", "->": {"dropout_ratio": 0.5}},
+        {"name": "fc8",
+         "type": "softmax", "->": {"output_sample_shape": 1000,
                                    "weights_stddev": 0.01}, "<-": _LR},
     ],
     "decision": {"max_epochs": 90, "fail_iterations": 1000},

@@ -21,6 +21,7 @@ exact same protocol (SURVEY.md §7: partition units into traced and host).
 
 import numpy
 
+from ..logger import events
 from ..units import Unit
 from .. import loader as loader_mod
 from .fused import FusedTrainStep
@@ -77,8 +78,10 @@ class ScanEpochStep(FusedTrainStep):
             def body(carry, batch):
                 p, o, m = carry
                 bidx, bsize, bseed = batch
-                x = self._constrain_batch(jnp.take(data_dev, bidx, axis=0))
-                y = self._constrain_batch(jnp.take(y_dev, bidx, axis=0))
+                with jax.named_scope("gather"):
+                    x = self._constrain_batch(
+                        jnp.take(data_dev, bidx, axis=0))
+                    y = self._constrain_batch(jnp.take(y_dev, bidx, axis=0))
                 p, o, m, loss, _ = train(p, o, m, x, y, bsize, bseed,
                                          lr_scale)
                 return (p, o, m), loss
@@ -89,8 +92,10 @@ class ScanEpochStep(FusedTrainStep):
         def eval_scan(data_dev, y_dev, params, macc, idx, sizes):
             def body(m, batch):
                 bidx, bsize = batch
-                x = self._constrain_batch(jnp.take(data_dev, bidx, axis=0))
-                y = self._constrain_batch(jnp.take(y_dev, bidx, axis=0))
+                with jax.named_scope("gather"):
+                    x = self._constrain_batch(
+                        jnp.take(data_dev, bidx, axis=0))
+                    y = self._constrain_batch(jnp.take(y_dev, bidx, axis=0))
                 m, loss, _ = evaluate(params, m, x, y, bsize)
                 return m, loss
             macc, losses = lax.scan(body, macc, (idx, sizes))
@@ -148,40 +153,56 @@ class ScanEpochStep(FusedTrainStep):
                 idx[i, len(chunk):] = chunk[0]  # pad; masked by sizes
         return idx, sizes
 
+    def _dispatch(self, cls, idx, sizes):
+        """Hand one class's index matrix to its jitted scan
+        (``step.dispatch``: the argument hand-over and the enqueue; the
+        device runs on) and file the loader's served count."""
+        with events.timed("step.dispatch"):
+            if cls == loader_mod.TRAIN:
+                (self._params_, self._opt_, self._macc_, losses) = \
+                    self._train_scan_(
+                        self._data_dev_, self._y_dev_, self._params_,
+                        self._opt_, self._macc_, idx, sizes,
+                        self._next_seeds(len(sizes)), float(self.lr_scale))
+            else:
+                self._macc_, losses = self._eval_scan_(
+                    self._data_dev_, self._y_dev_,
+                    self._params_, self._macc_, idx, sizes)
+        self.loss = losses[-1]
+        self.loader.samples_served += int(sizes.sum())
+
     def run(self):
         ld = self.loader
+        new_epoch = self._class_cursor == 0 and self._epochs_done > 0
         classes = self._classes_with_samples()
-        if self._class_cursor == 0 and self._epochs_done:
-            # same moment the per-step loader wraps: entering a new epoch
-            ld.epoch_number += 1
-            ld.shuffle()
         cls = classes[self._class_cursor]
-        idx, sizes = self._class_index_matrix(cls)
-        if cls == loader_mod.TRAIN:
-            (self._params_, self._opt_, self._macc_, losses) = \
-                self._train_scan_(self._data_dev_, self._y_dev_,
-                                  self._params_, self._opt_, self._macc_,
-                                  idx, sizes, self._next_seeds(len(sizes)),
-                                  float(self.lr_scale))
-        else:
-            self._macc_, losses = self._eval_scan_(
-                self._data_dev_, self._y_dev_,
-                self._params_, self._macc_, idx, sizes)
-        self.loss = losses[-1]
-        ld.samples_served += int(sizes.sum())
-        # drive the loader protocol so Decision sees normal class ends
-        ld.minibatch_class = cls
-        ld.minibatch_size = int(sizes[-1])
-        last = self._class_cursor == len(classes) - 1
-        self._class_cursor = 0 if last else self._class_cursor + 1
-        ld.last_minibatch <<= True
-        ld.train_ended <<= cls == loader_mod.TRAIN
-        ld.valid_ended <<= cls == loader_mod.VALID
-        ld.epoch_ended <<= last
-        if last:
-            self._epochs_done += 1
-        self._flush_metrics()
-        self.sync_weights()
+        epoch = ld.epoch_number + new_epoch
+        events.set_work(epoch)
+        with events.timed("step.run", cls=loader_mod.CLASS_NAME[cls],
+                          epoch=epoch) as span:
+            if new_epoch:
+                # same moment the per-step loader wraps: entering a new
+                # epoch
+                ld.epoch_number += 1
+                with events.timed("step.shuffle"):
+                    ld.shuffle()
+            with events.timed("step.index_matrix"):
+                idx, sizes = self._class_index_matrix(cls)
+            span.count(steps=len(sizes), images=int(sizes.sum()))
+            self._dispatch(cls, idx, sizes)
+            # drive the loader protocol so Decision sees normal class ends
+            ld.minibatch_class = cls
+            ld.minibatch_size = int(sizes[-1])
+            last = self._class_cursor == len(classes) - 1
+            self._class_cursor = 0 if last else self._class_cursor + 1
+            ld.last_minibatch <<= True
+            ld.train_ended <<= cls == loader_mod.TRAIN
+            ld.valid_ended <<= cls == loader_mod.VALID
+            ld.epoch_ended <<= last
+            if last:
+                self._epochs_done += 1
+            self._flush_metrics()
+            self.sync_weights()
 
     # -- bulk training -------------------------------------------------------
     def train_epochs(self, n_epochs):
@@ -193,23 +214,24 @@ class ScanEpochStep(FusedTrainStep):
         for wall-clock) pays a single dispatch + a single metric read
         (what that buys on the present chip: not measured)."""
         ld = self.loader
-        chunks = []
-        for _ in range(n_epochs):
-            if self._epochs_done:
-                ld.epoch_number += 1
-                ld.shuffle()
-            idx, sizes = self._class_index_matrix(loader_mod.TRAIN)
-            chunks.append((idx, sizes))
-            self._epochs_done += 1
-        idx = numpy.concatenate([c[0] for c in chunks])
-        sizes = numpy.concatenate([c[1] for c in chunks])
-        (self._params_, self._opt_, self._macc_, losses) = \
-            self._train_scan_(self._data_dev_, self._y_dev_,
-                              self._params_, self._opt_, self._macc_,
-                              idx, sizes, self._next_seeds(len(sizes)),
-                              float(self.lr_scale))
-        self.loss = losses[-1]
-        ld.samples_served += int(sizes.sum())
-        ld.minibatch_class = loader_mod.TRAIN
-        self._flush_metrics()
-        self.sync_weights()
+        first = ld.epoch_number + (self._epochs_done > 0)
+        events.set_work(first)
+        with events.timed("step.run", cls=loader_mod.CLASS_NAME[
+                loader_mod.TRAIN], epoch=first, epochs=n_epochs) as span:
+            chunks = []
+            for _ in range(n_epochs):
+                if self._epochs_done:
+                    ld.epoch_number += 1
+                    with events.timed("step.shuffle"):
+                        ld.shuffle()
+                with events.timed("step.index_matrix"):
+                    chunks.append(
+                        self._class_index_matrix(loader_mod.TRAIN))
+                self._epochs_done += 1
+            idx = numpy.concatenate([c[0] for c in chunks])
+            sizes = numpy.concatenate([c[1] for c in chunks])
+            span.count(steps=len(sizes), images=int(sizes.sum()))
+            self._dispatch(loader_mod.TRAIN, idx, sizes)
+            ld.minibatch_class = loader_mod.TRAIN
+            self._flush_metrics()
+            self.sync_weights()
