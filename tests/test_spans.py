@@ -314,6 +314,7 @@ def check_step_spans(wf, children):
         assert below == children(run), (run.info, below)
         assert all(s.work == run.work for s in spans
                    if s.parent == run.seq)
+    assert check_class_ends(spans, runs) == 4   # two classes, two epochs
     assert sorted({r.info["epoch"] for r in runs}) == [0, 1]
     assert {r.info["cls"] for r in runs} == {"train", "validation"}
     total = events.totals()[SPAN_PREFIX + "step.run"]
@@ -329,11 +330,37 @@ def check_step_spans(wf, children):
     return runs
 
 
+def check_class_ends(spans, runs):
+    """At a class end the host blocks last: the weight copies are
+    enqueued and their span closed, the new accumulator is made, and only
+    then the one blocking read opens, as the only child of
+    ``step.flush_metrics``.  Returns the number of class ends."""
+    ends = 0
+    for run in runs:
+        below = {s.name[len(SPAN_PREFIX):]: s for s in spans
+                 if s.parent == run.seq}
+        if "step.flush_metrics" not in below:
+            continue
+        ends += 1
+        sync, flush = below["step.sync_weights"], below["step.flush_metrics"]
+        (read,) = [s for s in spans if s.parent == flush.seq
+                   and s.name != "veles.compile"]
+        assert read.name == "veles.step.read_metrics"
+        assert below["step.dispatch"].seq < sync.seq < flush.seq < read.seq
+        assert sync.start_ns + sync.duration_ns <= flush.start_ns \
+            <= read.start_ns
+        assert read.start_ns + read.duration_ns \
+            <= flush.start_ns + flush.duration_ns + 1_000_000
+        assert read.work == run.work
+    assert events.totals()["veles.step.read_metrics"]["count"] == ends
+    return ends
+
+
 def test_scan_workflow_leaves_its_spans():
     wf = mnist_workflow(epoch_scan=True)
     wf.run()
-    both = ["step.index_matrix", "step.dispatch", "step.flush_metrics",
-            "step.sync_weights"]
+    both = ["step.index_matrix", "step.dispatch", "step.sync_weights",
+            "step.flush_metrics"]
 
     def children(run):
         # a new epoch starts with the validation class: it shuffles
@@ -352,7 +379,7 @@ def test_fused_workflow_leaves_its_spans():
         last = run.info["images"] and run is last_of_class[
             (run.info["epoch"], run.info["cls"])]
         return ["step.dispatch"] + (
-            ["step.flush_metrics", "step.sync_weights"] if last else [])
+            ["step.sync_weights", "step.flush_metrics"] if last else [])
     last_of_class = {(r.info["epoch"], r.info["cls"]): r
                      for r in named(events.spans(), "step.run")}
     runs = check_step_spans(wf, children)
@@ -371,8 +398,9 @@ def test_train_epochs_is_one_step_run():
              if s.parent == run.seq and s.name != "veles.compile"]
     assert below.count("veles.step.index_matrix") == 3
     assert below.count("veles.step.shuffle") == 2
-    assert below[-3:] == ["veles.step.dispatch", "veles.step.flush_metrics",
-                          "veles.step.sync_weights"]
+    assert below[-3:] == ["veles.step.dispatch", "veles.step.sync_weights",
+                          "veles.step.flush_metrics"]
+    assert check_class_ends(events.spans(), [run]) == 1
 
 
 def test_workflow_initialize_leaves_one_span_a_unit():

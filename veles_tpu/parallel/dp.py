@@ -41,6 +41,9 @@ class DistributedTrainStep(FusedTrainStep):
             state["mesh"] = mesh_mod.mesh_spec(mesh)
         return state
 
+    def _macc_init(self):
+        return mesh_mod.fresh_accumulator(self, super()._macc_init())
+
     def make_trace(self):
         """Sharding survives tracing by construction: the SPMD step stays
         a natively-executed pre-compiled region, its in-program sharding
@@ -71,6 +74,7 @@ class DistributedTrainStep(FusedTrainStep):
             self._macc_ = jax.tree.map(numpy.asarray, self._macc_)
         param_shard, opt_shard, scalar = mesh_mod.trainer_shardings(
             m, self._params_, self._opt_, self.model_axis, self.tp_mode)
+        self._rep_ = scalar     # where fresh accumulators go (_macc_init)
         batch_shard = mesh_mod.batch_sharding(m, self.data_axis)
         label_shard = batch_shard
         # input-pipeline hooks (loader/prefetch.py): single-host, the

@@ -99,6 +99,21 @@ def replicated(mesh):
     return NamedSharding(mesh, P())
 
 
+def fresh_accumulator(step, macc):
+    """A trainer's fresh metric accumulator ``macc``, placed like the one
+    its jitted step returns (``step._rep_``, once the operands are
+    placed).  Left on the default device it is a second signature of the
+    jitted step — a second executable, compiled in the second epoch — and
+    a reshard inside the dispatch at every class start.  Across
+    processes a single-device array cannot be placed outside jit, so
+    there it stays as it is."""
+    import jax
+    rep = getattr(step, "_rep_", None)
+    if rep is None or jax.process_count() > 1:
+        return macc
+    return jax.device_put(macc, rep)
+
+
 def trainer_shardings(mesh, params, opt, model_axis=None,
                       tp_mode="column"):
     """The fused trainers' operand shardings: params tensor-sharded over

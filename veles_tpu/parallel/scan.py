@@ -84,6 +84,9 @@ class DistributedScanStep(ScanEpochStep):
         self._y_dev_ = jax.device_put(self._y_dev_, rep)
         self._placed_ = True
 
+    def _macc_init(self):
+        return mesh_mod.fresh_accumulator(self, super()._macc_init())
+
     def _constrain_batch(self, a):
         from jax import lax
         from jax.sharding import NamedSharding, PartitionSpec as P

@@ -390,8 +390,7 @@ class FusedTrainStep(Unit, IResultProvider):
             span.count(steps=1, images=size)
             self._run_minibatch(size, cls == loader_mod.TRAIN)
             if bool(self.last_minibatch):
-                self._flush_metrics()
-                self.sync_weights()
+                self._finish_class()
 
     def _run_minibatch(self, size, train):
         """Hand one minibatch to the jitted step (``step.dispatch``: the
@@ -440,19 +439,33 @@ class FusedTrainStep(Unit, IResultProvider):
         self.loss = loss           # device scalars; pulled lazily
         self.output.devmem = out
 
-    def _flush_metrics(self):
-        """Pull the device accumulator into the evaluator-compatible
-        Arrays (one sync per class boundary, not per step) and start a
-        fresh one."""
-        with events.timed("step.flush_metrics"):
-            self._pull_metrics()
-            self._macc_ = self._macc_init()
+    def _finish_class(self):
+        """The class-end epilogue, in the order that keeps the device fed.
+        The class's last dispatch is still running when this is called, so
+        all the device has to do next is enqueued behind it first — the
+        weight copies into the forward units, the fresh accumulator, the
+        scalars' copy to the host — and the host blocks on the class's
+        numbers last (``step.read_metrics``), in this same ``run()``:
+        Decision sees them exactly when it did.  Blocking first left the
+        device's queue empty through the read and all that followed it,
+        twice an epoch (PERF.md section 6, PR 27)."""
+        self.sync_weights()
+        self._flush_metrics()
 
-    def _pull_metrics(self):
-        """The blocking read of the accumulator's scalars."""
-        import jax
+    def _flush_metrics(self):
+        """Fold the device accumulator into the evaluator-compatible
+        Arrays (one sync per class boundary, not per step).  Its
+        successor is made before the read, so the next dispatch never
+        waits for it."""
+        with events.timed("step.flush_metrics"):
+            done, self._macc_ = self._macc_, self._macc_init()
+            self._pull_metrics(done)
+
+    def _pull_metrics(self, macc):
+        """File what the accumulator ``macc`` holds: the confusion matrix
+        on the device, the scalars through one blocking read."""
         if self.loss_kind == "softmax":
-            n_err, cm, maxerr = self._macc_
+            n_err, cm, maxerr = macc
             if self.compute_confusion_matrix:
                 # the [C, C] matrix stays ON DEVICE: pulling it per class
                 # boundary costs C²·4 bytes of D2H (4 MB for ImageNet
@@ -470,23 +483,39 @@ class FusedTrainStep(Unit, IResultProvider):
                 else:
                     self._cm_dev_ = self._cm_dev_ + cm
                 self.confusion_matrix.devmem = self._cm_dev_
-            # scalars ride ONE batched device_get (per-leaf reads are a
-            # device sync each)
-            n_err, maxerr = jax.device_get((n_err, maxerr))
+            n_err, maxerr = self._read_scalars((n_err, maxerr))
             self.n_err.map_write()[0] += int(n_err)
             self.max_err_output_sum.map_write()[0] = max(
                 float(self.max_err_output_sum[0]), float(maxerr))
         else:
-            sse, mx, mn = jax.device_get(self._macc_)
+            sse, mx, mn = self._read_scalars(macc)
             m = self.metrics.map_write()
             m[0] += float(sse)
             m[1] = max(m[1], float(mx))
             m[2] = min(m[2], float(mn))
 
+    @staticmethod
+    def _read_scalars(scalars):
+        """The class's one blocking read.  The copies to the host are
+        started first and queue behind the running dispatch; the read
+        rides ONE batched device_get (per-leaf reads are a device sync
+        each) under a span of its own: how long the host waited for the
+        device, which is the scan's remaining time where the epilogue was
+        enqueued in time."""
+        import jax
+        for leaf in scalars:
+            leaf.copy_to_host_async()
+        with events.timed("step.read_metrics"):
+            return jax.device_get(scalars)
+
     def sync_weights(self):
-        """Reflect the fused params back into the forward units' Arrays.
-        Copies on device (cheap, once per epoch) — the fused buffers get
-        donated by the next step and must not be aliased externally."""
+        """Reflect the fused params back into the forward units' Arrays:
+        one ``jnp.array`` copy on the device per tensor (sixteen launches
+        for AlexNet) — the fused buffers get donated by the next step and
+        must not be aliased externally.  At a class end it is called with
+        the dispatch still running, so the copies queue behind it; called
+        on its own (snapshot, rollback, the workflow's end) it costs the
+        host 5.5 ms on an idle chip (PERF.md section 5, PR 25)."""
         import jax.numpy as jnp
         with events.timed("step.sync_weights"):
             for fwd, p in zip(self.forwards, self._params_):

@@ -10,8 +10,12 @@ validation minibatches) into one jitted ``lax.scan``:
 with the resident FullBatch dataset gathered per-iteration *inside* the
 scan (``jnp.take``), masks built from the per-batch ``sizes`` vector, so
 results are bit-identical to the per-step path (asserted in tests).  Host
-work per class: build the index matrix (numpy), one device_put, one
-dispatch, one metric flush.
+work per class: build the index matrix (numpy), one dispatch (the index
+matrix rides along as an argument), then the class-end epilogue
+(``FusedTrainStep._finish_class``) enqueued behind the running scan —
+sixteen weight copies for AlexNet, a fresh accumulator — and last the one
+blocking read of the class's scalars.  Measured on the v5e: PERF.md
+sections 5 and 6 (PR 27).
 
 The unit replaces loader+fused_step in the control graph (repeater →
 scan_step → decision); the Loader still owns the dataset, shuffling, and
@@ -201,8 +205,7 @@ class ScanEpochStep(FusedTrainStep):
             ld.epoch_ended <<= last
             if last:
                 self._epochs_done += 1
-            self._flush_metrics()
-            self.sync_weights()
+            self._finish_class()
 
     # -- bulk training -------------------------------------------------------
     def train_epochs(self, n_epochs):
@@ -233,5 +236,4 @@ class ScanEpochStep(FusedTrainStep):
             span.count(steps=len(sizes), images=int(sizes.sum()))
             self._dispatch(loader_mod.TRAIN, idx, sizes)
             ld.minibatch_class = loader_mod.TRAIN
-            self._flush_metrics()
-            self.sync_weights()
+            self._finish_class()
