@@ -32,20 +32,22 @@ from veles_tpu.znicz import (flash_attention as fa, gemm, lrn,  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-try:
-    _TOPOLOGY = topologies.get_topology_desc(platform="tpu",
-                                             topology_name="v5e:2x2")
-except Exception as exc:  # noqa: BLE001 — no libtpu, no description
-    _TOPOLOGY, _WHY_NOT = None, "%s: %s" % (type(exc).__name__, exc)
-
-needs_topology = pytest.mark.skipif(
-    _TOPOLOGY is None,
-    reason="the v5e:2x2 topology cannot be described here"
-           + ("" if _TOPOLOGY is not None else " (%s)" % _WHY_NOT))
+@pytest.fixture(scope="module")
+def topology():
+    """The described v5e:2x2, asked for once a module and only when a
+    test of this file runs: describing it loads the TPU's library, which
+    one process at a time may hold, so nothing here does it while the
+    file is imported (every xdist worker imports every test file)."""
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip("the v5e:2x2 topology cannot be described here "
+                    "(%s: %s)" % (type(exc).__name__, exc))
 
 
 @pytest.fixture()
-def v5e(monkeypatch):
+def v5e(monkeypatch, topology):
     """One described v5e chip; kernels out of interpret mode; JAX's
     persistent cache off (an entry compiled for a described chip is
     written but cannot be read back without one, and warns)."""
@@ -55,7 +57,7 @@ def v5e(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        yield SingleDeviceSharding(_TOPOLOGY.devices[0])
+        yield SingleDeviceSharding(topology.devices[0])
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
@@ -77,7 +79,6 @@ _PAGED = [("smoke-f32", 16, 8, 32, 16, 16, jnp.float32),
           ("real-bf16", 16, 16, 128, 128, 16, jnp.bfloat16)]
 
 
-@needs_topology
 @pytest.mark.parametrize("entry", ["decode", "prefill", "verify"])
 @pytest.mark.parametrize("geometry", _PAGED, ids=[g[0] for g in _PAGED])
 def test_paged_attention_compiles_for_v5e(v5e, geometry, entry):
@@ -118,7 +119,6 @@ _FLASH = [(2, 2048, 8, 64, jnp.float32, None),
           (1, 16384, 8, 64, jnp.float32, 512)]
 
 
-@needs_topology
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
 @pytest.mark.parametrize(
     "shape", _FLASH,
@@ -140,14 +140,12 @@ def test_flash_attention_compiles_for_v5e(v5e, shape, grad):
     assert text.count("tpu_custom_call") >= 3
 
 
-@needs_topology
 @pytest.mark.parametrize("level", [0, 1, 2])
 def test_precise_matmul_compiles_for_v5e(v5e, level):
     a = jax.ShapeDtypeStruct((1024, 1024), jnp.float32, sharding=v5e)
     _compile(lambda a, b: gemm.precise_matmul(a, b, level, False), a, a)
 
 
-@needs_topology
 def test_quantized_matmul_compiles_for_v5e(v5e):
     a = jax.ShapeDtypeStruct((1024, 1024), jnp.float32, sharding=v5e)
     w = jax.ShapeDtypeStruct((1024, 1024), jnp.int8, sharding=v5e)
@@ -157,7 +155,6 @@ def test_quantized_matmul_compiles_for_v5e(v5e):
              a, w, s)
 
 
-@needs_topology
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
 def test_pallas_lrn_compiles_for_v5e(v5e, grad):
     x = jax.ShapeDtypeStruct((128, 55, 55, 96), jnp.float32, sharding=v5e)
@@ -186,6 +183,47 @@ cs.FLASH_WINDOW = 8
 cs.MNIST.update(max_batch=2, requests=(1, 2))
 sys.exit(cs.run(backend="cpu"))
 """
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize("shared", [True, False], ids=["shared", "copied"])
+def test_latent_flash_attention_compiles_for_v5e(v5e, shared, grad):
+    """The latent-attention kernels at the widths of a 32-head model
+    (192-wide query-key heads in a 128 and a 64 part, 128-wide values),
+    two sequences of 8,192, bfloat16, with the rope key one a token."""
+    b, h, t = 2, 32, 8192
+
+    def struct(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e)
+    operands = (struct(b, h, t, 128), struct(b, h, t, 64),
+                struct(b, h, t, 128),
+                struct(b, t, 64) if shared else struct(b, h, t, 64),
+                struct(b, h, t, 128))
+    if not grad:
+        text = _compile(fa.mla_flash_attention, *operands)
+        assert "mla_flash_fwd" in text
+        return
+    text = _compile(jax.grad(
+        lambda *a: fa.mla_flash_attention(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4)), *operands)
+    for kernel in ("mla_flash_fwd", "mla_flash_dq", "mla_flash_dkv"):
+        assert kernel in text, kernel
+
+
+@pytest.mark.parametrize("shape", [(2048, 1536), (768, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_compiles_for_v5e(v5e, shape):
+    """The expert layer's grouped products at the row bound of 16,384
+    tokens x 6 choices and 16 held experts, forward and gradients."""
+    k, n = shape
+    lhs = jax.ShapeDtypeStruct((16384 * 6, k), jnp.bfloat16, sharding=v5e)
+    rhs = jax.ShapeDtypeStruct((16, k, n), jnp.bfloat16, sharding=v5e)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=v5e)
+    text = _compile(jax.grad(
+        lambda a, b, s: gemm.grouped_matmul(a, b, s).astype(
+            jnp.float32).sum(), argnums=(0, 1)), lhs, rhs, sizes)
+    assert "gmm" in text and "tgmm" in text
+    assert text.count("tpu_custom_call") >= 2     # the two gradients
 
 
 def test_chip_smoke_rehearsal_on_cpu_runs_every_phase_and_fails():

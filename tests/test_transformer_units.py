@@ -1,0 +1,202 @@
+"""The transformer units' pieces by hand and against oracles, on the
+CPU: latent flash attention (query-key and value heads of different
+sizes, a rope key shared by the heads) in interpret mode against
+``attention_reference``; interleaved RoPE, RMSNorm and AdamW against
+hand arithmetic; the grouped product and the gather pair of the expert
+layer; the head's blocked token loss."""
+
+import jax
+import jax.numpy as jnp
+import numpy
+import pytest
+
+from veles_tpu.parallel.ring import attention_reference
+from veles_tpu.znicz import gemm, solvers, transformer
+from veles_tpu.znicz.flash_attention import (mla_attention_reference,
+                                             mla_flash_attention)
+
+
+def latent_operands(shared, t=128, b=2, h=4, dn=16, dr=8, dv=16):
+    ks = jax.random.split(jax.random.key(0), 5)
+    return (jax.random.normal(ks[0], (b, h, t, dn)),
+            jax.random.normal(ks[1], (b, h, t, dr)),
+            jax.random.normal(ks[2], (b, h, t, dn)),
+            jax.random.normal(ks[3], (b, t, dr) if shared
+                              else (b, h, t, dr)),
+            jax.random.normal(ks[4], (b, h, t, dv)))
+
+
+def oracle(q_nope, q_rope, k_nope, k_rope, v, causal):
+    """``attention_reference`` on the concatenated 24-wide query-key
+    heads and the 16-wide value heads, [B, T, H, D] layout."""
+    if k_rope.ndim == 3:
+        k_rope = jnp.broadcast_to(k_rope[:, None], q_rope.shape)
+    q = jnp.concatenate([q_nope, q_rope], -1).transpose(0, 2, 1, 3)
+    k = jnp.concatenate([k_nope, k_rope], -1).transpose(0, 2, 1, 3)
+    out = attention_reference(q, k, v.transpose(0, 2, 1, 3), causal=causal)
+    return out.transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("shared", [True, False])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blocks", [(32, 32), (32, 64), (64, 32)])
+def test_latent_flash_attention_against_the_oracle(shared, causal, blocks):
+    operands = latent_operands(shared)
+    weight = jnp.cos(jnp.arange(16.0))
+
+    def flash(*a):
+        return mla_flash_attention(*a, causal=causal, block_q=blocks[0],
+                                   block_k=blocks[1])
+    got, want = flash(*operands), oracle(*operands, causal)
+    assert got.shape == (2, 4, 128, 16)
+    assert float(jnp.abs(got - want).max()) < 2e-5
+    assert float(jnp.abs(mla_attention_reference(
+        *operands, causal=causal) - want).max()) < 2e-5
+    grads = jax.grad(lambda *a: (flash(*a) * weight).sum(),
+                     argnums=(0, 1, 2, 3, 4))(*operands)
+    wants = jax.grad(lambda *a: (oracle(*a, causal) * weight).sum(),
+                     argnums=(0, 1, 2, 3, 4))(*operands)
+    for g, w in zip(grads, wants):
+        assert g.shape == w.shape
+        assert float(jnp.abs(g - w).max()) < 1e-4 * float(
+            jnp.abs(w).max() + 1)
+
+
+def test_latent_flash_attention_bfloat16_and_untileable_lengths():
+    operands = [a.astype(jnp.bfloat16) for a in latent_operands(True)]
+    got = mla_flash_attention(*operands, block_q=64, block_k=64)
+    want = oracle(*[a.astype(jnp.float32) for a in operands], True)
+    assert got.dtype == jnp.bfloat16
+    assert float(jnp.abs(got.astype(jnp.float32) - want).max()) < 5e-2
+    # T = 24 has no block of 32 or more: the explicit-score path
+    short = latent_operands(True, t=24)
+    assert float(jnp.abs(mla_flash_attention(*short)
+                         - oracle(*short, True)).max()) < 2e-5
+
+
+def test_interleaved_rope_by_hand():
+    x = jnp.asarray([[1.0, 2.0, 3.0, 4.0], [5.0, 6.0, 7.0, 8.0],
+                     [0.5, -1.0, 2.0, 0.0]])
+    theta = 100.0
+    got = numpy.asarray(transformer.rope_interleaved(x, theta))
+    want = numpy.zeros((3, 4))
+    for pos in range(3):
+        for i in range(2):                  # the pair (2i, 2i + 1)
+            angle = pos * theta ** (-2 * i / 4)
+            a, b = float(x[pos, 2 * i]), float(x[pos, 2 * i + 1])
+            want[pos, 2 * i] = a * numpy.cos(angle) - b * numpy.sin(angle)
+            want[pos, 2 * i + 1] = a * numpy.sin(angle) \
+                + b * numpy.cos(angle)
+    assert numpy.allclose(got, want, atol=1e-5)
+    assert numpy.allclose(got[0], x[0])     # position 0 does not rotate
+    # a rotation: norms of the pairs are kept
+    assert numpy.allclose((got ** 2).reshape(3, 2, 2).sum(-1),
+                          (numpy.asarray(x) ** 2).reshape(3, 2, 2).sum(-1),
+                          atol=1e-4)
+
+
+def test_rms_norm_by_hand():
+    x = jnp.asarray([[3.0, 4.0], [0.0, 2.0]])
+    w = jnp.asarray([2.0, 0.5])
+    got = numpy.asarray(transformer.rms_norm(x, w, 1e-6))
+    want = numpy.asarray(x) / numpy.sqrt(
+        (numpy.asarray(x) ** 2).mean(-1, keepdims=True) + 1e-6) * [2.0, 0.5]
+    assert numpy.allclose(got, want, atol=1e-6)
+
+
+@pytest.mark.parametrize("xp", [numpy, jnp], ids=["numpy", "jax"])
+def test_adamw_by_hand(xp):
+    solver = solvers.factory("adamw", beta1=0.9, beta2=0.95, epsilon=1e-8,
+                             weight_decay=0.1)
+    lr = 3e-4
+    for w0 in (numpy.asarray([[1.0, -2.0], [0.5, 4.0]], numpy.float32),
+               numpy.asarray([1.0, -2.0, 0.5], numpy.float32)):
+        rng = numpy.random.default_rng(1)
+        w, state = xp.asarray(w0), solver.init(xp.asarray(w0), xp)
+        m = v = numpy.zeros_like(w0, numpy.float64)
+        want = w0.astype(numpy.float64)
+        for t in (1, 2, 3):
+            g = rng.standard_normal(w0.shape).astype(numpy.float32)
+            delta, state = solver.update(xp.asarray(g), w, state, lr, xp)
+            w = w + delta
+            m = 0.9 * m + 0.1 * g
+            v = 0.95 * v + 0.05 * g.astype(numpy.float64) ** 2
+            step = (m / (1 - 0.9 ** t)) / (numpy.sqrt(v / (1 - 0.95 ** t))
+                                           + 1e-8)
+            if w0.ndim >= 2:                # decay on matrices only
+                step = step + 0.1 * want
+            want = want - lr * step
+            assert numpy.allclose(numpy.asarray(w), want, rtol=2e-5,
+                                  atol=1e-7)
+        assert int(state[2]) == 3
+        assert numpy.allclose(numpy.asarray(state[0]), m, rtol=1e-5)
+        assert numpy.allclose(numpy.asarray(state[1]), v, rtol=1e-5)
+    # a zero gradient moves nothing that does not decay (a buffer)
+    delta, _ = solver.update(xp.zeros(3), xp.ones(3), solver.init(
+        xp.ones(3), xp), lr, xp)
+    assert not numpy.asarray(delta).any()
+
+
+@pytest.mark.parametrize("sizes", [[5, 0, 9, 2], [0, 0, 0, 0], [16, 0, 0, 0],
+                                   [3, 3, 3, 3]])
+def test_grouped_matmul_against_a_loop(sizes):
+    m, k, n = 16, 24, 40
+    lhs = jax.random.normal(jax.random.key(1), (m, k))
+    rhs = jax.random.normal(jax.random.key(2), (len(sizes), k, n))
+    sizes = jnp.asarray(sizes, jnp.int32)
+    filled = (jnp.arange(m) < sizes.sum())[:, None]
+
+    def loop(lhs, rhs):
+        group = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(m),
+                                 side="right").clip(0, len(sizes) - 1)
+        return jnp.where(filled, jnp.einsum("mk,mkn->mn", lhs, rhs[group],
+                                            precision="highest"), 0)
+
+    def ours(lhs, rhs):
+        return jnp.where(filled, gemm.grouped_matmul(lhs, rhs, sizes), 0)
+    assert numpy.allclose(ours(lhs, rhs), loop(lhs, rhs), atol=1e-4)
+    weight = jnp.sin(jnp.arange(m * n, dtype=jnp.float32)).reshape(m, n)
+    got = jax.grad(lambda a, b: (ours(a, b) * weight).sum(),
+                   argnums=(0, 1))(lhs, rhs)
+    want = jax.grad(lambda a, b: (loop(a, b) * weight).sum(),
+                    argnums=(0, 1))(lhs, rhs)
+    for g, w in zip(got, want):
+        g = jnp.where(filled, g, 0) if g.shape == lhs.shape else g
+        assert numpy.allclose(g, w, atol=1e-4)
+
+
+def test_the_gather_pair_has_a_gathers_gradient():
+    x = jax.random.normal(jax.random.key(3), (6, 5))
+    order = jax.random.permutation(jax.random.key(4), 18)
+    weight = jax.random.normal(jax.random.key(5), (18, 5))
+    for index in (order // 3, jnp.argsort(order)[:18] % 18):
+        source = x if index.max() < 6 else jnp.tile(x, (3, 1))
+        got = jax.grad(lambda a: (transformer._permute(a, index)
+                                  * weight).sum())(source)
+        want = jax.grad(lambda a: (jnp.take(a, index, axis=0)
+                                   * weight).sum())(source)
+        assert numpy.allclose(got, want, atol=1e-5)
+
+
+def test_blocked_token_loss_is_the_plain_one():
+    head = transformer.NormHead(None, hidden_size=16, vocab_size=50,
+                                loss_block_tokens=8, name="head")
+    params = {"norm": 1.0 + 0.1 * jax.random.normal(jax.random.key(6),
+                                                    (16,)),
+              "weights": jax.random.normal(jax.random.key(7), (16, 50))}
+    x = jax.random.normal(jax.random.key(8), (3, 8, 16))
+    labels = jax.random.randint(jax.random.key(9), (3, 8), 0, 50)
+    mask = jnp.asarray([1.0, 1.0, 0.0])     # the last sequence is padding
+    total, wrong, pred = head.token_loss(params, x, labels, mask)
+    logits = head.apply(params, x)
+    logp = jax.nn.log_softmax(logits, -1)
+    nll = -jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
+    assert abs(float(total) - float(nll[:2].sum())) < 1e-4
+    assert numpy.array_equal(pred, logits.argmax(-1))
+    assert int(wrong) == int((logits.argmax(-1) != labels)[:2].sum())
+    got = jax.grad(lambda p: head.token_loss(p, x, labels, mask)[0])(params)
+    want = jax.grad(lambda p: -(jnp.take_along_axis(jax.nn.log_softmax(
+        head.apply(p, x), -1), labels[..., None], -1)[..., 0][:2]).sum())(
+        params)
+    for name in params:
+        assert numpy.allclose(got[name], want[name], atol=1e-4), name

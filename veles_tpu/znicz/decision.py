@@ -76,7 +76,9 @@ class DecisionGD(DecisionBase, IResultProvider):
             return
         cls = self.minibatch_class
         self.epoch_n_err[cls] = int(self.n_err[0])
-        length = self.class_lengths[cls] or 1
+        # a token loss counts wrong TOKENS: the percentage is of labels
+        length = (self.class_lengths[cls] or 1) * getattr(
+            self.evaluator, "labels_per_sample", 1)
         self.epoch_n_err_pt[cls] = 100.0 * self.epoch_n_err[cls] / length
         # reset the evaluator's accumulator for the next class/epoch
         self.n_err.map_write()[0] = 0

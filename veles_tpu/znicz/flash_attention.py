@@ -543,3 +543,362 @@ def _flash_bwd(causal, scale, block_q, block_k, window, res, g):
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+# -- latent attention: query-key and value heads of different sizes ----------
+#
+# A latent-attention layer (DeepSeek-V2 and its descendants) scores each
+# head with a per-head part (``nope``) plus a rotary part whose KEY is one
+# vector a token, shared by every head, and its value heads are narrower
+# than its query-key heads.  The kernels above read one ``D`` for all
+# operands, so this is a second family: operands stay in their own dtype
+# (bf16 on the training path: the MXU multiplies them at its native rate
+# and sums in float32), the shared rope key is read through an index map
+# that divides the head out (no H-fold copy of it exists in HBM), a
+# causal grid re-references the last needed block instead of fetching
+# blocks it will skip, and the mask is applied on diagonal blocks only.
+# Layout is head-major, [B, H, T, D], which a projection's einsum writes
+# directly (no transpose pass).  Each pallas_call carries a ``name`` so a
+# device trace shows ``mla_flash_fwd`` / ``mla_flash_dq`` /
+# ``mla_flash_dkv``.
+
+#: the fastest of the pairs tried at [2, 32, 8192, .] bfloat16 on the v5e
+#: (PERF.md section 6, PR 28); (1024, 2048) and (512, 2048) pass the
+#: kernels' VMEM
+MLA_BLOCK_Q = 1024
+MLA_BLOCK_K = 1024
+
+
+def _nt(a, b):
+    """a [M, D] x b [N, D] -> [M, N], float32 sums."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    """a [M, K] x b [K, N] -> [M, N], float32 sums."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                    m_scr, l_scr, acc_scr, *, scale, causal, block_q,
+                    block_k):
+    from jax.experimental import pallas as pl
+
+    iq, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def step(masked):
+        s = (_nt(qn_ref[0], kn_ref[0]) + _nt(qr_ref[0], kr_ref[0])) * scale
+        if masked:
+            s = _mask_causal(s, iq, j, block_q, block_k)
+        m = m_scr[...]
+        # every row of a needed causal block sees at least key 0, so
+        # after the first step m is finite; exp(-inf - m) is 0
+        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - new_m)
+        p = jnp.exp(s - new_m)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + _nn(
+            p.astype(v_ref.dtype), v_ref[0])
+        m_scr[...] = new_m
+
+    if causal:
+        # block fully visible: its last key <= the block's first query
+        clear = (j + 1) * block_k - 1 <= iq * block_q
+        needed = j * block_k <= iq * block_q + block_q - 1
+        pl.when(clear)(lambda: step(False))
+        pl.when(jnp.logical_and(needed, jnp.logical_not(clear)))(
+            lambda: step(True))
+    else:
+        step(False)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        l = l_scr[...]
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = m_scr[...] + jnp.log(l)
+
+
+def _mla_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dqn_ref, dqr_ref, dqn_scr, dqr_scr, *, scale,
+                   causal, block_q, block_k):
+    from jax.experimental import pallas as pl
+
+    iq, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        dqn_scr[...] = jnp.zeros_like(dqn_scr)
+        dqr_scr[...] = jnp.zeros_like(dqr_scr)
+
+    def step(masked):
+        kn, kr = kn_ref[0], kr_ref[0]
+        s = (_nt(qn_ref[0], kn) + _nt(qr_ref[0], kr)) * scale
+        if masked:
+            s = _mask_causal(s, iq, j, block_q, block_k)
+        p = jnp.exp(s - lse_ref[0])
+        dov = _nt(do_ref[0], v_ref[0])
+        ds = (p * (dov - delta_ref[0]) * scale).astype(kn.dtype)
+        dqn_scr[...] = dqn_scr[...] + _nn(ds, kn)
+        dqr_scr[...] = dqr_scr[...] + _nn(ds, kr)
+
+    if causal:
+        clear = (j + 1) * block_k - 1 <= iq * block_q
+        needed = j * block_k <= iq * block_q + block_q - 1
+        pl.when(clear)(lambda: step(False))
+        pl.when(jnp.logical_and(needed, jnp.logical_not(clear)))(
+            lambda: step(True))
+    else:
+        step(False)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        dqn_ref[0] = dqn_scr[...].astype(dqn_ref.dtype)
+        dqr_ref[0] = dqr_scr[...].astype(dqr_ref.dtype)
+
+
+def _mla_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dkn_ref, dkr_ref, dv_ref, dkn_scr, dkr_scr,
+                    dv_scr, *, scale, causal, block_q, block_k):
+    """The K block is pinned, Q blocks stream; everything is computed
+    TRANSPOSED ([keys, queries]) so the per-query statistics are rows
+    and no operand is transposed on the way into the MXU."""
+    from jax.experimental import pallas as pl
+
+    jk, i = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(i == 0)
+    def _init():
+        dkn_scr[...] = jnp.zeros_like(dkn_scr)
+        dkr_scr[...] = jnp.zeros_like(dkr_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def step(masked):
+        qn, qr, do = qn_ref[0], qr_ref[0], do_ref[0]
+        st = (_nt(kn_ref[0], qn) + _nt(kr_ref[0], qr)) * scale
+        if masked:
+            keys = jk * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            queries = i * block_q + lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            st = jnp.where(keys > queries, _NEG_INF, st)
+        pt = jnp.exp(st - lse_ref[0])                  # [BK, BQ]
+        dv_scr[...] = dv_scr[...] + _nn(pt.astype(do.dtype), do)
+        dovt = _nt(v_ref[0], do)
+        dst = (pt * (dovt - delta_ref[0]) * scale).astype(qn.dtype)
+        dkn_scr[...] = dkn_scr[...] + _nn(dst, qn)
+        dkr_scr[...] = dkr_scr[...] + _nn(dst, qr)
+
+    if causal:
+        # fully visible: the block's first query >= its last key
+        clear = i * block_q >= jk * block_k + block_k - 1
+        needed = i * block_q + block_q - 1 >= jk * block_k
+        pl.when(clear)(lambda: step(False))
+        pl.when(jnp.logical_and(needed, jnp.logical_not(clear)))(
+            lambda: step(True))
+    else:
+        step(False)
+
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _finish():
+        dkn_ref[0] = dkn_scr[...].astype(dkn_ref.dtype)
+        dkr_ref[0] = dkr_scr[...].astype(dkr_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _mla_specs(heads_per_rope, block_q, block_k, dr, causal):
+    """Block specs of the (q-pinned, k-streamed) grids: forward and dq."""
+    from jax.experimental import pallas as pl
+
+    def kj(i, j):
+        # a causal grid re-references the last block it needs: a block
+        # index that does not change is not fetched again
+        return jnp.minimum(j, (i * block_q + block_q - 1) // block_k) \
+            if causal else j
+
+    def q_spec(d):
+        return pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+
+    def k_spec(d):
+        return pl.BlockSpec((1, block_k, d),
+                            lambda b, i, j: (b, kj(i, j), 0))
+    kr_spec = pl.BlockSpec(
+        (1, block_k, dr),
+        lambda b, i, j: (b // heads_per_rope, kj(i, j), 0))
+    return q_spec, k_spec, kr_spec
+
+
+def _mla_fwd_bh(qn, qr, kn, kr, v, scale, causal, block_q, block_k):
+    """[BH, T, .] operands (``kr`` [BH / heads_per_rope, T, dr]) ->
+    (out [BH, T, dv], lse [BH, T, 1])."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, t, dn = qn.shape
+    dr, dv = qr.shape[-1], v.shape[-1]
+    q_spec, k_spec, kr_spec = _mla_specs(
+        bh // kr.shape[0], block_q, block_k, dr, causal)
+    return pl.pallas_call(
+        functools.partial(_mla_fwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        grid=(bh, t // block_q, t // block_k),
+        in_specs=[q_spec(dn), q_spec(dr), k_spec(dn), kr_spec, k_spec(dv)],
+        out_specs=[q_spec(dv), q_spec(1)],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, dv), v.dtype),
+                   jax.ShapeDtypeStruct((bh, t, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="mla_flash_fwd", interpret=_interpret())(qn, qr, kn, kr, v)
+
+
+def _mla_bwd_bh(qn, qr, kn, kr, v, out, lse, do, scale, causal, block_q,
+                block_k):
+    """-> (dqn, dqr, dkn, dkr [BH, T, dr] float32 per head, dv)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, t, dn = qn.shape
+    dr, dv = qr.shape[-1], v.shape[-1]
+    heads_per_rope = bh // kr.shape[0]
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)            # [BH, T, 1]
+    q_spec, k_spec, kr_spec = _mla_specs(
+        heads_per_rope, block_q, block_k, dr, causal)
+    semantics = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    dqn, dqr = pl.pallas_call(
+        functools.partial(_mla_dq_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        grid=(bh, t // block_q, t // block_k),
+        in_specs=[q_spec(dn), q_spec(dr), k_spec(dn), kr_spec, k_spec(dv),
+                  q_spec(dv), q_spec(1), q_spec(1)],
+        out_specs=[q_spec(dn), q_spec(dr)],
+        out_shape=[jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+                   jax.ShapeDtypeStruct(qr.shape, qr.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_q, dn), jnp.float32),
+                        pltpu.VMEM((block_q, dr), jnp.float32)],
+        compiler_params=semantics, name="mla_flash_dq",
+        interpret=_interpret())(qn, qr, kn, kr, v, do, lse, delta)
+
+    # dk/dv pass: per-query statistics as rows [BH, 1, T] (the same
+    # bytes as the columns above)
+    def qi(j, i):
+        return jnp.maximum(i, (j * block_k) // block_q) if causal else i
+
+    def qs_spec(d):
+        return pl.BlockSpec((1, block_q, d),
+                            lambda b, j, i: (b, qi(j, i), 0))
+
+    def ks_spec(d):
+        return pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+    row = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qi(j, i)))
+    dkn, dkr, dvv = pl.pallas_call(
+        functools.partial(_mla_dkv_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        grid=(bh, t // block_k, t // block_q),
+        in_specs=[qs_spec(dn), qs_spec(dr), ks_spec(dn),
+                  pl.BlockSpec((1, block_k, dr),
+                               lambda b, j, i: (b // heads_per_rope, j, 0)),
+                  ks_spec(dv), qs_spec(dv), row, row],
+        out_specs=[ks_spec(dn), ks_spec(dr), ks_spec(dv)],
+        out_shape=[jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+                   jax.ShapeDtypeStruct((bh, t, dr), jnp.float32),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, dn), jnp.float32),
+                        pltpu.VMEM((block_k, dr), jnp.float32),
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
+        compiler_params=semantics, name="mla_flash_dkv",
+        interpret=_interpret())(qn, qr, kn, kr, v, do,
+                                lse.reshape(bh, 1, t),
+                                delta.reshape(bh, 1, t))
+    return dqn, dqr, dkn, dkr, dvv
+
+
+def mla_attention_reference(q_nope, q_rope, k_nope, k_rope, v, causal=True,
+                            scale=None):
+    """The same attention with explicit [T, T] scores, head-major
+    [B, H, T, D] operands (``k_rope`` [B, T, dr], one a token, or
+    [B, H, T, dr]): the oracle of the kernels and the path of a T they
+    cannot tile."""
+    scale = scale if scale is not None else 1.0 / math.sqrt(
+        q_nope.shape[-1] + q_rope.shape[-1])
+    rope = "btd" if k_rope.ndim == 3 else "bhtd"
+    s = (jnp.einsum("bhqd,bhkd->bhqk", q_nope, k_nope,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhqd,%s->bhqk" % rope.replace("t", "k"), q_rope,
+                      k_rope, preferred_element_type=jnp.float32)) * scale
+    if causal:
+        t = s.shape[-1]
+        s = jnp.where(jnp.arange(t)[None, :] > jnp.arange(t)[:, None],
+                      _NEG_INF, s)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v,
+                      preferred_element_type=jnp.float32).astype(v.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _mla_flash(q_nope, q_rope, k_nope, k_rope, v, causal, scale, block_q,
+               block_k):
+    return _mla_flash_fwd(q_nope, q_rope, k_nope, k_rope, v, causal, scale,
+                          block_q, block_k)[0]
+
+
+def _flat(x):
+    return x.reshape((-1,) + x.shape[-2:])
+
+
+def _mla_flash_fwd(q_nope, q_rope, k_nope, k_rope, v, causal, scale,
+                   block_q, block_k):
+    out, lse = _mla_fwd_bh(_flat(q_nope), _flat(q_rope), _flat(k_nope),
+                           _flat(k_rope), _flat(v), scale, causal, block_q,
+                           block_k)
+    out = out.reshape(v.shape)
+    return out, (q_nope, q_rope, k_nope, k_rope, v, out, lse)
+
+
+def _mla_flash_bwd(causal, scale, block_q, block_k, res, g):
+    q_nope, q_rope, k_nope, k_rope, v, out, lse = res
+    dqn, dqr, dkn, dkr, dv = _mla_bwd_bh(
+        _flat(q_nope), _flat(q_rope), _flat(k_nope), _flat(k_rope),
+        _flat(v), _flat(out), lse, _flat(g), scale, causal, block_q,
+        block_k)
+    dkr = dkr.reshape(q_rope.shape)
+    if k_rope.ndim == 3:        # one key for all heads: their sum
+        dkr = dkr.sum(axis=1)
+    return (dqn.reshape(q_nope.shape), dqr.reshape(q_rope.shape),
+            dkn.reshape(k_nope.shape), dkr.astype(k_rope.dtype),
+            dv.reshape(v.shape))
+
+
+_mla_flash.defvjp(_mla_flash_fwd, _mla_flash_bwd)
+
+
+def mla_flash_attention(q_nope, q_rope, k_nope, k_rope, v, causal=True,
+                        scale=None, block_q=MLA_BLOCK_Q,
+                        block_k=MLA_BLOCK_K):
+    """Latent attention, head-major: ``q_nope``/``k_nope`` [B, H, T, dn],
+    ``q_rope`` [B, H, T, dr], ``k_rope`` [B, T, dr] (one rope key a
+    token, read by every head) or [B, H, T, dr], ``v`` [B, H, T, dv] ->
+    [B, H, T, dv].  Scores are ``(q_nope.k_nope + q_rope.k_rope) *
+    scale`` (default ``1 / sqrt(dn + dr)``).  Falls back to
+    :func:`mla_attention_reference` for a T that cannot be tiled."""
+    scale = scale if scale is not None else 1.0 / math.sqrt(
+        q_nope.shape[-1] + q_rope.shape[-1])
+    blocks = _blocks(q_nope.shape[2], block_q, block_k)
+    if blocks is None:
+        _warn_fallback(q_nope.shape[2])
+        return mla_attention_reference(q_nope, q_rope, k_nope, k_rope, v,
+                                       causal, scale)
+    return _mla_flash(q_nope, q_rope, k_nope, k_rope, v, causal, scale,
+                      *blocks)

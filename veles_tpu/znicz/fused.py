@@ -77,6 +77,9 @@ class FusedTrainStep(Unit, IResultProvider):
         self.compute_confusion_matrix = bool(
             kwargs.get("compute_confusion_matrix", True))
         self.loss = None
+        #: {class name: {unit scope: {counter: total}}} of the forward
+        #: units that count (``apply_stats``), filed at each class end
+        self.unit_stats = {}
         self.output = Array()      # last forward's output (for consumers)
         self.max_idx = Array()
         # deterministic per-step seed for stochastic units (dropout,
@@ -145,12 +148,37 @@ class FusedTrainStep(Unit, IResultProvider):
         if cdtype is not None:
             cdtype = jnp.dtype(cdtype)
 
-        def net_apply(params, x, with_logits, seed):
+        # what a unit may ask of the trainer (znicz/transformer.py):
+        # FLOAT32_PARAMS, tensors the boundary cast leaves float32;
+        # ``remat``, jax.checkpoint around its apply; ``apply_stats``,
+        # an apply that also returns counters for the accumulator
+        keep_f32 = [getattr(f, "FLOAT32_PARAMS", ()) for f in forwards]
+        with_stats = [hasattr(f, "apply_stats") for f in forwards]
+
+        def applier(fwd):
+            fn = getattr(fwd, "apply_stats", fwd.apply)
+            return jax.checkpoint(fn) if getattr(fwd, "remat", False) \
+                else fn
+        appliers = [applier(f) for f in forwards]
+
+        def net_body(params, x, seed):
+            """The chain up to the last unit: (its input, the parameters
+            as the chain computes with them, the units' counters)."""
             if cdtype is not None:
                 # cast once at the boundary; XLA keeps everything in
                 # compute dtype through the chain (MXU native rate)
-                params = jax.tree.map(lambda p: p.astype(cdtype), params)
-                x = x.astype(cdtype)
+                # (two forms: where no unit keeps a tensor float32 the
+                # cast is traced exactly as it always was, so the older
+                # models' programs and cache entries stay the same)
+                if any(keep_f32):
+                    params = [{k: p if k in keep else p.astype(cdtype)
+                               for k, p in layer.items()}
+                              for layer, keep in zip(params, keep_f32)]
+                else:
+                    params = jax.tree.map(lambda p: p.astype(cdtype),
+                                          params)
+                if jnp.issubdtype(x.dtype, jnp.floating):
+                    x = x.astype(cdtype)
             h = x
             train = seed is not None
             if train and has_stochastic:
@@ -161,13 +189,25 @@ class FusedTrainStep(Unit, IResultProvider):
                 impl = root.common.engine.get("rng_impl",
                                               "threefry2x32")
                 key = jax.random.key(seed, impl=impl)
+            stats = {}
             for i, fwd in enumerate(forwards[:-1]):
+                # token ids are not cast (bfloat16 holds integers up to
+                # 256); the rows they select are
+                ids = not jnp.issubdtype(h.dtype, jnp.floating)
                 with jax.named_scope(scopes[i]):
                     if train and fwd.stochastic:
                         h = fwd.apply_train(params[i], h,
                                             jax.random.fold_in(key, i))
+                    elif with_stats[i]:
+                        h, stats[scopes[i]] = appliers[i](params[i], h)
                     else:
-                        h = fwd.apply(params[i], h)
+                        h = appliers[i](params[i], h)
+                if ids and cdtype is not None:
+                    h = h.astype(cdtype)
+            return h, params, stats
+
+        def net_apply(params, x, with_logits, seed):
+            h, params, _ = net_body(params, x, seed)
             last = forwards[-1]
             with jax.named_scope(scopes[-1]):
                 if with_logits and softmax_head:
@@ -175,6 +215,17 @@ class FusedTrainStep(Unit, IResultProvider):
                 return last.apply(params[-1], h)
 
         def loss_fn(params, x, labels_or_targets, mask, seed=None):
+            if loss_kind == "token":
+                # the head takes the projection and the loss over blocks
+                # of tokens: [tokens, V] float32 logits never exist whole
+                h, params, stats = net_body(params, x, seed)
+                with jax.named_scope(scopes[-1]):
+                    total, wrong, pred = forwards[-1].token_loss(
+                        params[-1], h, labels_or_targets, mask)
+                tokens = jnp.maximum(
+                    mask.sum() * labels_or_targets.shape[1], 1.0)
+                return total / tokens, (pred, {
+                    "n_err": wrong, "loss_sum": total, "units": stats})
             out = net_apply(params, x, True, seed)
             # the loss itself is f32: bf16 log-sum-exp/reduction noise
             # would feed straight into the gradients' scale
@@ -244,6 +295,18 @@ class FusedTrainStep(Unit, IResultProvider):
             output otherwise.  The loss itself consumed the logits."""
             return jax.nn.softmax(out) if softmax_head else out
 
+        def fold(macc, out, labels_or_targets, mask):
+            """(accumulator with this step folded in, observable
+            output).  A token loss brings its counts with it (wrong
+            tokens, summed loss, the units' counters: no one-hot, no
+            confusion matrix) and shows the predicted ids."""
+            if loss_kind == "token":
+                pred, counts = out
+                with jax.named_scope("metrics"):
+                    return jax.tree.map(jnp.add, macc, counts), pred
+            out = observable(out)
+            return accumulate(macc, out, labels_or_targets, mask), out
+
         def train_step(params, opt, macc, x, y, size, seed, lr_scale):
             mask = (jnp.arange(x.shape[0]) < size).astype(jnp.float32)
             (loss, out), grads = jax.value_and_grad(
@@ -267,22 +330,29 @@ class FusedTrainStep(Unit, IResultProvider):
                         layer_o[name] = st
                 new_params.append(layer_p)
                 new_opt.append(layer_o)
-            out = observable(out)
-            macc = accumulate(macc, out, y, mask)
+            macc, out = fold(macc, out, y, mask)
             return new_params, new_opt, macc, loss, out
 
         def eval_step(params, macc, x, y, size):
             mask = (jnp.arange(x.shape[0]) < size).astype(jnp.float32)
             loss, out = loss_fn(params, x, y, mask)
-            out = observable(out)
-            return accumulate(macc, out, y, mask), loss, out
+            macc, out = fold(macc, out, y, mask)
+            return macc, loss, out
 
         # the metric accumulator stays ON DEVICE between steps and is
         # flushed to the host only at class boundaries — per-step int()
         # pulls would serialize the pipeline on a device sync.  int32 for
         # error counts (exact); float32 for mse sums (flushed per class,
         # so drift stays bounded by one epoch)
+        self._unit_stats_ = {
+            scopes[i]: f.stats_shapes() for i, f in enumerate(forwards[:-1])
+            if with_stats[i]}
         self._macc_ = self._macc_init()
+        # the chain's evaluation-mode output (logits for a softmax or a
+        # token head) as a function of the parameters: what a comparison
+        # with a reference reads; compiled only if called
+        self._forward_ = jax.jit(
+            lambda params, x: net_apply(params, x, True, None))
         self._train_step_ = jax.jit(train_step, donate_argnums=(0, 1, 2))
         self._eval_step_ = jax.jit(eval_step, donate_argnums=(1,))
         # the gather-in-step path needs the dataset resident on a real
@@ -354,6 +424,13 @@ class FusedTrainStep(Unit, IResultProvider):
     def _macc_init(self):
         """Fresh on-device metric accumulator pytree."""
         import jax.numpy as jnp
+        if self.loss_kind == "token":
+            return {"n_err": jnp.zeros((), jnp.int32),
+                    "loss_sum": jnp.zeros((), jnp.float32),
+                    "units": {scope: {name: jnp.zeros(shape, jnp.int32)
+                                      for name, shape in shapes.items()}
+                              for scope, shapes in
+                              self._unit_stats_.items()}}
         if self.loss_kind == "softmax":
             c = self._n_classes if self.compute_confusion_matrix else 0
             return (jnp.zeros((), jnp.int32),
@@ -387,10 +464,26 @@ class FusedTrainStep(Unit, IResultProvider):
         events.set_work(self.epoch_number)
         with events.timed("step.run", cls=loader_mod.CLASS_NAME[cls],
                           epoch=self.epoch_number) as span:
-            span.count(steps=1, images=size)
+            span.count(**self._work(1, size))
             self._run_minibatch(size, cls == loader_mod.TRAIN)
             if bool(self.last_minibatch):
                 self._finish_class()
+
+    @property
+    def labels_per_sample(self):
+        """Labels a sample carries: a sequence's tokens under the token
+        loss (``n_err`` then counts wrong tokens), else one."""
+        if self.loss_kind == "token":
+            return int(self.forwards[0].input_shape[1])
+        return 1
+
+    def _work(self, steps, images):
+        """What a ``step.run`` span counts: steps and samples, and for a
+        token loss the tokens those samples hold."""
+        work = {"steps": steps, "images": images}
+        if self.loss_kind == "token":
+            work["tokens"] = images * self.labels_per_sample
+        return work
 
     def _run_minibatch(self, size, train):
         """Hand one minibatch to the jitted step (``step.dispatch``: the
@@ -464,7 +557,20 @@ class FusedTrainStep(Unit, IResultProvider):
     def _pull_metrics(self, macc):
         """File what the accumulator ``macc`` holds: the confusion matrix
         on the device, the scalars through one blocking read."""
-        if self.loss_kind == "softmax":
+        if self.loss_kind == "token":
+            import jax
+            got = self._read_scalars(macc)
+            self.n_err.map_write()[0] += int(got["n_err"])
+            self.metrics.map_write()[0] += float(got["loss_sum"])
+            # the units' counters (per-expert token counts, rows of the
+            # grouped product), summed per class since the workflow
+            # started
+            name = loader_mod.CLASS_NAME[self.minibatch_class]
+            totals = self.unit_stats.get(name) or jax.tree.map(
+                lambda a: numpy.zeros(a.shape, numpy.int64), got["units"])
+            self.unit_stats[name] = jax.tree.map(
+                lambda total, new: total + new, totals, got["units"])
+        elif self.loss_kind == "softmax":
             n_err, cm, maxerr = macc
             if self.compute_confusion_matrix:
                 # the [C, C] matrix stays ON DEVICE: pulling it per class
@@ -503,7 +609,7 @@ class FusedTrainStep(Unit, IResultProvider):
         device, which is the scan's remaining time where the epilogue was
         enqueued in time."""
         import jax
-        for leaf in scalars:
+        for leaf in jax.tree.leaves(scalars):
             leaf.copy_to_host_async()
         with events.timed("step.read_metrics"):
             return jax.device_get(scalars)

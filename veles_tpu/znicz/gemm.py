@@ -330,3 +330,27 @@ def _pm_bwd(level, interpret, res, g):
 
 
 precise_matmul.defvjp(_pm_fwd, _pm_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``out[r] = lhs[r] @ rhs[g(r)]`` for rows sorted by group: ``lhs``
+    [M, K], ``rhs`` [G, K, N], ``group_sizes`` [G] int32 whose sum may be
+    less than M (the rows past it are not computed and hold nothing).
+    float32 sums, result in ``lhs``'s dtype.  The expert layer's product
+    (``znicz/transformer.py``); differentiable in ``lhs`` and ``rhs``.
+
+    JAX's megablox kernels (``gmm`` forward and for the rows' gradient,
+    ``tgmm`` for the weights'): their grid covers the tiles that hold
+    rows and no others, so the work follows the data's group sizes
+    however uneven.  Against ``lax.ragged_dot`` at the expert layer's
+    shapes on the v5e they were 2-8 % faster on the 2048 x 1536 product
+    and 10-25 % on the 768 x 2048 one, forward and backward (PERF.md
+    section 6, PR 28), so they ship and nothing chooses."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    tm = next(t for t in (512, 256, 128, 64, 32, 16, 8, 1) if m % t == 0)
+    tn = next((t for t in (1024, 512, 256, 128) if n % t == 0), n)
+    return megablox.gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype,
+                        (tm, min(k, 1024), tn),
+                        interpret=_interpret_default())

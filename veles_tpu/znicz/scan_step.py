@@ -192,7 +192,7 @@ class ScanEpochStep(FusedTrainStep):
                     ld.shuffle()
             with events.timed("step.index_matrix"):
                 idx, sizes = self._class_index_matrix(cls)
-            span.count(steps=len(sizes), images=int(sizes.sum()))
+            span.count(**self._work(len(sizes), int(sizes.sum())))
             self._dispatch(cls, idx, sizes)
             # drive the loader protocol so Decision sees normal class ends
             ld.minibatch_class = cls
@@ -233,7 +233,7 @@ class ScanEpochStep(FusedTrainStep):
                 self._epochs_done += 1
             idx = numpy.concatenate([c[0] for c in chunks])
             sizes = numpy.concatenate([c[1] for c in chunks])
-            span.count(steps=len(sizes), images=int(sizes.sum()))
+            span.count(**self._work(len(sizes), int(sizes.sum())))
             self._dispatch(loader_mod.TRAIN, idx, sizes)
             ld.minibatch_class = loader_mod.TRAIN
             self._finish_class()

@@ -127,7 +127,43 @@ class RProp(Solver):
         return -xp.sign(grad) * step, (step, grad)
 
 
-_SOLVERS = {c.name: c for c in (SGD, Momentum, AdaGrad, AdaDelta, RProp)}
+class AdamW(Solver):
+    """Adam with decoupled weight decay (Loshchilov & Hutter 2019):
+    ``m = b1 m + (1 - b1) g``, ``v = b2 v + (1 - b2) g^2``, ``delta =
+    -lr (m_hat / (sqrt(v_hat) + eps) + weight_decay w)`` with the bias
+    corrections ``m_hat = m / (1 - b1^t)``, ``v_hat = v / (1 - b2^t)``.
+    The decay acts on matrices only (``param.ndim >= 2``): norms' weights
+    and biases are left alone, the usual convention.  State: ``(m, v,
+    t)``, moments in the parameter's dtype (float32 master weights give
+    float32 moments)."""
+
+    name = "adamw"
+
+    def init(self, param, xp=numpy):
+        return (xp.zeros_like(param), xp.zeros_like(param),
+                xp.zeros((), xp.int32))
+
+    def update(self, grad, param, state, lr, xp=numpy):
+        m, v, t = state
+        b1 = self.hyper.get("beta1", 0.9)
+        b2 = self.hyper.get("beta2", 0.999)
+        eps = self.hyper.get("epsilon", 1e-8)
+        decay = self.hyper.get("weight_decay", 0.0) \
+            if param.ndim >= 2 else 0.0
+        t = t + 1
+        m = b1 * m + (1 - b1) * grad
+        v = b2 * v + (1 - b2) * grad * grad
+        steps = t.astype(param.dtype)
+        m_hat = m / (1 - b1 ** steps)
+        v_hat = v / (1 - b2 ** steps)
+        step = m_hat / (xp.sqrt(v_hat) + eps)
+        if decay:
+            step = step + decay * param
+        return -lr * step, (m, v, t)
+
+
+_SOLVERS = {c.name: c for c in (SGD, Momentum, AdaGrad, AdaDelta, RProp,
+                                AdamW)}
 
 
 def factory(name, **hyper):

@@ -196,7 +196,15 @@ class StandardWorkflow(Workflow):
 
         # evaluator (graph mode only — fused mode computes the loss and
         # metrics inside the step) + decision
-        if self.loss_function == "softmax":
+        if self.loss_function == "token":
+            # next-token loss over [B, S] labels: the head computes it
+            # inside the fused step (znicz/transformer.py); n_err counts
+            # wrong tokens
+            if not self.fused:
+                raise ValueError("loss_function='token' needs the fused "
+                                 "or the epoch-scan trainer")
+            self.decision = DecisionGD(self, **self.decision_config)
+        elif self.loss_function == "softmax":
             if not self.fused:
                 self.evaluator = EvaluatorSoftmax(self)
             self.decision = DecisionGD(self, **self.decision_config)

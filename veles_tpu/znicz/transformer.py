@@ -1,0 +1,523 @@
+"""Transformer blocks as Znicz forward units: a token embedding, a
+latent-attention block, a gated-MLP block, an expert block and a
+normalised head over a vocabulary slice.
+
+Each is a :class:`ForwardBase` with a ``MAPPING``, so a
+``StandardWorkflow`` ``layers`` list builds a decoder out of them and the
+fused / epoch-scan trainers chain their pure ``apply(params, x)`` like any
+other layer's.  Pre-norm and residual live INSIDE a block (``x + f(norm(
+x))``), so the chain stays a chain.  The blocks are driven by the keys a
+public ``config.json`` of the DeepSeek-V2/V3 family uses
+(``qk_nope_head_dim``, ``kv_lora_rank``, ``n_routed_experts``, ...) and by
+the share of a deployment this chip holds (``experts_held``,
+``experts_offset``), never by a model's name.
+
+Arithmetic under ``--compute-dtype bfloat16``: matrix operands bfloat16,
+sums float32; the router, every norm's statistics, the rotary angles and
+the loss float32 (``FLOAT32_PARAMS`` names the tensors the trainer's
+boundary cast leaves alone).  Weights are drawn ON THE DEVICE from the
+unit's seed (``init_params``): a host draw of half a billion numbers and
+its upload would be tens of seconds of set-up.
+
+What a unit tells the trainer (``fused.py`` reads these attributes of any
+forward unit): ``remat`` asks for ``jax.checkpoint`` around ``apply``
+(the block's activations are recomputed in the backward pass);
+``apply_stats`` returns ``(y, stats)`` with counters the step's device
+accumulator sums; ``token_loss`` (the head) folds the vocabulary
+projection and the loss over blocks of tokens.
+"""
+
+import math
+
+import numpy
+
+from ..memory import Array
+from .nn_units import ForwardBase, GradientDescentBase
+
+
+def rms_norm(x, weight, eps):
+    """``x / sqrt(mean(x^2) + eps) * weight``, statistics in float32,
+    result in ``x``'s dtype."""
+    import jax.numpy as jnp
+    xf = x.astype(jnp.float32)
+    inv = jnp.reciprocal(jnp.sqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                                  + eps))
+    return (xf * inv * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope_interleaved(x, theta):
+    """Rotary embedding over the last axis of ``x`` [..., T, D] with the
+    pairs ``(2i, 2i+1)`` (``rope_interleave``), positions 0..T-1, angles
+    and rotation in float32, result in ``x``'s dtype."""
+    import jax.numpy as jnp
+    t, d = x.shape[-2], x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)          # [T, D/2]
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def gated_mlp(h, gate, up, down):
+    """``(silu(h gate) * (h up)) down`` with float32 sums, in ``h``'s
+    dtype between the products."""
+    import jax
+    import jax.numpy as jnp
+    g = jnp.dot(h, gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(h, up, preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(g) * u).astype(h.dtype)
+    return jnp.dot(a, down, preferred_element_type=jnp.float32)
+
+
+class BlockBase(ForwardBase):
+    """A forward unit whose parameters are a dictionary of named tensors
+    drawn on the device.  Subclasses give ``tensor_shapes()`` ->
+    ``{name: (shape, kind)}`` with ``kind`` ``"matrix"`` (normal,
+    ``weights_stddev``), ``"ones"`` (a norm's weight) or ``"bias"``
+    (normal, ``bias_stddev``: an untrained buffer)."""
+
+    hide_from_registry = True
+    #: tensors that stay float32 under a bfloat16 compute dtype
+    FLOAT32_PARAMS = ("norm",)
+    #: ask the trainer for jax.checkpoint around apply
+    remat = True
+
+    def __init__(self, workflow, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.weights_stddev = float(kwargs.get("weights_stddev", 0.02))
+        self.bias_stddev = float(kwargs.get("bias_stddev", 0.01))
+        self.seed = int(kwargs.get("seed", 0))
+        self.rms_norm_eps = float(kwargs.get("rms_norm_eps", 1e-6))
+        self.tensors = {}
+        self.exports = []
+
+    def tensor_shapes(self):
+        raise NotImplementedError
+
+    def init_params(self):
+        import jax
+        import jax.numpy as jnp
+        shapes = self.tensor_shapes()
+        stddev, bias_stddev = self.weights_stddev, self.bias_stddev
+
+        def draw(key):
+            out = {}
+            for i, (name, (shape, kind)) in enumerate(sorted(
+                    shapes.items())):
+                if kind == "ones":
+                    out[name] = jnp.ones(shape, jnp.float32)
+                else:
+                    scale = stddev if kind == "matrix" else bias_stddev
+                    out[name] = scale * jax.random.normal(
+                        jax.random.fold_in(key, i), shape, jnp.float32)
+            return out
+        # two 32-bit words: a seed may be wider than int32
+        key = jnp.asarray([self.seed >> 32 & 0xFFFFFFFF,
+                           self.seed & 0xFFFFFFFF], jnp.uint32)
+        drawn = jax.jit(lambda k: draw(jax.random.wrap_key_data(
+            k, impl="threefry2x32")))(key)
+        self.set_params(drawn)
+        self.exports = sorted(self.tensors)
+
+    @property
+    def params(self):
+        return {name: a.devmem for name, a in self.tensors.items()}
+
+    def set_params(self, params):
+        for name, value in params.items():
+            self.tensors.setdefault(name, Array()).devmem = value
+
+    @property
+    def host_params(self):
+        return {name: a.map_read() for name, a in self.tensors.items()}
+
+    def set_host_params(self, params):
+        for name, value in params.items():
+            self.tensors.setdefault(name, Array()).mem = numpy.asarray(
+                value, numpy.float32)
+
+    def output_shape_for(self, input_shape):
+        return tuple(input_shape)
+
+    def initialize(self, device=None, **kwargs):
+        from ..verified import verify_contract
+        verify_contract(self, ForwardBase)
+        super(ForwardBase, self).initialize(device=device, **kwargs)
+        if not self.tensors:
+            self.init_params()
+        shape = tuple(self.output_shape_for(self.input_shape))
+        if not self.output or tuple(self.output.shape) != shape:
+            # a shape for the next unit to size itself by, not a buffer:
+            # [B, S, V] zeros would be a gigabyte of host memory
+            self.output.reset(numpy.broadcast_to(
+                numpy.zeros((), numpy.float32), shape))
+
+    def _norm(self, x, weight):
+        return rms_norm(x, weight, self.rms_norm_eps)
+
+
+class TokenEmbedding(BlockBase):
+    """Token ids [B, S] (any integer dtype) -> [B, S, hidden].  The table
+    stays float32 (its gradient is a scatter-add over repeated ids, which
+    bfloat16 sums would lose); the rows come out float32 and the trainer casts
+    them to its compute dtype."""
+
+    MAPPING = "token_embedding"
+    FLOAT32_PARAMS = ("weights",)
+    remat = False
+
+    def __init__(self, workflow, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.vocab_size = int(kwargs["vocab_size"])
+        self.hidden_size = int(kwargs["hidden_size"])
+
+    def tensor_shapes(self):
+        return {"weights": ((self.vocab_size, self.hidden_size), "matrix")}
+
+    def output_shape_for(self, input_shape):
+        return tuple(input_shape) + (self.hidden_size,)
+
+    def apply(self, params, x):
+        import jax.numpy as jnp
+        return jnp.take(params["weights"], x, axis=0)
+
+
+class LatentAttentionBlock(BlockBase):
+    """``x + Attention(RMSNorm(x))`` with multi-head latent attention
+    (DeepSeek-V2): uncompressed queries of ``qk_nope_head_dim +
+    qk_rope_head_dim`` a head; keys and values from one compressed
+    ``kv_lora_rank`` latent a token (normalised) plus ONE rotary key a
+    token shared by every head; value heads of ``v_head_dim``; causal.
+    No bias anywhere.  The core runs the ``mla_flash_*`` kernels
+    (``flash_attention.py``) on a TPU and explicit scores elsewhere."""
+
+    MAPPING = "latent_attention_block"
+    FLOAT32_PARAMS = ("norm", "kv_norm")
+
+    def __init__(self, workflow, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.hidden_size = int(kwargs["hidden_size"])
+        self.heads = int(kwargs["num_attention_heads"])
+        self.nope = int(kwargs["qk_nope_head_dim"])
+        self.rope = int(kwargs["qk_rope_head_dim"])
+        self.v_dim = int(kwargs["v_head_dim"])
+        self.kv_rank = int(kwargs["kv_lora_rank"])
+        self.rope_theta = float(kwargs.get("rope_theta", 10000.0))
+        self.use_pallas = kwargs.get("use_pallas")
+
+    def tensor_shapes(self):
+        d, h = self.hidden_size, self.heads
+        return {
+            "norm": ((d,), "ones"),
+            "wq": ((d, h * (self.nope + self.rope)), "matrix"),
+            "wkva": ((d, self.kv_rank + self.rope), "matrix"),
+            "kv_norm": ((self.kv_rank,), "ones"),
+            "wkvb": ((self.kv_rank, h * (self.nope + self.v_dim)),
+                     "matrix"),
+            "wo": ((h * self.v_dim, d), "matrix"),
+        }
+
+    def _core(self, q_nope, q_rope, k_nope, k_rope, v):
+        from .flash_attention import (mla_attention_reference,
+                                      mla_flash_attention)
+        from .nn_units import resolve_use_pallas
+        if resolve_use_pallas(self.use_pallas, self.device, tpu_auto=True):
+            return mla_flash_attention(q_nope, q_rope, k_nope, k_rope, v)
+        return mla_attention_reference(q_nope, q_rope, k_nope, k_rope, v)
+
+    def apply(self, params, x):
+        import jax
+        import jax.numpy as jnp
+        f32 = jnp.float32
+        d, h = self.hidden_size, self.heads
+        hn = self._norm(x, params["norm"])
+        with jax.named_scope("mla/q"):
+            q = jnp.einsum("bsd,dhk->bhsk", hn, params["wq"].reshape(
+                d, h, self.nope + self.rope),
+                preferred_element_type=f32).astype(x.dtype)
+        with jax.named_scope("mla/kv_down"):
+            ckv = jnp.dot(hn, params["wkva"], preferred_element_type=f32)
+            latent = self._norm(ckv[..., :self.kv_rank].astype(x.dtype),
+                                params["kv_norm"])
+            k_rope = ckv[..., self.kv_rank:]           # [B, S, rope], f32
+        with jax.named_scope("mla/kv_up"):
+            kv = jnp.einsum("bsr,rhk->bhsk", latent, params["wkvb"].reshape(
+                self.kv_rank, h, self.nope + self.v_dim),
+                preferred_element_type=f32).astype(x.dtype)
+        with jax.named_scope("mla/rope"):
+            q_rope = rope_interleaved(q[..., self.nope:], self.rope_theta)
+            k_rope = rope_interleaved(k_rope, self.rope_theta).astype(
+                x.dtype)
+        with jax.named_scope("mla/core"):
+            out = self._core(q[..., :self.nope], q_rope,
+                             kv[..., :self.nope], k_rope,
+                             kv[..., self.nope:])
+        with jax.named_scope("mla/out"):
+            y = jnp.einsum("bhsv,hvd->bsd", out, params["wo"].reshape(
+                h, self.v_dim, d), preferred_element_type=f32)
+        return (x.astype(f32) + y).astype(x.dtype)
+
+
+class GatedMLPBlock(BlockBase):
+    """``x + (silu(h Wg) * h Wu) Wd`` with ``h = RMSNorm(x)``."""
+
+    MAPPING = "gated_mlp_block"
+
+    def __init__(self, workflow, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.hidden_size = int(kwargs["hidden_size"])
+        self.intermediate_size = int(kwargs["intermediate_size"])
+
+    def tensor_shapes(self):
+        d, f = self.hidden_size, self.intermediate_size
+        return {"norm": ((d,), "ones"), "gate": ((d, f), "matrix"),
+                "up": ((d, f), "matrix"), "down": ((f, d), "matrix")}
+
+    def apply(self, params, x):
+        import jax.numpy as jnp
+        hn = self._norm(x, params["norm"])
+        y = gated_mlp(hn, params["gate"], params["up"], params["down"])
+        return (x.astype(jnp.float32) + y).astype(x.dtype)
+
+
+class ExpertBlock(BlockBase):
+    """``x + sum_e w_e E_e(h) + Shared(h)``, ``h = RMSNorm(x)``: a
+    float32 sigmoid router over ALL ``n_routed_experts``, the
+    ``num_experts_per_tok`` largest ``s + b`` chosen (``b``: the
+    ``noaux_tc`` correction bias, used for the choice only), weights
+    ``routed_scaling_factor * s / sum s`` over the chosen.  This chip
+    holds ``experts_held`` experts from ``experts_offset``; tokens routed
+    to them are sorted by expert and go through a grouped matrix product
+    whose group sizes are the data's (``gemm.grouped_matmul``); what the
+    absent experts would add is left out.  No capacity: the row buffer
+    holds every token's every choice, so no token is ever dropped."""
+
+    MAPPING = "expert_block"
+    FLOAT32_PARAMS = ("norm", "router", "router_bias")
+
+    def __init__(self, workflow, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.hidden_size = int(kwargs["hidden_size"])
+        self.width = int(kwargs["moe_intermediate_size"])
+        self.n_experts = int(kwargs["n_routed_experts"])
+        self.top_k = int(kwargs["num_experts_per_tok"])
+        self.n_shared = int(kwargs.get("n_shared_experts", 0))
+        self.scaling = float(kwargs.get("routed_scaling_factor", 1.0))
+        self.norm_topk = bool(kwargs.get("norm_topk_prob", True))
+        self.held = int(kwargs.get("experts_held", self.n_experts))
+        self.offset = int(kwargs.get("experts_offset", 0))
+        if not 0 <= self.offset <= self.n_experts - self.held:
+            raise ValueError("experts [%d, %d) are not among the %d routed"
+                             % (self.offset, self.offset + self.held,
+                                self.n_experts))
+
+    def tensor_shapes(self):
+        d, f, e = self.hidden_size, self.width, self.held
+        shapes = {"norm": ((d,), "ones"),
+                  "router": ((d, self.n_experts), "matrix"),
+                  "router_bias": ((self.n_experts,), "bias"),
+                  # gate and up side by side: one grouped product
+                  "experts_gate_up": ((e, d, 2 * f), "matrix"),
+                  "experts_down": ((e, f, d), "matrix")}
+        if self.n_shared:
+            fs = self.n_shared * f
+            shapes.update({"shared_gate": ((d, fs), "matrix"),
+                           "shared_up": ((d, fs), "matrix"),
+                           "shared_down": ((fs, d), "matrix")})
+        return shapes
+
+    def stats_shapes(self):
+        """The int32 counters ``apply_stats`` returns, by name."""
+        return {"expert_tokens": (self.held,), "moe_rows": (),
+                "moe_routed": ()}
+
+    def route(self, params, h):
+        """(expert ids [T, k], weights [T, k] float32) of tokens ``h``
+        [T, d]."""
+        import jax
+        import jax.numpy as jnp
+        scores = jax.nn.sigmoid(jnp.dot(
+            h.astype(jnp.float32), params["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, chosen = jax.lax.top_k(
+            scores + params["router_bias"].astype(jnp.float32), self.top_k)
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if self.norm_topk:
+            weights = weights / (weights.sum(axis=-1, keepdims=True)
+                                 + 1e-20)
+        return chosen, weights * self.scaling
+
+    def apply_stats(self, params, x):
+        import jax
+        import jax.numpy as jnp
+        from . import gemm
+        b, s, d = x.shape
+        k, held = self.top_k, self.held
+        hn = self._norm(x, params["norm"]).reshape(b * s, d)
+        with jax.named_scope("moe/router"):
+            chosen, weights = self.route(params, hn)
+        with jax.named_scope("moe/dispatch"):
+            local = chosen.reshape(-1) - self.offset           # [T * k]
+            here = (local >= 0) & (local < held)
+            # rows of absent experts sort behind every group and are
+            # never multiplied
+            order = jnp.argsort(jnp.where(here, local, held), stable=True)
+            sizes = jnp.sum(
+                local[:, None] == jnp.arange(held)[None, :], axis=0,
+                dtype=jnp.int32)
+            # rows past the last group hold nothing: zeros going in and,
+            # through where's transpose, zeros coming back (the grouped
+            # product leaves them unwritten in both directions)
+            filled = jnp.arange(b * s * k) < sizes.sum()
+            rows = jnp.where(filled[:, None], _permute(hn, order // k), 0)
+        with jax.named_scope("moe/experts"):
+            gate_up = gemm.grouped_matmul(rows, params["experts_gate_up"],
+                                          sizes)
+            a = (jax.nn.silu(gate_up[:, :self.width].astype(jnp.float32))
+                 * gate_up[:, self.width:].astype(jnp.float32)
+                 ).astype(x.dtype)
+            out = gemm.grouped_matmul(a, params["experts_down"], sizes)
+        with jax.named_scope("moe/combine"):
+            back = _permute(out, jnp.argsort(order)).reshape(b * s, k, d)
+            # where BEFORE the product, not times zero: rows past the
+            # last group are not written by the grouped product, and a
+            # product's transpose would multiply by them
+            back = jnp.where(here.reshape(b * s, k, 1),
+                             back.astype(jnp.float32), 0.0)
+            y = jnp.sum(back * weights[..., None], axis=1)
+        if self.n_shared:
+            with jax.named_scope("moe/shared"):
+                y = y + gated_mlp(hn, params["shared_gate"],
+                                  params["shared_up"],
+                                  params["shared_down"])
+        y = (x.astype(jnp.float32) + y.reshape(b, s, d)).astype(x.dtype)
+        # rows routed here, counted from the router's choice, and rows
+        # the grouped product was told to compute: equal iff dropless
+        stats = {"expert_tokens": sizes,
+                 "moe_rows": sizes.sum(),
+                 "moe_routed": here.sum(dtype=jnp.int32)}
+        return y, stats
+
+    def apply(self, params, x):
+        return self.apply_stats(params, x)[0]
+
+
+def _permute(x, index):
+    """``x[index]`` for a row gather whose backward is a gather too.
+    ``index`` [M] reads each row of ``x`` [N, d] a FIXED number of times
+    ``M / N`` (a token's k routes) or is a permutation; the cotangent of
+    row n is the sum of the cotangents of the rows that read it, which
+    ``argsort(index)`` lines up — no scatter-add, which a TPU
+    serialises."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def take(x, index):
+        return jnp.take(x, index, axis=0)
+
+    def fwd(x, index):
+        return take(x, index), (index, x.shape[0])
+
+    def bwd(res, g):
+        index, n = res
+        reads = index.shape[0] // n
+        readers = jnp.argsort(index, stable=True)      # [N * reads]
+        grad = jnp.take(g, readers, axis=0).reshape(
+            (n, reads) + g.shape[1:])
+        return (grad.astype(jnp.float32).sum(axis=1).astype(g.dtype),
+                None)
+
+    take.defvjp(fwd, bwd)
+    return take(x, index)
+
+
+class NormHead(BlockBase):
+    """Final RMSNorm and the untied head over this chip's slice of the
+    vocabulary: ``apply`` gives float32 logits [B, S, V];
+    ``token_loss`` gives the summed next-token cross-entropy and the
+    count of wrong tokens with the projection taken over blocks of
+    tokens, so the [tokens, V] float32 logits never exist whole."""
+
+    MAPPING = "lm_head"
+    remat = False
+
+    def __init__(self, workflow, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.hidden_size = int(kwargs["hidden_size"])
+        self.vocab_size = int(kwargs["vocab_size"])
+        self.loss_block = int(kwargs.get("loss_block_tokens", 2048))
+
+    def tensor_shapes(self):
+        return {"norm": ((self.hidden_size,), "ones"),
+                "weights": ((self.hidden_size, self.vocab_size), "matrix")}
+
+    def output_shape_for(self, input_shape):
+        return tuple(input_shape[:-1]) + (self.vocab_size,)
+
+    def apply(self, params, x):
+        import jax.numpy as jnp
+        return jnp.dot(self._norm(x, params["norm"]), params["weights"],
+                       preferred_element_type=jnp.float32)
+
+    def token_loss(self, params, x, labels, mask):
+        """(summed cross-entropy over unmasked tokens, wrong tokens,
+        predicted ids [B, S]) of hidden states ``x`` [B, S, d] against
+        ``labels`` [B, S]; ``mask`` [B] weighs whole sequences."""
+        import jax
+        import jax.numpy as jnp
+        b, s, d = x.shape
+        n = b * s
+        block = math.gcd(n, self.loss_block)
+        xs = x.reshape(n // block, block, d)
+        ys = labels.reshape(n // block, block)
+        ms = jnp.broadcast_to(mask[:, None], (b, s)).reshape(
+            n // block, block)
+
+        @jax.checkpoint
+        def one(args):
+            xb, yb, mb = args
+            logits = self.apply(params, xb)            # [block, V] f32
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
+            pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            wrong = ((pred != yb) & (mb > 0)).sum(dtype=jnp.int32)
+            return ((lse - picked) * mb).sum(), wrong, pred
+        with jax.named_scope("head/loss"):
+            losses, wrongs, preds = jax.lax.map(one, (xs, ys, ms))
+        return losses.sum(), wrongs.sum(), preds.reshape(b, s)
+
+
+class GDBlock(GradientDescentBase):
+    """Backward of a block through the VJP of its pure ``apply`` (the
+    chain rule the fused trainer differentiates); the solver and its
+    hyperparameters live here as for every layer."""
+
+    hide_from_registry = True
+
+    def backward(self, params, x, y, err_output, n_valid=None):
+        if n_valid is None:
+            n_valid = x.shape[0]
+        return self.backward_via_vjp(params, x, err_output, n_valid)
+
+
+class GDTokenEmbedding(GDBlock):
+    MAPPING = "token_embedding"
+
+
+class GDLatentAttentionBlock(GDBlock):
+    MAPPING = "latent_attention_block"
+
+
+class GDGatedMLPBlock(GDBlock):
+    MAPPING = "gated_mlp_block"
+
+
+class GDExpertBlock(GDBlock):
+    MAPPING = "expert_block"
+
+
+class GDNormHead(GDBlock):
+    MAPPING = "lm_head"
