@@ -226,6 +226,118 @@ def test_grouped_matmul_compiles_for_v5e(v5e, shape):
     assert text.count("tpu_custom_call") >= 2     # the two gradients
 
 
+# -- where the resident data set lies for the per-step programs ---------------
+
+#: ``alexnet_step``'s resident set: 8,192 + 512 float32 images
+SET = (8704, 227, 227, 3)
+
+
+@pytest.fixture(scope="module")
+def gather_step():
+    """A per-step trainer (``FusedTrainStep``, the gather inside the
+    step) at batch 256 whose first layer is AlexNet's conv1, initialized
+    on the CPU over 512 blank images: its own functions are then lowered
+    for the real set on the described chip.  The compiled programs are
+    kept beside it, one compile each."""
+    import numpy
+    from veles_tpu.backends import Device
+    from veles_tpu.config import root
+    from veles_tpu.znicz.samples import alexnet
+
+    class Blank(alexnet.SyntheticImagenetLoader):
+        def load_data(self):
+            self.original_data.mem = numpy.zeros((512,) + SET[1:],
+                                                 numpy.float32)
+            self.original_labels = [i % 10 for i in range(512)]
+            self.class_lengths[:] = [0, 256, 256]
+    conv1, _, pool1 = root.alexnet.layers[:3]
+    wf = alexnet.create_workflow(
+        loader_factory=Blank, loader={"minibatch_size": 256},
+        layers=[conv1, pool1,
+                {"type": "softmax", "->": {"output_sample_shape": 10},
+                 "<-": {"learning_rate": 0.01}}],
+        decision={"max_epochs": 1, "silent": True})
+    wf.initialize(device=Device(backend="cpu"))
+    assert wf.fused_step._use_gather_
+    return wf.fused_step, {}
+
+
+def _gather_program(gather_step, v5e, which):
+    """The gather step's ``which`` program compiled for the set ``SET``
+    on the described chip: ``train`` through ``_lower_gather_train``, as
+    ``_place_data`` compiles it (the set's layout left to the compiler);
+    ``eval`` against the layout ``train`` asked for, as its ``AotStep``
+    lowers it at the first call; ``train-default`` / ``eval-default``
+    against the layout a ``device_put`` gives."""
+    from jax.experimental.layout import Format
+    step, compiled = gather_step
+    if which in compiled:
+        return compiled[which]
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e)
+
+    def struct(shape, dtype, sharding=v5e):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    data, y = struct(SET, jnp.float32), struct(SET[:1], jnp.int32)
+    params, opt, macc = (jax.tree.map(on_chip, t) for t in (
+        step._params_, step._opt_, step._macc_))
+    idx, size = struct((256,), jnp.int32), struct((), jnp.int32)
+    train = (y, params, opt, macc, idx, size, size,
+             struct((), jnp.float32))
+    if which == "train":
+        lowered = step._lower_gather_train(data, *train)
+    elif which == "train-default":
+        lowered = step._train_step_g_.lower(data, *train)
+    else:
+        if which == "eval":
+            asked = _gather_program(gather_step, v5e, "train")
+            data = struct(SET, jnp.float32, Format(
+                asked.input_formats[0][0].layout, v5e))
+        lowered = step._eval_step_g_.lower(data, y, params, macc, idx, size)
+    compiled[which] = lowered.compile()
+    return compiled[which]
+
+
+def _whole_set_instructions(compiled):
+    """The optimized program's instructions whose RESULT has the whole
+    set's shape, the parameter apart."""
+    import re
+    return re.findall(
+        r"^\s*(?:ROOT )?%%\S+ = \w+\[%s\]\S* (?!parameter\()\S+"
+        % ",".join(map(str, SET)), compiled.as_text(), re.M)
+
+
+@pytest.mark.parametrize("case", ["train", "eval", "train-default",
+                                  "eval-default", "batch-major"])
+def test_gather_step_reads_rows_of_a_set_placed_as_its_compiler_asks(
+        gather_step, v5e, case):
+    """From the layout the v5e's compiler chooses for the set, batch
+    dimension major-most, the train and the evaluation program gather
+    their 256 rows and no instruction makes anything of the set's size.
+    The control: from the layout ``device_put`` gives (batch dimension
+    minor-most: 8,704 = 68 x 128 pads no lane) each program first copies
+    the whole set to bfloat16, 13.5 ms a step on the chip (PERF.md
+    section 6, PR 29).  When a compiler no longer does that the control
+    fails, and ``FusedTrainStep._place_data`` can go with this test."""
+    if case == "batch-major":
+        chosen = _gather_program(gather_step, v5e, "train").input_formats
+        default = _gather_program(gather_step, v5e,
+                                  "train-default").input_formats
+        assert chosen[0][0].layout.major_to_minor[0] == 0
+        assert default[0][0].layout.major_to_minor[-1] == 0
+        # and no other argument's layout was left open
+        assert jax.tree.leaves(chosen[0][1:]) \
+            == jax.tree.leaves(default[0][1:])
+        return
+    found = _whole_set_instructions(_gather_program(gather_step, v5e, case))
+    if case.endswith("default"):
+        assert len(found) == 1, found
+        assert " copy(" in found[0] and "bf16[" in found[0], found
+    else:
+        assert found == []
+
+
 def test_chip_smoke_rehearsal_on_cpu_runs_every_phase_and_fails():
     """The control flow of ``chip_smoke.py`` end to end at tiny sizes on
     the CPU: every phase does its work and passes its functional checks

@@ -192,6 +192,38 @@ def test_aot_step_matches_jit_and_keeps_interfaces(tmp_path):
     assert step2.cache_hit is True
 
 
+def test_aot_step_keys_and_serves_an_arguments_layout(tmp_path):
+    """An array placed in another layout than its device's default (the
+    resident set after ``FusedTrainStep._place_data``) brings that layout
+    into the lowering and so into the key: the executable cached for the
+    default layout of the same shape is never handed it."""
+    import jax
+    from jax.experimental.layout import Format, Layout
+    from veles_tpu.compilecache.keys import cache_key
+    x = jax.device_put(numpy.arange(12, dtype=numpy.float32).reshape(3, 4))
+    other = Layout(major_to_minor=tuple(reversed(
+        x.format.layout.major_to_minor)))
+    placed = jax.device_put(x, Format(other, x.sharding))
+    assert placed.format.layout != x.format.layout
+    # the default layout is left unnamed, as it always was
+    assert cc.AotStep._leaf_struct(x).sharding is None
+    assert cc.AotStep._leaf_struct(numpy.asarray(x)).sharding is None
+    assert cc.AotStep._leaf_struct(placed).format == placed.format
+    jitted = jax.jit(lambda a, n: a[1] * n)
+    keys = {cache_key(jitted.lower(cc.AotStep._leaf_struct(a), 2))
+            for a in (x, placed)}
+    assert len(keys) == 2
+    cache = cc.CompileCache(str(tmp_path))
+    want = numpy.asarray(x)[1] * 2
+    for array, hits in ((x, [False, True]), (placed, [False, True])):
+        for hit in hits:
+            step = cc.AotStep(jitted, cache, "test.layout")
+            assert numpy.array_equal(numpy.asarray(step(array, 2)), want)
+            assert step.cache_hit is hit
+            assert step._cache_size() == 1
+            assert step.lower(array, 2).as_text()
+
+
 def test_aot_step_surfaces_a_failed_compile(tmp_path, monkeypatch):
     """A step that cannot be compiled raises: a quiet second path
     through plain jit would hide a program the device refused."""
@@ -201,7 +233,7 @@ def test_aot_step_surfaces_a_failed_compile(tmp_path, monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("compiler refused")
 
-    monkeypatch.setattr(cache, "get_or_compile", boom)
+    monkeypatch.setattr(cache, "load_or_compile", boom)
     step = cc.AotStep(jax.jit(lambda x: x * 2), cache, "test.step")
     x = numpy.arange(4, dtype=numpy.float32)
     with pytest.raises(RuntimeError, match="compiler refused"):
@@ -360,7 +392,11 @@ def test_fused_step_cache_roundtrip_bitwise_parity(tmp_path):
     assert isinstance(getattr(s_cold, step_attr), cc.AotStep)
     assert getattr(s_cold, step_attr).cache_hit is False
     assert getattr(s_warm, step_attr).cache_hit is True
-    assert not isinstance(getattr(s_off, step_attr), cc.AotStep)
+    # no directory, no store: the plain step stays a jit, the gather
+    # step is compiled ahead (its data argument's layout is the
+    # compiler's to choose) and consults nothing
+    assert not isinstance(s_off._train_step_, cc.AotStep)
+    assert getattr(s_off, step_attr).cache_hit is None
 
 
 # -- cross-process restart (the real thing) ----------------------------------

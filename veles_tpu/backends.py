@@ -16,6 +16,7 @@ Selection precedence mirrors the reference (-a flag > env > auto,
 backends.py:184-197): explicit name > $VELES_BACKEND > auto.
 """
 
+import contextlib
 import os
 import subprocess
 import sys
@@ -126,6 +127,26 @@ def apply_compilation_cache_config():
     # knob exists to persist; the entry-size knob is the filter here
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return directory
+
+
+@contextlib.contextmanager
+def compiles_not_persisted():
+    """What is compiled inside is not written to JAX's persistent
+    compilation cache (its floor on an entry's compile time is raised
+    for the while), so no later process is handed it back.  For a
+    program whose RESULT lies in another layout than the device's
+    default: read back from the cache (JAX 0.9.0, v5e and CPU alike) its
+    executable produces the default layout, whatever was compiled
+    (PERF.md section 6, PR 29).  A layout asked of a PARAMETER survives
+    the cache."""
+    import jax
+    floor_name = "jax_persistent_cache_min_compile_time_secs"
+    floor = getattr(jax.config, floor_name)
+    jax.config.update(floor_name, float("inf"))
+    try:
+        yield
+    finally:
+        jax.config.update(floor_name, floor)
 
 
 class BackendRegistry(type):

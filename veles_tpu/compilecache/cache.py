@@ -240,6 +240,21 @@ def inject_env(env=None):
 
 # -- the training-side adapter ------------------------------------------------
 
+
+def _placed_format(a):
+    """``a.format`` where the array ``a`` lies on its device in another
+    layout than the device's default for its shape, else None (host
+    values, and every array nobody placed)."""
+    fmt = getattr(a, "format", None)
+    if fmt is None or fmt.layout is None:
+        return None
+    from jax.experimental.layout import Layout
+    device = next(iter(a.devices()))
+    default = Layout.from_pjrt_layout(device.client.get_default_layout(
+        a.dtype, a.sharding.shard_shape(a.shape), device))
+    return None if fmt.layout == default else fmt
+
+
 class AotStep:
     """First-call AOT wrapper around a jitted step function.
 
@@ -256,8 +271,14 @@ class AotStep:
 
     Interface parity with ``jax.jit`` functions where the codebase
     relies on it: ``__wrapped__`` (scan/mesh steps re-jit from the raw
-    function) and ``_cache_size`` (the StepProfiler's recompile
-    accounting — stays 0 while the AOT path serves every call).
+    function), ``lower`` (bench.py reads the compiler's cost model) and
+    ``_cache_size`` (the StepProfiler's recompile accounting: the
+    executables this callable holds, the jit's own, which the AOT path
+    never uses, and the one compiled or loaded here).
+
+    ``cache`` None (no directory configured) compiles without a store:
+    for a step that has to be an AOT executable whatever the
+    configuration (``FusedTrainStep._place_data``).
     """
 
     def __init__(self, jitted, cache, name, key_extra=None):
@@ -267,6 +288,7 @@ class AotStep:
         self._key_extra = key_extra
         self._compiled = None
         self.cache_hit = None       # None until the first call decides
+        self.lower = jitted.lower
         wrapped = getattr(jitted, "__wrapped__", None)
         if wrapped is not None:
             self.__wrapped__ = wrapped
@@ -274,9 +296,10 @@ class AotStep:
     def _cache_size(self):
         fn = getattr(self._jitted, "_cache_size", None)
         try:
-            return int(fn()) if callable(fn) else 0
+            own = int(fn()) if callable(fn) else 0
         except Exception:  # noqa: BLE001 — diagnostics never raise
-            return 0
+            own = 0
+        return own + (self._compiled is not None)
 
     # scalar pinning: a python int/float traces as a weak 32-bit scalar
     # under the default x64-off config; the AOT struct pins the same
@@ -291,7 +314,12 @@ class AotStep:
             return jax.ShapeDtypeStruct((), numpy.int32)
         if isinstance(a, (float, numpy.floating)):
             return jax.ShapeDtypeStruct((), numpy.float32)
-        return jax.ShapeDtypeStruct(numpy.shape(a), a.dtype)
+        # an array placed in another layout than its device's default
+        # keeps it: the lowering then names it (``mhlo.layout_mode``), so
+        # the key does too, and an executable cached for the default
+        # layout is never handed this array
+        return jax.ShapeDtypeStruct(numpy.shape(a), a.dtype,
+                                    sharding=_placed_format(a))
 
     @staticmethod
     def _leaf_harden(a):
@@ -304,12 +332,22 @@ class AotStep:
             return numpy.float32(a)
         return a
 
+    def compile(self, lowered):
+        """Compile ``lowered`` now and run it from here on; returns the
+        executable.  For a caller that lowers the wrapped function itself
+        (the gather train step leaves one argument's layout to the
+        compiler); the first call does the same from its arguments."""
+        if self._cache is None:
+            self._compiled = lowered.compile()
+        else:
+            self._compiled, self.cache_hit = self._cache.load_or_compile(
+                lowered, name=self._name, key_extra=self._key_extra)
+        return self._compiled
+
     def _ensure_compiled(self, args):
         import jax
         structs = jax.tree_util.tree_map(self._leaf_struct, args)
-        self._compiled, self.cache_hit = self._cache.get_or_compile(
-            self._jitted, *structs, name=self._name,
-            key_extra=self._key_extra)
+        self.compile(self._jitted.lower(*structs))
 
     def __call__(self, *args):
         import jax

@@ -182,6 +182,14 @@ class Array(Pickleable):
             self._device_dirty_ = True
             self._host_dirty_ = False
 
+    def replace_devmem(self, value):
+        """The SAME values placed differently on the device (another
+        layout): which of the two copies is current does not change, so
+        the next host read downloads nothing it did not before."""
+        flags = self._device_dirty_, self._host_dirty_
+        self.devmem = value
+        self._device_dirty_, self._host_dirty_ = flags
+
     def swap_devmem(self, value):
         """Hot-path twin of the ``devmem`` setter (the graph compiler
         writes every traced output back each step): one combined

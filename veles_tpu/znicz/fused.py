@@ -16,8 +16,11 @@ Metrics surface through the same ``n_err``/``metrics`` Arrays the
 evaluator exposes, so Decision units work unchanged.
 """
 
+import logging
+
 import numpy
 
+from ..backends import compiles_not_persisted
 from ..compilecache import AotStep, default_cache
 from ..config import root
 from ..logger import events
@@ -115,10 +118,15 @@ class FusedTrainStep(Unit, IResultProvider):
 
         The loader then only computes shuffled indices host-side; the
         ``jnp.take`` rides inside the same executable as the forward/
-        backward — one launch per step instead of two.  What a launch
-        costs on the present chip is not measured; fusing is in any case
-        strictly less HBM traffic (the gathered batch never materializes
-        as a separate buffer between two executables)."""
+        backward — one launch per step instead of two, and the gathered
+        batch is no buffer between two executables.  That is less HBM
+        traffic only where rows CAN be gathered from the set as it lies:
+        from the v5e's default layout of ``f32[8704, 227, 227, 3]``
+        (batch dimension minor-most) the compiled step copied the whole
+        set every minibatch, 13.5 of AlexNet's 38 ms, until
+        ``_place_data`` asked the step's compiler where the set should
+        lie (PERF.md sections 5 and 6, PR 29).  What a launch costs on
+        the present chip is not measured."""
         self.gather_loader = loader
         loader.defer_device_gather = True
         return self
@@ -394,18 +402,14 @@ class FusedTrainStep(Unit, IResultProvider):
         # deserializes yesterday's executable instead of recompiling.
         # AotStep keeps __wrapped__ (the scan/mesh steps re-jit from the
         # raw function); a step the compiler refuses raises there too.
-        # No configured cache dir = exactly the code above
+        # No configured cache dir = exactly the code above (the gather
+        # steps apart: _place_data)
         cache = default_cache()
         if cache is not None:
             self._train_step_ = AotStep(self._train_step_, cache,
                                         "fused.train_step")
             self._eval_step_ = AotStep(self._eval_step_, cache,
                                        "fused.eval_step")
-            if self._use_gather_:
-                self._train_step_g_ = AotStep(self._train_step_g_, cache,
-                                              "fused.train_step_gather")
-                self._eval_step_g_ = AotStep(self._eval_step_g_, cache,
-                                             "fused.eval_step_gather")
         # copy: the step donates its param buffers, so they must not alias
         # the forward units' live weight Arrays
         self._params_ = [
@@ -420,6 +424,103 @@ class FusedTrainStep(Unit, IResultProvider):
                     gd.solver.init(p, jnp))
              for name, p in self._params_[i].items()}
             for i, gd in enumerate(gds)]
+        if self._use_gather_:
+            self._place_data()
+
+    def _lower_gather_train(self, data, *rest):
+        """``train_step_g`` lowered for the arguments ``(data, *rest)``
+        (arrays or ``ShapeDtypeStruct``s), the layout of ``data`` left to
+        the compiler.  Like a jit's own dispatch, only a committed
+        ``data`` brings its sharding: the outputs then stay uncommitted
+        where they were, and no program downstream sees another
+        signature."""
+        import jax
+        from jax.experimental.layout import Format, Layout
+        home = data.sharding if getattr(data, "committed", True) else None
+        auto = jax.jit(
+            self._train_step_g_.__wrapped__, donate_argnums=(2, 3, 4),
+            in_shardings=(Format(Layout.AUTO, home),) + (None,) * len(rest))
+        return auto.lower(jax.ShapeDtypeStruct(data.shape, data.dtype),
+                          *rest)
+
+    def _place_data(self):
+        """Compile the gather train step now, asking ITS compiler where
+        the resident set should lie, and place the set there once.
+
+        ``device_put`` and a jit's output leave an array in the device's
+        default layout.  For ``f32[8704, 227, 227, 3]`` on a v5e that one
+        has the batch dimension minor-most, rows cannot be gathered from
+        it, and every program that gathers first copied the WHOLE set:
+        13.5 of AlexNet's 38 ms a step (PERF.md section 6, PR 29).  So
+        the program is lowered with that one argument's layout left open
+        (``Layout.AUTO``), the executable says which it took, and the set
+        moves there if it lies otherwise and both placements fit on the
+        device for the moment of the copy (5.50 + 6.20 GB there, 0.18 s).
+        Nothing is decided here from shapes, models or platforms: a set
+        that lies as asked (every CPU run) is left alone, and the
+        executable compiled here is the step's one train program either
+        way.
+
+        Both gather steps are AOT executables (``AotStep``, with or
+        without a store): the train step because a layout left open can
+        only be compiled ahead; the evaluation step, compiled at its
+        first call against the set as it lies, because a placed set is a
+        COMMITTED array, and a jit, which specialises on that, would
+        compile once for a fresh accumulator and once for its own
+        output."""
+        import jax
+        from jax.experimental.layout import Format
+        ld, data = self.gather_loader, self._data_dev_
+        scalar = jax.ShapeDtypeStruct((), numpy.int32)
+        cache = default_cache()
+        name = "fused.train_step_gather"
+        train = AotStep(self._train_step_g_, cache, name)
+        compiled = train.compile(self._lower_gather_train(
+            data, self._y_dev_, self._params_, self._opt_, self._macc_,
+            jax.ShapeDtypeStruct((ld.max_minibatch_size,), ld.INDEX_DTYPE),
+            scalar, scalar, jax.ShapeDtypeStruct((), numpy.float32)))
+        asked = compiled.input_formats[0][0].layout
+        with events.timed("step.place_data", layout=str(asked)) as span:
+            before, lay = data.on_device_size_in_bytes(), data.format.layout
+            room = asked == lay or self._room_to_place(data, compiled)
+            if asked != lay and room:
+                # the relayout program's result has the layout asked for,
+                # which JAX's persistent cache would not give back
+                with compiles_not_persisted():
+                    data = jax.block_until_ready(jax.device_put(
+                        data, Format(asked, data.sharding), donate=True))
+                ld.original_data.replace_devmem(data)
+                self._data_dev_ = data
+            span.count(bytes_before=before,
+                       placed=data.format.layout != lay,
+                       bytes_after=data.on_device_size_in_bytes())
+        if asked != data.format.layout:
+            # today's placement and today's program, compiled at the
+            # first call: slower, but a set that fitted before does not
+            # fail now
+            logging.getLogger(type(self).__name__).warning(
+                "the step's compiler asks for the resident set in layout "
+                "%s, but %s: it stays in %s and every step copies it", asked,
+                "the copy came back as the set went" if room else
+                "the device has no room for two copies of it",
+                data.format.layout)
+            train = AotStep(self._train_step_g_, cache, name)
+        self._train_step_g_ = train
+        self._eval_step_g_ = AotStep(self._eval_step_g_, cache,
+                                     "fused.eval_step_gather")
+
+    @staticmethod
+    def _room_to_place(data, compiled):
+        """Whether the device holding ``data`` has room for a second
+        placement of it beside all it holds now.  The new one is counted
+        as no larger than ALL the arguments of the ``compiled`` step
+        together, of which it is one.  A device that reports no memory
+        statistics (the CPU) has room."""
+        stats = next(iter(data.devices())).memory_stats()
+        if not stats:
+            return True
+        need = compiled.memory_analysis().argument_size_in_bytes
+        return stats["bytes_in_use"] + need <= stats["bytes_limit"]
 
     def _macc_init(self):
         """Fresh on-device metric accumulator pytree."""

@@ -8,8 +8,17 @@ validation minibatches) into one jitted ``lax.scan``:
     (params, opt, macc) = scan(body, init, (idx_matrix, sizes))
 
 with the resident FullBatch dataset gathered per-iteration *inside* the
-scan (``jnp.take``), masks built from the per-batch ``sizes`` vector, so
-results are bit-identical to the per-step path (asserted in tests).  Host
+scan (``jnp.take``) in the source, masks built from the per-batch
+``sizes`` vector, so results are bit-identical to the per-step path
+(asserted in tests).  What the v5e's compiler makes of that gather is
+another program: rows cannot be gathered from the device's default layout
+of ``f32[8704, 227, 227, 3]`` (batch dimension minor-most), so each
+dispatch first copies the WHOLE set to bfloat16, hoisted out of the loop
+(13.5 ms a class a chip), and the iterations gather from the copy.  The
+per-step trainer cured the same disease by placing the set where its
+compiler asks (``FusedTrainStep._place_data``); in scan form that
+placement still leaves a hoisted whole-set convert, so the scans are left
+as they are (PERF.md section 6, PR 29; ROADMAP S12).  Host
 work per class: build the index matrix (numpy), one dispatch (the index
 matrix rides along as an argument), then the class-end epilogue
 (``FusedTrainStep._finish_class``) enqueued behind the running scan —
