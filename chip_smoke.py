@@ -35,8 +35,9 @@ SEED = 1234
 BACKEND = "tpu"
 
 #: the ImageNet AlexNet sample at its full width (samples/alexnet.py:
-#: 227x227x3, 1000 classes), batch 256 as bench.py runs it; the synthetic
-#: dataset is cut to eight train minibatches and the run to a few epochs
+#: 227x227x3, 1000 classes), batch 256 as the benchmark's cells run it;
+#: the synthetic dataset is cut to eight train minibatches and the run to
+#: a few epochs
 ALEXNET = {"batch": 256, "side": 227, "n_classes": 1000, "n_train": 2048,
            "n_valid": 256, "epochs": 3}
 #: (--mode, --compute-dtype): per-step fused steps with the prefetcher on,
@@ -44,8 +45,8 @@ ALEXNET = {"batch": 256, "side": 227, "n_classes": 1000, "n_train": 2048,
 TRAINER_RUNS = (("fused", "float32"), ("scan", "float32"),
                 ("fused", "bfloat16"), ("scan", "bfloat16"))
 
-#: the widest flagship the repo runs (bench.py bench_flagship) behind the
-#: README "Decode quickstart" geometry
+#: the widest flagship the repo runs (the old record's 249,902 tok/s)
+#: behind the README "Decode quickstart" geometry
 FLAGSHIP = {"stages": 4, "experts": 4, "d": 256, "heads": 8,
             "hidden": 1024, "vocab": 1024}
 DECODE_GEOMETRY = {"max_batch": 16, "block_size": 16,

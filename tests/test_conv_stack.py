@@ -259,102 +259,3 @@ def test_pallas_lrn_matches_reference_and_grads():
             lambda v: (uj.apply({}, v) ** 2).sum())(jnp.asarray(x)))
         assert numpy.abs(gp - gj).max() < 1e-4, \
             (n, numpy.abs(gp - gj).max())
-
-
-def test_fast_max_pool_grads_match_reduce_window_oracle():
-    """fast_max_pool's hand VJP (offset-predicated pads) must equal
-    autodiff through lax.reduce_window (XLA's select-and-scatter) —
-    values and input gradients, overlapping and padded windows, max and
-    max-|.| flavors."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from veles_tpu.znicz.pooling import fast_max_pool
-
-    rng = numpy.random.RandomState(5)
-    x = jnp.asarray(rng.uniform(-1, 1, (3, 9, 11, 4)), jnp.float32)
-    cases = [((3, 3), (2, 2), ((0, 0), (0, 0))),
-             ((2, 2), (2, 2), ((0, 0), (0, 0))),
-             ((3, 3), (1, 1), ((1, 1), (1, 1))),
-             ((3, 2), (2, 3), ((1, 0), (0, 1)))]
-    for window, strides, pad in cases:
-        def oracle(v):
-            return lax.reduce_window(
-                v, -numpy.inf, lax.max, (1,) + window + (1,),
-                (1,) + strides + (1,), ((0, 0),) + pad + ((0, 0),))
-
-        def fast(v):
-            return fast_max_pool(v, window, strides, pad, False)
-
-        y_o, y_f = oracle(x), fast(x)
-        assert numpy.allclose(y_o, y_f, atol=1e-6), (window, strides)
-        err = jnp.asarray(rng.uniform(-1, 1, y_o.shape), jnp.float32)
-        g_o = jax.grad(lambda v: (oracle(v) * err).sum())(x)
-        g_f = jax.grad(lambda v: (fast(v) * err).sum())(x)
-        assert numpy.allclose(g_o, g_f, atol=1e-5), (window, strides)
-
-    # max-|.|: compare against an explicit patches oracle (first-
-    # occurrence argmax over |window|, like the numpy twin)
-    def patches(v, window, strides, pad):
-        vp = jnp.pad(v, ((0, 0),) + pad + ((0, 0),))
-        oh = (vp.shape[1] - window[0]) // strides[0] + 1
-        ow = (vp.shape[2] - window[1]) // strides[1] + 1
-        planes = [vp[:, oy:oy + (oh - 1) * strides[0] + 1:strides[0],
-                     ox:ox + (ow - 1) * strides[1] + 1:strides[1], :]
-                  for oy in range(window[0]) for ox in range(window[1])]
-        return jnp.stack(planes, axis=3)
-
-    for window, strides, pad in cases:
-        p = patches(x, window, strides, pad)
-        idx = jnp.argmax(jnp.abs(p), axis=3)
-        want = jnp.take_along_axis(p, idx[:, :, :, None, :],
-                                   axis=3)[:, :, :, 0, :]
-        got = fast_max_pool(x, window, strides, pad, True)
-        assert numpy.allclose(want, got, atol=1e-6), (window, strides)
-        err = jnp.asarray(rng.uniform(-1, 1, want.shape), jnp.float32)
-        g_o = jax.grad(lambda v: (jnp.take_along_axis(
-            patches(v, window, strides, pad),
-            idx[:, :, :, None, :], axis=3)[:, :, :, 0, :]
-            * err).sum())(x)
-        g_f = jax.grad(
-            lambda v: (fast_max_pool(v, window, strides, pad, True)
-                       * err).sum())(x)
-        assert numpy.allclose(g_o, g_f, atol=1e-5), (window, strides)
-
-
-def test_max_pooling_separable_and_bf16_variants():
-    """Round-5 pooling experiments: separable is EXACT vs the 2-D
-    window (fwd and grads); bf16 matches to bf16 tolerance.  Overlapped
-    AlexNet geometry (k3 s2) on purpose."""
-    import jax
-    import jax.numpy as jnp
-    rng = numpy.random.RandomState(11)
-    x = rng.standard_normal((2, 15, 15, 8)).astype(numpy.float32)
-
-    def build(**kw):
-        wf = Workflow(name="pool-var")
-        u = MaxPooling(wf, kx=3, ky=3, sliding=(2, 2), **kw)
-        u.input = Array(x.copy())
-        u.initialize(device=Device(backend="cpu"))
-        return u
-
-    base = build()
-    sep = build(pool_separable=True)
-    bf16 = build(pool_bf16=True)
-    both = build(pool_separable=True, pool_bf16=True)
-    y0 = base.apply(None, jnp.asarray(x))
-    numpy.testing.assert_array_equal(
-        numpy.asarray(sep.apply(None, jnp.asarray(x))),
-        numpy.asarray(y0))
-    for v in (bf16, both):
-        out = numpy.asarray(v.apply(None, jnp.asarray(x)))
-        assert out.dtype == numpy.float32
-        numpy.testing.assert_allclose(out, numpy.asarray(y0),
-                                      rtol=1e-2, atol=1e-2)
-    # gradient parity: separable backward == select-and-scatter backward
-    g0 = jax.grad(lambda x: jnp.sum(base.apply(None, x) ** 2))(
-        jnp.asarray(x))
-    g1 = jax.grad(lambda x: jnp.sum(sep.apply(None, x) ** 2))(
-        jnp.asarray(x))
-    numpy.testing.assert_allclose(numpy.asarray(g1), numpy.asarray(g0),
-                                  rtol=1e-6, atol=1e-6)

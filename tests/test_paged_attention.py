@@ -70,12 +70,20 @@ def _naive_f64(q, k_pool, v_pool, table, lengths):
     return out
 
 
-def test_bitwise_equal_lengths():
+def test_equal_lengths_match_reference_to_the_last_places():
+    """The kernel's contract with its dense reference is a tolerance
+    (its docstring; ROADMAP D13): both stage their sums alike, but the
+    compiler may still associate or fuse them differently, and under
+    the present JAX the interpreted kernel differs from the reference
+    by 8.9e-8 on outputs of 0.8, a unit or two in the last place.  The
+    bound is the ragged test's: a few last places of O(1) outputs, far
+    below what one wrong, skipped or doubled block would move."""
     q, kp, vp, table = _setup()
     lengths = jnp.full((B,), T_MAX, jnp.int32)
     out = paged_attention(q, kp, vp, table, lengths)
     ref = paged_attention_reference(q, kp, vp, table, lengths)
-    assert numpy.array_equal(numpy.asarray(out), numpy.asarray(ref))
+    assert numpy.allclose(numpy.asarray(out), numpy.asarray(ref),
+                          atol=1e-6, rtol=1e-6)
 
 
 @pytest.mark.parametrize("lengths", [

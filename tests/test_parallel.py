@@ -169,7 +169,7 @@ def test_tp_conv_equals_dp():
 
 
 def test_mesh_epoch_scan_equals_single_scan():
-    """epoch_scan over a mesh (DistributedScanStep): one scan dispatch
+    """epoch_scan over a mesh (ScanEpochStep, mesh=): one scan dispatch
     per class, batch split over data, params replicated — must train
     the same weights as the single-device scan AND the per-step mesh."""
     wf_s = build(epoch_scan=True)

@@ -91,11 +91,16 @@ def test_quantize_pool_shapes_determinism_and_bound():
 
 # -- decode kernel ------------------------------------------------------------
 
-def test_paged_attention_int8_bitwise_vs_quantized_reference():
-    """The int8 kernel's contract with the quantized dense reference is
-    bitwise — same dequant staging — including padding rows (length 0)
-    and the reserved trash block; the end-to-end error vs the f32 path
-    stays well under the site's declared bound."""
+def test_paged_attention_int8_matches_quantized_reference_within_its_scales():
+    """The int8 kernel's contract with the quantized dense reference
+    (same dequant staging) is a tolerance (the kernel's docstring;
+    ROADMAP D13), stated in the units the pool is quantized in: the two
+    agree to a ten-thousandth of the largest V quantization step (2.8e-6
+    here; measured 1.8e-7, a last place of outputs up to 1.5), where
+    reading one wrong int8 value moves an output by whole steps.
+    Padding rows (length 0, the reserved trash block) are exactly zero;
+    the end-to-end error vs the f32 path stays well under the site's
+    declared bound."""
     rng = numpy.random.default_rng(1)
     kp, vp = _rand_pools(rng, n_blocks=6, bs=4, h=2, d=8)
     kq, ks = quantize_pool(kp)
@@ -107,7 +112,9 @@ def test_paged_attention_int8_bitwise_vs_quantized_reference():
                           k_scales=ks, v_scales=vs)
     ref = paged_attention_reference(q, kq, vq, table, lengths,
                                     k_scales=ks, v_scales=vs)
-    assert numpy.array_equal(numpy.asarray(out), numpy.asarray(ref))
+    step = float(numpy.asarray(vs).max())
+    assert numpy.abs(numpy.asarray(out) -
+                     numpy.asarray(ref)).max() <= 1e-4 * step
     assert (numpy.asarray(out)[2] == 0).all()     # padding row
     f32 = paged_attention(q, kp, vp, table, lengths)
     rmse = float(numpy.sqrt(numpy.mean(

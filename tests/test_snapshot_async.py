@@ -183,12 +183,12 @@ def test_queue_depth_is_bounded_for_periodic_shots():
 
 def test_roundtrip_with_prefetcher_and_distributed_step(tmp_path):
     """Satellite: snapshot→restore under the PR 3 machinery — a
-    MinibatchPrefetcher attached AND a DistributedTrainStep (mesh dp)
+    MinibatchPrefetcher attached AND a FusedTrainStep over a mesh (dp)
     initialized.  The transient_-dropping __getstate__ must keep both
     out of the pickle, and resumed training must match an uninterrupted
     run (same minibatch walk ⇒ same weights and epoch metrics)."""
     import jax
-    from veles_tpu.parallel.dp import DistributedTrainStep
+    from veles_tpu.znicz.fused import FusedTrainStep
     from veles_tpu.parallel.mesh import make_mesh
     if len(jax.devices()) < 8:
         pytest.skip("needs the conftest 8-device virtual CPU mesh")
@@ -197,7 +197,8 @@ def test_roundtrip_with_prefetcher_and_distributed_step(tmp_path):
     ref.run()
 
     part = build(3, tmp_path, minibatch=40, mesh=make_mesh({"data": 8}))
-    assert isinstance(part.fused_step, DistributedTrainStep)
+    assert type(part.fused_step) is FusedTrainStep
+    assert part.fused_step.mesh is not None
     assert part.loader.prefetcher_ is not None
     part.run()
 

@@ -274,36 +274,3 @@ def test_bf16_mixed_precision_trains():
     import jax
     leaves = jax.tree_util.tree_leaves(wf.fused_step._params_)
     assert all(leaf.dtype == numpy.float32 for leaf in leaves)
-
-
-def test_rng_impl_knob_trains_with_dropout():
-    """root.common.engine.rng_impl swaps the dropout-mask PRNG
-    (threefry default; 'rbg' = the TPU-cheap hardware generator) —
-    both train a dropout topology to comparable accuracy."""
-    from veles_tpu.config import root
-    from veles_tpu.znicz.samples import mnist
-    layers = [
-        {"type": "all2all_tanh", "->": {"output_sample_shape": 100},
-         "<-": {"learning_rate": 0.03, "gradient_moment": 0.9}},
-        {"type": "dropout", "->": {"dropout_ratio": 0.3}},
-        {"type": "softmax", "->": {"output_sample_shape": 10},
-         "<-": {"learning_rate": 0.03, "gradient_moment": 0.9}},
-    ]
-    errs = {}
-    try:
-        for impl in ("threefry2x32", "rbg"):
-            root.common.engine.rng_impl = impl
-            wf = mnist.create_workflow(
-                loader={"minibatch_size": 60, "n_train": 2000,
-                        "n_valid": 400,
-                        "prng": RandomGenerator().seed(3)},
-                layers=layers,
-                decision={"max_epochs": 6, "silent": True})
-            wf.initialize(device=Device(backend="cpu"))
-            wf.run()
-            errs[impl] = wf.gather_results()["best_validation_error_pt"]
-    finally:
-        # delete, don't None: a present key shadows code defaults
-        delattr(root.common.engine, "rng_impl")
-    for impl, err in errs.items():
-        assert err == err and err < 30, (impl, err)

@@ -1,2 +1,2 @@
-"""Dev/bench tooling (fixture writers, on-chip A/Bs).  A package so
-bench.py and the tools can share measurement harness code."""
+"""Dev tooling (fixture writers, on-chip A/Bs).  A package so the tools
+and the tests can share measurement harness code."""

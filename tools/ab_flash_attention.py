@@ -40,9 +40,7 @@ def train_shaped(attend, chain):
     while the caller's sync pulls 4 bytes — syncing on the updated
     tensors themselves would put an O(T*D) device-to-host copy of
     q'/k'/v' into every rep, an additive constant on both sides that
-    dilutes the ratio toward 1.  Shared by bench.py's flash/window stages and
-    tools/longcontext_demo.py — the recorded metric and the tool that
-    validated it must not diverge."""
+    dilutes the ratio toward 1.  Shared with tools/longcontext_demo.py."""
     import jax
     import jax.numpy as jnp
 
@@ -62,10 +60,7 @@ def time_pair(fa, fb, args, reps=12, chain=4):
     drift inverts sequential comparisons): compile+warm both fns, then
     each repetition times A then B back-to-back; ``chain`` dependent
     calls per dispatch amortize host dispatch.  Returns the
-    full per-rep second lists (callers take min/median/spread).
-    Shared by this tool and bench.py's flash_attention stage — the
-    recorded metric and the tool that validated it must not
-    diverge."""
+    full per-rep second lists (callers take min/median/spread)."""
     for fn in (fa, fb):
         _sync(fn(*args))
     ta, tb = [], []

@@ -18,8 +18,8 @@ against the dense/oracle reference) and persists the winner keyed by
 kernel call sites resolve through at dispatch time.  ``verify`` is
 read-only (unlike dispatch, which quarantines) and exits 1 when any
 record fails validation.  ``resolve`` reports what a process with
-``$VELES_AUTOTUNE_DIR=DIR`` would actually run — the cross-process
-reuse proof ``bench.py --stage autotune`` builds on.
+``$VELES_AUTOTUNE_DIR=DIR`` would actually run: the cross-process
+reuse proof (``tests/test_autotune.py``).
 """
 
 import argparse

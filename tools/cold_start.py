@@ -21,9 +21,8 @@ Emits ONE JSON line:
      "first_infer_s"|"first_step_s": ..., "total_s": ...,
      "compiles": N, "cache_hits": N, "cache": {...} | null}
 
-``bench.py --stage cold_start`` drives this twice per mode and records
-the cold/warm ratio; ``tests/test_compilecache.py`` uses it as the
-cross-process reuse proof.
+Run it twice per mode on one cache directory for the cold/warm ratio;
+``tests/test_compilecache.py`` uses it as the cross-process reuse proof.
 """
 
 import argparse

@@ -1,7 +1,7 @@
 """Interpreted vs traced throughput probes for whole-workflow compilation.
 
-Three probes, each printing ONE JSON line (bench.py `graph_compile` stage
-runs them in fresh subprocesses):
+Three probes, each printing ONE JSON line (run each in a fresh process;
+``tests/test_graphcomp.py`` does):
 
 - ``nonstd``: a deliberately NON-standard workflow — two-branch forward
   towers joined into a shared softmax head + evaluator (an ensemble-style

@@ -18,7 +18,7 @@ load shape (N concurrent clients, mixed request batch sizes):
   ``--offered-rps``, recording achieved rate, tail latency and shed
   (429/overflow) counts, the way serving SLOs are actually stated.
 
-Emits ONE JSON line (bench.py convention):
+Emits ONE JSON line:
     {"metric": "serve_rps", "value": N, "unit": "req/s", ...}
 
 Smoke mode (``--smoke``) keeps everything under ~10 s so it can ride in
@@ -260,8 +260,7 @@ def run_bench(package=None, clients=8, seconds=2.0, sizes=DEFAULT_SIZES,
     seed_infer(numpy.zeros((1,) + sample_shape, numpy.float32))  # warm
     # time-to-first-response: scheduler construction (bucket-ladder
     # warmup — compiles, or deserializes off a warm executable cache)
-    # through the first answered request; the cold-start regression
-    # signal in every BENCH_*.json (bench.py cold_start stage measures
+    # through the first answered request (tools/cold_start.py measures
     # the same path across fresh processes)
     t0 = time.perf_counter()
     scheduler = BucketScheduler(loader, max_batch=max_batch,

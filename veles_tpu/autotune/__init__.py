@@ -15,7 +15,7 @@ byte-for-byte unchanged.
 Drive it with ``tools/autotune.py tune|list|show|verify``.
 """
 
-from .dispatch import (AUTOTUNE_DIR_ENV, default_store, describe,  # noqa: F401
+from .dispatch import (AUTOTUNE_DIR_ENV, default_store,  # noqa: F401
                        reset_default_stores, resolve, resolve_config)
 from .runner import measure_candidate, run_isolated, tune_site  # noqa: F401
 from .space import SITES, SearchSpace, ladder, site  # noqa: F401

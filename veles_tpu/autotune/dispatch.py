@@ -98,13 +98,3 @@ def resolve(site, shape_class, default=None):
     merged = dict(default)
     merged.update(config)
     return merged, source
-
-
-def describe(site, shape_class, default=None):
-    """Bench/JSON provenance helper: the resolved config flattened with
-    its ``config_source`` tag (satellite: every kernel metric in
-    bench.py carries which config produced it)."""
-    config, source = resolve(site, shape_class, default)
-    out = dict(config)
-    out["config_source"] = source
-    return out

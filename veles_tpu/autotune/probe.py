@@ -32,7 +32,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 def _timed_pair(cand_fn, ref_fn, reps, windows):
     """Interleaved min-of-windows seconds for (candidate, reference):
     alternating window order cancels monotone load drift, the min
-    discards contended windows (the bench.py discipline)."""
+    discards contended windows."""
     cand_fn()
     ref_fn()                    # both warm (compiles outside timing)
     cand_times, ref_times = [], []

@@ -183,8 +183,9 @@ _register(SearchSpace(
             "block_rows": (256, 512, 1024, 2048, 4096)},
     # the hand-picked pallas config (lrn._LRN_BLOCK_ROWS); "mxu" is the
     # banded-matmul LAYOUT as a searchable candidate — the measured
-    # answer to BENCH_r05's 0.6x: on device classes where the
-    # pallas_call fusion boundary loses, the tuner picks the band
+    # answer to the old record's 0.6x (docs/PERF.md): on device classes
+    # where the pallas_call fusion boundary loses, the tuner picks the
+    # band
     default={"impl": "pallas", "block_rows": 1024},
     constraint=_lrn_constraint,
     classify=lambda ctx: "c%d_n%d" % (ctx["c"], ctx.get("n", 5)),

@@ -270,8 +270,9 @@ class AotStep:
     :meth:`CompileCache.load_or_compile`, at the cost of a recompile.
 
     Interface parity with ``jax.jit`` functions where the codebase
-    relies on it: ``__wrapped__`` (scan/mesh steps re-jit from the raw
-    function), ``lower`` (bench.py reads the compiler's cost model) and
+    relies on it: ``__wrapped__`` (``FusedTrainStep._lower_gather_train``
+    lowers the raw function again), ``lower`` (the compiler's cost model)
+    and
     ``_cache_size`` (the StepProfiler's recompile accounting: the
     executables this callable holds, the jit's own, which the AOT path
     never uses, and the one compiled or loaded here).

@@ -310,7 +310,7 @@ root.update({
             # atomic: tmp-write + rename).
             "async_write": True,
             # gz/bz2/xz codec level: 9 buys ~nothing on float weights
-            # and costs multiples in CPU time (bench.py snapshot stage)
+            # and costs multiples in CPU time (CPU, PR 4: write 1.5x)
             "compression_level": 6,
             # _report_size fattest-units diagnostic threshold, bytes
             # (0 disables)

@@ -27,9 +27,7 @@ Everything is emitted twice: into the process-global
 
 Fencing serializes the dispatch pipeline, which is precisely what makes
 the breakdown honest — and is why the profiler is opt-in
-(``Workflow.attach_profiler()``, ``root.common.observability.profile``)
-and why ``bench.py --stage observability`` records its measured
-overhead on the MNIST step loop.
+(``Workflow.attach_profiler()``, ``root.common.observability.profile``).
 """
 
 import collections

@@ -11,4 +11,3 @@ handles long contexts.  The out-of-band job protocol survives separately in
 """
 
 from .mesh import make_mesh, data_parallel_sharding, batch_sharding  # noqa
-from .dp import DistributedTrainStep                                 # noqa

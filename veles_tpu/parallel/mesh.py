@@ -62,10 +62,27 @@ def mesh_for_spec(spec, devices=None):
 
 def mesh_spec(mesh):
     """Picklable ``{axis: size}`` geometry of a Mesh.  jax Device
-    handles are process-local and cannot be pickled — snapshots store
-    the spec and ``make_mesh(spec)`` rebuilds the mesh on the restoring
-    process's devices (the sharded steps do this in initialize)."""
+    handles are process-local and cannot be pickled: snapshots store
+    the spec (:func:`spec_in_state`) and the restoring process rebuilds
+    the mesh over its own devices (:func:`live_mesh`)."""
     return {name: int(size) for name, size in mesh.shape.items()}
+
+
+def spec_in_state(state):
+    """``state`` (what a ``__getstate__`` is about to return) with its
+    ``"mesh"`` as a spec: how every holder of a mesh (the workflow, the
+    trainer step) pickles it."""
+    mesh = state.get("mesh")
+    if mesh is not None and not isinstance(mesh, dict):
+        state["mesh"] = mesh_spec(mesh)
+    return state
+
+
+def live_mesh(mesh):
+    """What a holder's ``initialize`` makes of its ``mesh``: a spec
+    (restored from a snapshot) is rebuilt over this process's devices;
+    a Mesh, or none, stays."""
+    return mesh_for_spec(mesh) if isinstance(mesh, dict) else mesh
 
 
 def register_mesh_metrics(mesh, workflow="-"):
@@ -99,42 +116,97 @@ def replicated(mesh):
     return NamedSharding(mesh, P())
 
 
-def fresh_accumulator(step, macc):
-    """A trainer's fresh metric accumulator ``macc``, placed like the one
-    its jitted step returns (``step._rep_``, once the operands are
-    placed).  Left on the default device it is a second signature of the
-    jitted step — a second executable, compiled in the second epoch — and
-    a reshard inside the dispatch at every class start.  Across
-    processes a single-device array cannot be placed outside jit, so
-    there it stays as it is."""
-    import jax
-    rep = getattr(step, "_rep_", None)
-    if rep is None or jax.process_count() > 1:
-        return macc
-    return jax.device_put(macc, rep)
+class TrainerPlacement:
+    """Where the operands of a fused trainer lie on a mesh: the one
+    holder of that rule, which ``FusedTrainStep`` and ``ScanEpochStep``
+    keep when they are given a mesh (with none they keep no placement
+    and make plain ``jax.jit`` calls).
 
+    Operands are named by KIND: ``"param"`` (each parameter replicated,
+    or split over ``model_axis`` as :func:`tensor_parallel_sharding`
+    says), ``"opt"`` (every solver-state entry like its parameter:
+    momentum buffers, adadelta tuples), ``"rep"`` (replicated: scalars,
+    the metric accumulator, the resident data set, a scan's index
+    tensors) and ``"batch"`` (a minibatch, leading dimension split over
+    ``data_axis``).  XLA derives the collectives, the gradient
+    all-reduce among them, from these annotations.
 
-def trainer_shardings(mesh, params, opt, model_axis=None,
-                      tp_mode="column"):
-    """The fused trainers' operand shardings: params tensor-sharded over
-    ``model_axis`` when given (else replicated DP), opt-state entries
-    shaped like their param (momentum buffers, adadelta tuples), plus
-    the replicated spec for scalars/metrics.  Shared by the per-step
-    (parallel/dp.py) and epoch-scan (parallel/scan.py) mesh trainers."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    if model_axis and model_axis in mesh.shape:
-        param_shard = tensor_parallel_sharding(mesh, params, model_axis,
-                                               mode=tp_mode)
-    else:
-        param_shard = data_parallel_sharding(mesh, params)
-    opt_shard = [
-        {name: tuple(param_shard[i][name]
-                     for _ in range(len(opt[i][name])))
-         if isinstance(opt[i][name], tuple)
-         else param_shard[i][name]
-         for name in opt[i]}
-        for i in range(len(opt))]
-    return param_shard, opt_shard, NamedSharding(mesh, P())
+    Across processes every process holds the same full values (the
+    loaders are identically seeded), and an array of one process cannot
+    be resharded to a global sharding outside jit: operands are placed
+    from HOST memory there (:meth:`place`, and the ``host_argnums`` of
+    :meth:`jit`)."""
+
+    def __init__(self, mesh, params, opt, data_axis="data",
+                 model_axis=None, tp_mode="column", workflow="-"):
+        import jax
+        self.mesh = mesh
+        self.multihost = jax.process_count() > 1
+        if model_axis and model_axis in mesh.shape:
+            param = tensor_parallel_sharding(mesh, params, model_axis,
+                                             mode=tp_mode)
+        else:
+            param = data_parallel_sharding(mesh, params)
+        self._kinds = {
+            "param": param,
+            "opt": [{name: (param[i][name],) * len(state)
+                     if isinstance(state, tuple) else param[i][name]
+                     for name, state in layer.items()}
+                    for i, layer in enumerate(opt)],
+            "rep": replicated(mesh),
+            "batch": batch_sharding(mesh, data_axis)}
+        register_mesh_metrics(mesh, workflow)
+
+    def shardings(self, kinds):
+        return tuple(self._kinds[kind] for kind in kinds)
+
+    def place(self, operands, kind):
+        """``operands`` (a pytree) on the sharding of ``kind``."""
+        import jax
+        if self.multihost:
+            operands = jax.tree.map(numpy.asarray, operands)
+        return jax.device_put(operands, self._kinds[kind])
+
+    def jit(self, fn, in_kinds, out_kinds, donate_argnums,
+            host_argnums=()):
+        """``fn`` as one SPMD program: arguments and results laid out
+        by kind.  Across processes the arguments ``host_argnums`` (a
+        loader's minibatch, a scan's index tensors: values of this
+        process, the same on every one) are first placed from host
+        memory; in one process they go to the jit as they are."""
+        import jax
+        program = jax.jit(fn, in_shardings=self.shardings(in_kinds),
+                          out_shardings=self.shardings(out_kinds),
+                          donate_argnums=donate_argnums)
+        if not (self.multihost and host_argnums):
+            return program
+
+        def placing(*args):
+            args = list(args)
+            for i in host_argnums:
+                args[i] = self.place(args[i], in_kinds[i])
+            return program(*args)
+        return placing
+
+    def constrain_batch(self, a):
+        """Inside a program: ``a`` (a minibatch gathered from the
+        replicated set) split over the data axis."""
+        from jax import lax
+        return lax.with_sharding_constraint(a, self._kinds["batch"])
+
+    def fresh_accumulator(self, macc):
+        """A fresh metric accumulator placed like the one the programs
+        return.  Left on the default device it is a second signature of
+        the jitted step (a second executable, compiled in the second
+        epoch) and a reshard inside the dispatch at every class start.
+        Across processes it stays as it is."""
+        return macc if self.multihost else self.place(macc, "rep")
+
+    def batch_staging(self):
+        """The sharding a ``MinibatchPrefetcher`` stages minibatches
+        onto ahead of the step; None across processes, where the step
+        places host batches itself and nothing may be staged."""
+        return None if self.multihost else self._kinds["batch"]
 
 
 def data_parallel_sharding(mesh, params_tree):

@@ -275,9 +275,10 @@ class Workflow(Container):
         workflow's loader (``root.common.loader.prefetch_depth`` deep
         unless ``depth=`` is given; 0 disables).  Call after
         ``initialize`` — minibatch buffers and the device path must
-        exist.  When the training step exposes a batch sharding
-        (``_batch_sharding_``, set by the distributed per-step trainer)
-        prefetched minibatches are device_put straight onto it.  Attach
+        exist.  Where the training step lies over a mesh, prefetched
+        minibatches are device_put straight onto the sharding its
+        placement stages batches on (``TrainerPlacement.batch_staging``).
+        Attach
         BEFORE ``attach_profiler`` so the profiler's data-wait phase
         measures time blocked on the prefetch queue.  Returns the
         prefetcher, or None when disabled/unsupported."""
@@ -287,8 +288,9 @@ class Workflow(Container):
         if loader is None:
             raise ValueError("no loader to prefetch for %r" % self)
         step = getattr(self, "fused_step", None)
-        kwargs.setdefault("sharding",
-                          getattr(step, "_batch_sharding_", None))
+        placement = getattr(step, "_placement_", None)
+        if placement is not None:
+            kwargs.setdefault("sharding", placement.batch_staging())
         self.prefetcher_ = MinibatchPrefetcher.attach(loader, **kwargs)
         return self.prefetcher_
 

@@ -28,9 +28,23 @@ from ..memory import Array
 from ..result_provider import IResultProvider
 from ..units import Unit
 from .. import loader as loader_mod
+from ..parallel import mesh as mesh_mod
 from .all2all import All2AllSoftmax
 from .evaluator import EvaluatorSoftmax, EvaluatorMSE
 from . import solvers
+
+
+def jit_program(placement, fn, in_kinds, out_kinds, donate_argnums,
+                host_argnums=()):
+    """One program of a trainer step, jitted once: with no placement
+    the plain ``jax.jit`` (no sharding named, no array committed), with
+    one (``parallel.mesh.TrainerPlacement``) its SPMD jit, the operands
+    laid out by kind."""
+    if placement is None:
+        import jax
+        return jax.jit(fn, donate_argnums=donate_argnums)
+    return placement.jit(fn, in_kinds, out_kinds, donate_argnums,
+                         host_argnums)
 
 
 def scope_names(units):
@@ -49,12 +63,37 @@ class FusedTrainStep(Unit, IResultProvider):
     Parameters: ``forwards`` (list of ForwardBase), ``gd_configs`` (list of
     GradientDescentBase *or* kwargs dicts, one per forward, reverse not
     required), ``loss`` ("softmax" | "mse").
+
+    ``mesh`` (a ``jax.sharding.Mesh``) makes the same step one SPMD
+    program: the batch split over ``data_axis``, the parameters
+    replicated or split over ``model_axis`` (``tp_mode``: "column" |
+    "megatron"), XLA inserting the gradient all-reduce from the sharding
+    annotations.  That is the TPU-native replacement for the reference's
+    master-slave trainer (SURVEY.md §2.4), and synchronous where that
+    was stale by one update; its elastic join/leave moved to
+    checkpoint-restart (``veles_tpu.distributed``).  Which operand lies
+    where is ``parallel.mesh.TrainerPlacement``'s to say; with no mesh
+    the step holds no placement and its jits take no shardings.
     """
 
+    #: what one dispatch covers, for ``make_trace``
+    DISPATCH = "one compiled donated program per minibatch"
+    #: whether ``run()`` dispatches the per-minibatch programs.  Over a
+    #: mesh they then take the placement; a class that dispatches
+    #: others (the epoch scan) leaves them plain jits, which take a
+    #: caller's arrays wherever they lie (the benchmark's correctness
+    #: check hands ``_eval_step_`` a replicated batch)
+    DISPATCHES_STEPS = True
+
     def __init__(self, workflow, forwards, gd_units, loss="softmax",
-                 **kwargs):
+                 mesh=None, data_axis="data", model_axis=None,
+                 tp_mode="column", **kwargs):
         super().__init__(workflow, **kwargs)
         self.view_group = "TRAINER"
+        self.mesh = mesh
+        self.data_axis = data_axis
+        self.model_axis = model_axis
+        self.tp_mode = tp_mode
         self.gather_loader = None   # set by link_fused_gather
         self.forwards = list(forwards)
         self.gd_units = list(gd_units)
@@ -131,8 +170,12 @@ class FusedTrainStep(Unit, IResultProvider):
         loader.defer_device_gather = True
         return self
 
+    def __getstate__(self):
+        return mesh_mod.spec_in_state(super().__getstate__())
+
     # -- jit construction ----------------------------------------------------
     def initialize(self, device=None, **kwargs):
+        self.mesh = mesh_mod.live_mesh(self.mesh)
         # forwards live outside the control graph in fused mode, so they
         # have not been initialized by the dependency walk — bring them up
         # in chain order (shapes propagate input→output)
@@ -190,13 +233,7 @@ class FusedTrainStep(Unit, IResultProvider):
             h = x
             train = seed is not None
             if train and has_stochastic:
-                # rng_impl="rbg" swaps threefry for the TPU-cheap
-                # hardware RBG (dropout masks cost ~4% of an AlexNet
-                # step as threefry VPU work); default stays threefry —
-                # reproducible across backends
-                impl = root.common.engine.get("rng_impl",
-                                              "threefry2x32")
-                key = jax.random.key(seed, impl=impl)
+                key = jax.random.key(seed)
             stats = {}
             for i, fwd in enumerate(forwards[:-1]):
                 # token ids are not cast (bfloat16 holds integers up to
@@ -355,14 +392,52 @@ class FusedTrainStep(Unit, IResultProvider):
         self._unit_stats_ = {
             scopes[i]: f.stats_shapes() for i, f in enumerate(forwards[:-1])
             if with_stats[i]}
+        self._placement_ = None
         self._macc_ = self._macc_init()
+        # copy: the step donates its param buffers, so they must not alias
+        # the forward units' live weight Arrays
+        self._params_ = [
+            {k: jnp.array(v) for k, v in fwd.params.items()}
+            for fwd in forwards]
+        # solver state: restored from the GD units' pickled state when
+        # resuming a snapshot, else freshly initialized
+        self._opt_ = [
+            {name: (tuple(jnp.asarray(s) for s in
+                          gd.solver_state[name])
+                    if gd.solver_state.get(name) else
+                    gd.solver.init(p, jnp))
+             for name, p in self._params_[i].items()}
+            for i, gd in enumerate(gds)]
+        if self.mesh is not None:
+            self._placement_ = place = mesh_mod.TrainerPlacement(
+                self.mesh, self._params_, self._opt_, self.data_axis,
+                self.model_axis, self.tp_mode,
+                workflow=getattr(self._workflow, "name", "-"))
+            self._params_ = place.place(self._params_, "param")
+            self._opt_ = place.place(self._opt_, "opt")
+            self._macc_ = place.place(self._macc_, "rep")
+        #: the two raw step functions (the epoch scan loops over them)
+        self._step_fns_ = train_step, eval_step
         # the chain's evaluation-mode output (logits for a softmax or a
         # token head) as a function of the parameters: what a comparison
         # with a reference reads; compiled only if called
         self._forward_ = jax.jit(
             lambda params, x: net_apply(params, x, True, None))
-        self._train_step_ = jax.jit(train_step, donate_argnums=(0, 1, 2))
-        self._eval_step_ = jax.jit(eval_step, donate_argnums=(1,))
+        # ``size``, ``seed`` and ``lr_scale`` stay DYNAMIC (replicated
+        # scalars): a static size would recompile the step for every
+        # distinct tail batch.  Across processes the minibatch leaves
+        # the loader as a process-local array (argnums 3, 4 / 2, 3)
+        steps_place = self._placement_ if self.DISPATCHES_STEPS else None
+        self._train_step_ = jit_program(
+            steps_place, train_step,
+            ("param", "opt", "rep", "batch", "batch", "rep", "rep", "rep"),
+            ("param", "opt", "rep", "rep", "batch"),
+            donate_argnums=(0, 1, 2), host_argnums=(3, 4))
+        self._eval_step_ = jit_program(
+            steps_place, eval_step,
+            ("param", "rep", "batch", "batch", "rep"),
+            ("rep", "rep", "batch"), donate_argnums=(1,),
+            host_argnums=(2, 3))
         # the gather-in-step path needs the dataset resident on a real
         # device; numpy/force-numpy loaders fill minibatch_data host-side
         # instead, so fall back to the plain step there
@@ -370,15 +445,7 @@ class FusedTrainStep(Unit, IResultProvider):
                              getattr(self.gather_loader, "_use_device",
                                      False))
         if self._use_gather_:
-            # gather-in-step variants: the resident dataset rides as an
-            # ARGUMENT (a closed-over jax.Array would be baked into the
-            # HLO as a literal — see loader/fullbatch.py)
-            ld = self.gather_loader
-            self._data_dev_ = ld.original_data.devmem
-            if self.loss_kind == "softmax":
-                self._y_dev_ = jax.device_put(ld._dense_labels)
-            else:
-                self._y_dev_ = ld.original_targets.devmem
+            self._hold_resident_set(self.gather_loader)
 
             def train_step_g(data, y_all, params, opt, macc, idx, size,
                              seed, lr_scale):
@@ -398,34 +465,37 @@ class FusedTrainStep(Unit, IResultProvider):
                                           donate_argnums=(2, 3, 4))
             self._eval_step_g_ = jax.jit(eval_step_g, donate_argnums=(3,))
         # persistent executable cache (compilecache subsystem): wrap the
-        # jitted steps so an ElasticRunner respawn / snapshot restore
-        # deserializes yesterday's executable instead of recompiling.
-        # AotStep keeps __wrapped__ (the scan/mesh steps re-jit from the
-        # raw function); a step the compiler refuses raises there too.
-        # No configured cache dir = exactly the code above (the gather
-        # steps apart: _place_data)
+        # per-step programs that are plain jits so an ElasticRunner
+        # respawn / snapshot restore deserializes yesterday's executable
+        # instead of recompiling (the placed programs and the scans stay
+        # jits: ROADMAP D14); a step the compiler refuses raises there
+        # too.  No configured cache dir = exactly the code above (the
+        # gather steps apart: _place_data)
         cache = default_cache()
-        if cache is not None:
+        if cache is not None and steps_place is None:
             self._train_step_ = AotStep(self._train_step_, cache,
                                         "fused.train_step")
             self._eval_step_ = AotStep(self._eval_step_, cache,
                                        "fused.eval_step")
-        # copy: the step donates its param buffers, so they must not alias
-        # the forward units' live weight Arrays
-        self._params_ = [
-            {k: jnp.array(v) for k, v in fwd.params.items()}
-            for fwd in forwards]
-        # solver state: restored from the GD units' pickled state when
-        # resuming a snapshot, else freshly initialized
-        self._opt_ = [
-            {name: (tuple(jnp.asarray(s) for s in
-                          gd.solver_state[name])
-                    if gd.solver_state.get(name) else
-                    gd.solver.init(p, jnp))
-             for name, p in self._params_[i].items()}
-            for i, gd in enumerate(gds)]
         if self._use_gather_:
             self._place_data()
+
+    def _hold_resident_set(self, ld):
+        """The loader's resident set and its labels, for the programs
+        that gather their minibatches on the device (the gather step,
+        the scans).  They ride as ARGUMENTS: a closed-over jax.Array
+        would be baked into the HLO as a literal, bloating the
+        executable by the whole set (see loader/fullbatch.py).  Over a
+        mesh the set is replicated: every shard gathers its own rows."""
+        import jax
+        self._data_dev_ = ld.original_data.devmem
+        if self.loss_kind == "softmax":
+            self._y_dev_ = jax.device_put(ld._dense_labels)
+        else:
+            self._y_dev_ = ld.original_targets.devmem
+        if self._placement_ is not None:
+            self._data_dev_, self._y_dev_ = self._placement_.place(
+                (self._data_dev_, self._y_dev_), "rep")
 
     def _lower_gather_train(self, data, *rest):
         """``train_step_g`` lowered for the arguments ``(data, *rest)``
@@ -523,23 +593,28 @@ class FusedTrainStep(Unit, IResultProvider):
         return stats["bytes_in_use"] + need <= stats["bytes_limit"]
 
     def _macc_init(self):
-        """Fresh on-device metric accumulator pytree."""
+        """Fresh on-device metric accumulator pytree, placed over a
+        mesh like the one the programs return."""
         import jax.numpy as jnp
         if self.loss_kind == "token":
-            return {"n_err": jnp.zeros((), jnp.int32),
+            macc = {"n_err": jnp.zeros((), jnp.int32),
                     "loss_sum": jnp.zeros((), jnp.float32),
                     "units": {scope: {name: jnp.zeros(shape, jnp.int32)
                                       for name, shape in shapes.items()}
                               for scope, shapes in
                               self._unit_stats_.items()}}
-        if self.loss_kind == "softmax":
+        elif self.loss_kind == "softmax":
             c = self._n_classes if self.compute_confusion_matrix else 0
-            return (jnp.zeros((), jnp.int32),
+            macc = (jnp.zeros((), jnp.int32),
                     jnp.zeros((c, c), jnp.int32),
                     jnp.zeros((), jnp.float32))
-        return (jnp.zeros((), jnp.float32),
-                jnp.zeros((), jnp.float32),
-                jnp.full((), jnp.inf, jnp.float32))
+        else:
+            macc = (jnp.zeros((), jnp.float32),
+                    jnp.zeros((), jnp.float32),
+                    jnp.full((), jnp.inf, jnp.float32))
+        if self._placement_ is None:
+            return macc
+        return self._placement_.fresh_accumulator(macc)
 
     # -- run -----------------------------------------------------------------
     def _staged_seed_arg(self):
@@ -746,9 +821,11 @@ class FusedTrainStep(Unit, IResultProvider):
         """The hand-fused step is already ONE compiled, donated program:
         under whole-workflow compilation it reports as a pre-compiled
         region of its own (one producer of traced regions, not a special
-        case) and keeps executing natively — including its sharded and
-        epoch-scan subclasses, whose in-program shardings survive
-        untouched."""
+        case) and keeps executing natively, per minibatch or per class,
+        its in-program shardings (and the all-reduce XLA derives from
+        them) untouched by the graph compiler."""
         from ..graphcomp.faces import OpaqueFace
-        return OpaqueFace(self, "hand-fused train step: one compiled "
-                                "donated program per minibatch")
+        over = "" if self.mesh is None else \
+            ", SPMD over the %r mesh axes" % list(self.mesh.axis_names)
+        return OpaqueFace(self, "hand-fused train step: %s%s"
+                          % (self.DISPATCH, over))

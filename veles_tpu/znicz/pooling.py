@@ -3,18 +3,11 @@
 Re-creation of ``veles.znicz.pooling`` (absent; SURVEY.md §2.9):
 MaxPooling, AvgPooling, MaxAbsPooling, StochasticPooling(±Abs, ±Depooling).
 
-TPU-first: ``lax.reduce_window`` — XLA's native windowed reduction —
-whose autodiff emits ``SelectAndScatter`` for the backward.
-:func:`fast_max_pool` is a measured-and-rejected alternative kept for
-the record: a window-offset formulation with a hand-written VJP (int8
-argmax plane forward, ky*kx predicated dilated pads backward) built on
-the hypothesis that SelectAndScatter was the memory-bound backward
-bottleneck; the round-4 interleaved on-chip A/B showed the OPPOSITE —
-reduce_window trains AlexNet ~28 % faster end-to-end (7921 vs 6198
-img/s median; docs/PERF.md) because XLA:TPU's select-and-scatter is
-fine while the offset formulation's extra planes defeat fusion.  It
-stays exported (grad-parity-tested against the reduce_window oracle)
-for shapes where a recorded-argmax pooling is needed.
+TPU-first: ``lax.reduce_window`` (XLA's native windowed reduction),
+whose autodiff emits ``SelectAndScatter`` for the backward.  What was
+tried against it on the chip and turned down (a window-offset max pool
+with a hand-written VJP, a separable window, bfloat16 activations
+through the window) is recorded in docs/PERF.md, rounds 4 and 5.
 
 MaxAbsPooling keeps the *signed* value whose magnitude wins (the Znicz
 semantic), built from two reductions.  Stochastic pooling samples a
@@ -23,83 +16,11 @@ Fergus), keyed by the unit's deterministic KeyTree so runs are
 reproducible.
 """
 
-import functools
-
-import jax
 import numpy
 
 from ..prng.random_generator import KeyTree
 from .nn_units import ParamlessForward
 from .conv import _quad
-
-
-def _offset_slice(arr, oy, ox, sy, sx, oh, ow):
-    """The [b, oh, ow, c] plane of window element (oy, ox) across all
-    (strided) window positions of a padded input."""
-    return arr[:, oy:oy + (oh - 1) * sy + 1:sy,
-               ox:ox + (ow - 1) * sx + 1:sx, :]
-
-
-def _max_pool_core(x, window, strides, padding, use_abs, want_idx):
-    import jax.numpy as jnp
-    ky, kx = window
-    sy, sx = strides
-    (pt, pb), (pl, pr) = padding
-    pad_val = 0.0 if use_abs else -numpy.inf
-    xp_arr = jnp.pad(x, ((0, 0), (pt, pb), (pl, pr), (0, 0)),
-                     constant_values=jnp.asarray(pad_val, x.dtype))
-    hp, wp = xp_arr.shape[1], xp_arr.shape[2]
-    oh, ow = (hp - ky) // sy + 1, (wp - kx) // sx + 1
-    best = key = idx = None
-    for k, (oy, ox) in enumerate(
-            (oy, ox) for oy in range(ky) for ox in range(kx)):
-        s = _offset_slice(xp_arr, oy, ox, sy, sx, oh, ow)
-        cur = jnp.abs(s) if use_abs else s
-        if best is None:
-            best, key = s, cur
-            idx = jnp.zeros(s.shape, jnp.int8) if want_idx else None
-        else:
-            better = cur > key  # strict: first max in window order wins
-            best = jnp.where(better, s, best)
-            key = jnp.where(better, cur, key)
-            if want_idx:
-                idx = jnp.where(better, jnp.int8(k), idx)
-    return best, idx
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def fast_max_pool(x, window, strides, padding, use_abs):
-    """Max (or max-|.|) pooling with a scatter-free backward; see the
-    module docstring.  ``window``/``strides`` are (y, x) ints,
-    ``padding`` is ((top, bottom), (left, right))."""
-    best, _ = _max_pool_core(x, window, strides, padding, use_abs, False)
-    return best
-
-
-def _fast_max_pool_fwd(x, window, strides, padding, use_abs):
-    best, idx = _max_pool_core(x, window, strides, padding, use_abs, True)
-    return best, (idx, x.shape)
-
-
-def _fast_max_pool_bwd(window, strides, padding, use_abs, res, g):
-    import jax.numpy as jnp
-    idx, xshape = res
-    ky, kx = window
-    sy, sx = strides
-    (pt, pb), (pl, pr) = padding
-    b, h, w, c = xshape
-    hp, wp = h + pt + pb, w + pl + pr
-    oh, ow = (hp - ky) // sy + 1, (wp - kx) // sx + 1
-    dxp = jnp.zeros((b, hp, wp, c), g.dtype)
-    for k, (oy, ox) in enumerate(
-            (oy, ox) for oy in range(ky) for ox in range(kx)):
-        contrib = jnp.where(idx == jnp.int8(k), g,
-                            jnp.zeros((), g.dtype))
-        dxp = _offset_slice(dxp.at, oy, ox, sy, sx, oh, ow).add(contrib)
-    return (dxp[:, pt:pt + h, pl:pl + w, :],)
-
-
-fast_max_pool.defvjp(_fast_max_pool_fwd, _fast_max_pool_bwd)
 
 
 class PoolingBase(ParamlessForward):
@@ -152,57 +73,14 @@ class PoolingBase(ParamlessForward):
 
 
 class MaxPooling(PoolingBase):
-    """Max pooling via ``lax.reduce_window``, plus two opt-in layout
-    experiments for the memory-bound pool region (round-5 hypotheses;
-    docs/PERF.md ablation: max-pool machinery ~25 % of the AlexNet f32
-    step):
-
-    - ``pool_separable``: the 2-D window as two 1-D reduce_windows
-      (rows then cols) — exact for max, reads ky+kx elements per output
-      instead of ky*kx, and the backward becomes two smaller
-      select-and-scatters (the first pass output is already
-      row-decimated);
-    - ``pool_bf16``: run the window (and therefore its backward select)
-      on bfloat16 activations — halves the HBM bytes of the dominant
-      pre-pool tensor; output upcast to the input dtype.  Numerics: max
-      VALUES round to bf16 (~3 decimal digits) and near-ties may pick a
-      different winner; opt-in only.
-
-    Both default to ``root.common.engine.pool_separable`` /
-    ``.pool_bf16`` (False) and compose."""
-
     MAPPING = "max_pooling"
     PAD_VALUE = -numpy.inf
 
-    def __init__(self, workflow, **kwargs):
-        super().__init__(workflow, **kwargs)
-        from ..config import root
-        eng = root.common.engine
-        self.pool_separable = bool(kwargs.get(
-            "pool_separable", eng.get("pool_separable", False)))
-        self.pool_bf16 = bool(kwargs.get(
-            "pool_bf16", eng.get("pool_bf16", False)))
-
     def apply(self, params, x):
-        import jax.numpy as jnp
         from jax import lax
-        dtype = x.dtype
-        if self.pool_bf16:
-            x = x.astype(jnp.bfloat16)
-        if self.pool_separable:
-            (pt, pb), (pl, pr) = self._window_padding()[1:3]
-            sy, sx = self.sliding
-            x = lax.reduce_window(
-                x, -numpy.inf, lax.max, (1, self.ky, 1, 1),
-                (1, sy, 1, 1), ((0, 0), (pt, pb), (0, 0), (0, 0)))
-            x = lax.reduce_window(
-                x, -numpy.inf, lax.max, (1, 1, self.kx, 1),
-                (1, 1, sx, 1), ((0, 0), (0, 0), (pl, pr), (0, 0)))
-        else:
-            x = lax.reduce_window(
-                x, -numpy.inf, lax.max, self._window_dims(),
-                self._window_strides(), self._window_padding())
-        return x.astype(dtype) if x.dtype != dtype else x
+        return lax.reduce_window(
+            x, -numpy.inf, lax.max, self._window_dims(),
+            self._window_strides(), self._window_padding())
 
     def apply_numpy(self, params, x):
         out = numpy.empty(self.output_shape_for(x.shape), x.dtype)

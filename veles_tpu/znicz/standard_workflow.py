@@ -278,45 +278,33 @@ class StandardWorkflow(Workflow):
                 raise ValueError(
                     "zero_filler is graph-mode only; use Conv(grouping=N) "
                     "in fused workflows (see ZeroFiller docstring)")
+        # how many minibatches a dispatch covers is the class; where the
+        # operands lie (one device, or --mesh) is the step's argument
+        if self.epoch_scan:
+            from .scan_step import ScanEpochStep as step_class
+        else:
+            step_class = FusedTrainStep
+        self.fused_step = step_class(
+            self, self.forwards, self.gds, loss=self.loss_function,
+            mesh=self.mesh, model_axis=self.model_axis,
+            tp_mode=self.tp_mode, **self.trainer_config)
         if self.epoch_scan:
             from ..mutable import Bool
-            if self.mesh is not None:
-                # the two big levers composed: one scan dispatch per
-                # class AND dp/tp shardings over the mesh
-                from ..parallel.scan import DistributedScanStep
-                self.fused_step = DistributedScanStep(
-                    self, self.forwards, self.gds, mesh=self.mesh,
-                    loss=self.loss_function, model_axis=self.model_axis,
-                    tp_mode=self.tp_mode, **self.trainer_config)
-            else:
-                from .scan_step import ScanEpochStep
-                self.fused_step = ScanEpochStep(
-                    self, self.forwards, self.gds,
-                    loss=self.loss_function, **self.trainer_config)
             # the scan step drives the loader itself; the loader stays
             # linked (so it initializes before the scan step in dependency
             # order) but permanently blocked from running
             self.loader.gate_block = Bool(True)
             self.fused_step.link_from(self.repeater)
             self.fused_step.link_scan_loader(self.loader)
-        elif self.mesh is not None:
-            from ..parallel.dp import DistributedTrainStep
-            self.fused_step = DistributedTrainStep(
-                self, self.forwards, self.gds, mesh=self.mesh,
-                loss=self.loss_function, model_axis=self.model_axis,
-                tp_mode=self.tp_mode, **self.trainer_config)
-            self.fused_step.link_from(self.loader)
-            self.fused_step.link_loader(self.loader)
         else:
-            self.fused_step = FusedTrainStep(
-                self, self.forwards, self.gds, loss=self.loss_function,
-                **self.trainer_config)
             self.fused_step.link_from(self.loader)
             self.fused_step.link_loader(self.loader)
             from ..loader.fullbatch import FullBatchLoader
-            if isinstance(self.loader, FullBatchLoader):
+            if self.mesh is None and isinstance(self.loader,
+                                                FullBatchLoader):
                 # HBM-resident dataset: gather rides inside the jitted
-                # step — one executable launch per minibatch
+                # step — one executable launch per minibatch (over a
+                # mesh the loader hands minibatches over: ROADMAP W5)
                 self.fused_step.link_fused_gather(self.loader)
         self.decision.link_from(self.fused_step)
         self.decision.link_loader(self.loader)
@@ -367,23 +355,15 @@ class StandardWorkflow(Workflow):
         return self.snapshotter
 
     def __getstate__(self):
-        state = super().__getstate__()
-        mesh = state.get("mesh")
-        if mesh is not None and not isinstance(mesh, dict):
-            # jax Device handles are process-local; snapshot the axis
-            # geometry instead (the sharded steps do the same) and
-            # rebuild over the restoring process's devices
-            from ..parallel import mesh as mesh_mod
-            state["mesh"] = mesh_mod.mesh_spec(mesh)
-        return state
+        from ..parallel.mesh import spec_in_state
+        return spec_in_state(super().__getstate__())
 
     def initialize(self, device=None, **kwargs):
-        if isinstance(self.mesh, dict):   # restored from a snapshot
-            from ..parallel import mesh as mesh_mod
-            self.mesh = mesh_mod.mesh_for_spec(self.mesh)
-        # cross-mesh restore: the workflow's mesh (spec-rebuilt above,
-        # or a Mesh the caller assigned before initialize) overrides the
-        # geometry the sharded step snapshotted for itself
+        from ..parallel.mesh import live_mesh
+        self.mesh = live_mesh(self.mesh)
+        # cross-mesh restore: the workflow's mesh (rebuilt from its spec
+        # above, or a Mesh the caller assigned before initialize)
+        # overrides the geometry the step snapshotted for itself
         step = getattr(self, "fused_step", None)
         if self.mesh is not None and getattr(step, "mesh", None) is not None:
             step.mesh = self.mesh
@@ -412,12 +392,13 @@ class StandardWorkflow(Workflow):
     def _maybe_attach_prefetcher(self, device):
         """Overlap host minibatch prep with device compute on the
         per-step fused path (loader/prefetch.py).  The epoch-scan path
-        already amortizes the whole class into one dispatch, and the
-        multi-host distributed step re-places host batches itself, so
-        both skip."""
+        already amortizes the whole class into one dispatch, and a mesh
+        step across processes places host batches itself, so both
+        skip."""
         if not self.fused or self.epoch_scan or self.fused_step is None:
             return
-        if getattr(self.fused_step, "_prefetch_unsupported_", False):
+        placement = getattr(self.fused_step, "_placement_", None)
+        if placement is not None and placement.batch_staging() is None:
             return
         stage = bool(device is not None and
                      getattr(device, "exists", False))
