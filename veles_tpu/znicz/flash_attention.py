@@ -36,6 +36,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import backends
 
@@ -63,6 +64,13 @@ _STAT_LANES = 128       # per-row stats (lse, delta) ride a full lane
                         # layout.
 _NEG_INF = float("-inf")
 _warned_shapes = set()
+#: what the forward rules call (``checkpoint_name``) the two results the
+#: backward kernels read, the output and the row statistics: a
+#: ``jax.checkpoint`` whose policy saves these names does not rerun the
+#: forward kernel in the backward pass (the plain family, the latent one);
+#: outside such a policy a name is an identity
+SAVED_NAMES = ("flash_out", "flash_lse")
+MLA_SAVED_NAMES = ("mla_flash_out", "mla_flash_lse")
 
 
 def _blocks(t, block_q, block_k):
@@ -520,7 +528,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     bq, bk = blocks
     out_bh, lse = _flash_fwd_bh(_to_bh(q), _to_bh(k), _to_bh(v),
                                 scale, causal, bq, bk, window=window)
-    out = _from_bh(out_bh, b, h)
+    out = checkpoint_name(_from_bh(out_bh, b, h), SAVED_NAMES[0])
+    lse = checkpoint_name(lse, SAVED_NAMES[1])
     return out, (q, k, v, out, lse)
 
 
@@ -863,7 +872,12 @@ def _mla_flash_fwd(q_nope, q_rope, k_nope, k_rope, v, causal, scale,
     out, lse = _mla_fwd_bh(_flat(q_nope), _flat(q_rope), _flat(k_nope),
                            _flat(k_rope), _flat(v), scale, causal, block_q,
                            block_k)
-    out = out.reshape(v.shape)
+    out = checkpoint_name(out.reshape(v.shape), MLA_SAVED_NAMES[0])
+    # the statistics wait for the backward pass as [BH, T]: of [BH, T, 1],
+    # as the kernels write and read them, the v5e pads the 1 to 128
+    # lanes, 268 MB a block of 2 x 8,192 tokens where 2 MB are numbers
+    # (PERF.md section 6, PR 31)
+    lse = checkpoint_name(lse.reshape(lse.shape[:2]), MLA_SAVED_NAMES[1])
     return out, (q_nope, q_rope, k_nope, k_rope, v, out, lse)
 
 
@@ -871,8 +885,8 @@ def _mla_flash_bwd(causal, scale, block_q, block_k, res, g):
     q_nope, q_rope, k_nope, k_rope, v, out, lse = res
     dqn, dqr, dkn, dkr, dv = _mla_bwd_bh(
         _flat(q_nope), _flat(q_rope), _flat(k_nope), _flat(k_rope),
-        _flat(v), _flat(out), lse, _flat(g), scale, causal, block_q,
-        block_k)
+        _flat(v), _flat(out), lse.reshape(lse.shape + (1,)), _flat(g),
+        scale, causal, block_q, block_k)
     dkr = dkr.reshape(q_rope.shape)
     if k_rope.ndim == 3:        # one key for all heads: their sum
         dkr = dkr.sum(axis=1)

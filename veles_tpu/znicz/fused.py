@@ -47,6 +47,61 @@ def jit_program(placement, fn, in_kinds, out_kinds, donate_argnums,
                          host_argnums)
 
 
+def applier(fwd):
+    """What the chain calls of the forward unit ``fwd``: its
+    ``apply_stats`` where it counts, else its ``apply``; under
+    ``jax.checkpoint`` where the unit declares ``remat``, and that
+    checkpoint keeping the values the unit names in ``remat_saves``
+    (``jax.ad_checkpoint.checkpoint_name``) where it names any."""
+    import jax
+    fn = getattr(fwd, "apply_stats", fwd.apply)
+    if not getattr(fwd, "remat", False):
+        return fn
+    saves = getattr(fwd, "remat_saves", ())
+    if not saves:
+        return jax.checkpoint(fn)
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(*saves))
+
+
+def jaxpr_equations(jaxpr):
+    """Every equation of ``jaxpr`` and, depth first, of the jaxprs among
+    its equations' parameters (a checkpoint's, a custom rule's, a
+    scan's)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)        # a closed one
+                if hasattr(sub, "eqns"):
+                    yield from jaxpr_equations(sub)
+
+
+def saved_bytes(fwd, keep_f32, cdtype):
+    """The bytes of the values ``fwd``'s ``apply`` names among its
+    ``remat_saves``, which the train step holds from the unit's forward
+    pass to its backward pass: from the shapes alone, by a trace of the
+    unit over one whole minibatch (over a mesh: all shards') in the
+    chain's arithmetic.  A path that names nothing (explicit scores in
+    place of the kernel) holds nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    def in_chain(a, cast):
+        floating = jnp.issubdtype(a.dtype, jnp.floating)
+        return jax.ShapeDtypeStruct(
+            tuple(a.shape), cdtype if cast and floating else a.dtype)
+    cast = cdtype is not None
+    params = {k: in_chain(p, cast and k not in keep_f32)
+              for k, p in fwd.params.items()}
+    traced = jax.make_jaxpr(fwd.apply)(params, in_chain(fwd.input, cast))
+    return sum(
+        v.aval.size * v.aval.dtype.itemsize
+        for eqn in jaxpr_equations(traced.jaxpr)
+        if eqn.primitive.name == "name"
+        and eqn.params["name"] in fwd.remat_saves for v in eqn.outvars)
+
+
 def scope_names(units):
     """The ``jax.named_scope`` of each unit of a chain: the unit's own
     name, with its index where two units share one.  A trace then names
@@ -201,16 +256,13 @@ class FusedTrainStep(Unit, IResultProvider):
 
         # what a unit may ask of the trainer (znicz/transformer.py):
         # FLOAT32_PARAMS, tensors the boundary cast leaves float32;
-        # ``remat``, jax.checkpoint around its apply; ``apply_stats``,
-        # an apply that also returns counters for the accumulator
+        # ``remat``, jax.checkpoint around its apply, and ``remat_saves``,
+        # what that checkpoint keeps; ``apply_stats``, an apply that also
+        # returns counters for the accumulator
         keep_f32 = [getattr(f, "FLOAT32_PARAMS", ()) for f in forwards]
         with_stats = [hasattr(f, "apply_stats") for f in forwards]
-
-        def applier(fwd):
-            fn = getattr(fwd, "apply_stats", fwd.apply)
-            return jax.checkpoint(fn) if getattr(fwd, "remat", False) \
-                else fn
         appliers = [applier(f) for f in forwards]
+        self._file_remat(scopes, keep_f32, cdtype)
 
         def net_body(params, x, seed):
             """The chain up to the last unit: (its input, the parameters
@@ -479,6 +531,27 @@ class FusedTrainStep(Unit, IResultProvider):
                                        "fused.eval_step")
         if self._use_gather_:
             self._place_data()
+
+    def _file_remat(self, scopes, keep_f32, cdtype):
+        """The record of what the step's checkpoints do, filed once as
+        the step is built (span ``step.remat``): the units under a
+        ``jax.checkpoint`` (``units``, by scope), what each keeps across
+        it (``saves``: ``scope:name+name``) and the bytes a train step
+        holds for that from forward to backward (``bytes``; the span's
+        length is the trace that found them).  A chain with no
+        checkpointed unit files nothing."""
+        under = [(scope, fwd, keep) for scope, fwd, keep in zip(
+            scopes, self.forwards, keep_f32) if getattr(fwd, "remat", False)]
+        if not under:
+            return
+        saving = [(scope, fwd, keep) for scope, fwd, keep in under
+                  if getattr(fwd, "remat_saves", ())]
+        with events.timed(
+                "step.remat", units=",".join(scope for scope, _, _ in under),
+                saves=" ".join("%s:%s" % (scope, "+".join(fwd.remat_saves))
+                               for scope, fwd, _ in saving)) as span:
+            span.count(bytes=sum(saved_bytes(fwd, keep, cdtype)
+                                 for _, fwd, keep in saving))
 
     def _hold_resident_set(self, ld):
         """The loader's resident set and its labels, for the programs

@@ -22,6 +22,10 @@ its upload would be tens of seconds of set-up.
 What a unit tells the trainer (``fused.py`` reads these attributes of any
 forward unit): ``remat`` asks for ``jax.checkpoint`` around ``apply``
 (the block's activations are recomputed in the backward pass);
+``remat_saves`` lists the values, by their ``checkpoint_name``, that the
+checkpoint keeps from the forward pass instead (the attention block: the
+flash kernel's output and row statistics, so the backward pass reruns
+the cheap projections around the kernel and not the kernel);
 ``apply_stats`` returns ``(y, stats)`` with counters the step's device
 accumulator sums; ``token_loss`` (the head) folds the vocabulary
 projection and the loss over blocks of tokens.
@@ -83,6 +87,9 @@ class BlockBase(ForwardBase):
     FLOAT32_PARAMS = ("norm",)
     #: ask the trainer for jax.checkpoint around apply
     remat = True
+    #: the ``checkpoint_name``s whose values that checkpoint keeps for the
+    #: backward pass (``save_only_these_names``); none: all is recomputed
+    remat_saves = ()
 
     def __init__(self, workflow, **kwargs):
         super().__init__(workflow, **kwargs)
@@ -195,6 +202,10 @@ class LatentAttentionBlock(BlockBase):
 
     MAPPING = "latent_attention_block"
     FLOAT32_PARAMS = ("norm", "kv_norm")
+    #: ``flash_attention.MLA_SAVED_NAMES``: the kernel's two results, 136
+    #: MB a block at 2 x 8,192 tokens, where recomputing them reran the
+    #: forward kernel, 13.6 ms a block a step (PERF.md section 6, PR 31)
+    remat_saves = ("mla_flash_out", "mla_flash_lse")
 
     def __init__(self, workflow, **kwargs):
         super().__init__(workflow, **kwargs)
