@@ -186,6 +186,33 @@ sys.exit(cs.run(backend="cpu"))
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize("kv_heads", [8, 32], ids=["grouped", "one-count"])
+def test_grouped_query_flash_compiles_for_v5e(v5e, kv_heads, grad):
+    """The plain flash kernels as the grouped-query block calls them: 32
+    query heads of 64 on 8 key-value heads (and on 32, the group of 1),
+    two sequences of 8,192, bfloat16, at the block sizes the kernel
+    layer picks: head size 64 tiles, K and V stay at their head count."""
+
+    def struct(heads):
+        return jax.ShapeDtypeStruct((2, 8192, heads, 64), jnp.bfloat16,
+                                    sharding=v5e)
+
+    def attend(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True)
+    operands = (struct(32), struct(kv_heads), struct(kv_heads))
+    if not grad:
+        assert "gqa_flash_fwd" in _compile(attend, *operands)
+        return
+    text = _compile(jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), *operands)
+    for kernel in ("gqa_flash_fwd", "gqa_flash_dq", "gqa_flash_dkv"):
+        assert kernel in text, kernel
+    # dK and dV leave the kernel per KEY-VALUE head
+    assert "bf16[%d,8192,64]" % (2 * kv_heads) in text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
 @pytest.mark.parametrize("shared", [True, False], ids=["shared", "copied"])
 def test_latent_flash_attention_compiles_for_v5e(v5e, shared, grad):
     """The latent-attention kernels at the widths of a 32-head model

@@ -63,7 +63,8 @@ class PlainAttention:
 FAMILIES = {
     "latent": (latent_block, ("mla_flash_fwd", "mla_flash_dq",
                               "mla_flash_dkv")),
-    "plain": (PlainAttention, ("_fwd_kernel", "_dq_kernel", "_dkv_kernel")),
+    "plain": (PlainAttention, ("gqa_flash_fwd", "gqa_flash_dq",
+                               "gqa_flash_dkv")),
 }
 
 

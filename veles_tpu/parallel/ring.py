@@ -27,7 +27,9 @@ from jax import lax
 def attention_reference(q, k, v, causal=False, scale=None,
                         window=None):
     """Plain single-device softmax attention, [B, T, H, D] layout —
-    the parity oracle (and the small-model fallback).  ``window``
+    the parity oracle (and the small-model fallback).  ``k`` and ``v``
+    may have fewer heads than ``q`` (grouped-query attention: query head
+    ``h`` reads key-value head ``h // (H_q / H_kv)``).  ``window``
     (requires ``causal``): sliding-window attention — position i sees
     keys in (i - window, i], the Mistral-style band."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
@@ -35,7 +37,12 @@ def attention_reference(q, k, v, causal=False, scale=None,
         raise ValueError("window requires causal=True")
     if window is not None and window < 1:
         raise ValueError("window must be >= 1, got %r" % (window,))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    # the query heads of a group as rows of their key-value head (one
+    # head count: groups of 1)
+    b, t, h, d = q.shape
+    kv = k.shape[2]
+    s = jnp.einsum("bqngd,bknd->bngqk", q.reshape(b, t, kv, h // kv, d),
+                   k) * scale
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         rows = jnp.arange(tq)[:, None]
@@ -45,7 +52,7 @@ def attention_reference(q, k, v, causal=False, scale=None,
             mask = mask | (cols <= rows - window)
         s = jnp.where(mask, -jnp.inf, s)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    return jnp.einsum("bngqk,bknd->bqngd", p, v).reshape(b, t, h, -1)
 
 
 def _ring_attention_local(q, k, v, axis_name, causal, scale,

@@ -47,6 +47,11 @@ def _interpret():
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
+#: both blocks for heads of 64 and narrower without a window, measured:
+#: [2, 8192, 32 on 8, 64] bfloat16, causal, forward + backward on the
+#: v5e take 123.3 ms at 256, 58.8 at 512, 44.2 at 1,024 (PERF.md section
+#: 6, PR 33)
+NARROW_HEAD_BLOCK = 1024
 _MIN_BLOCK = 32         # >= f32 sublane tile; smallest worthwhile tile
 _STAT_LANES = 128       # per-row stats (lse, delta) ride a full lane
                         # dim INSIDE the kernels: Mosaic requires block
@@ -88,6 +93,14 @@ def _blocks(t, block_q, block_k):
     if bq is None or bk is None:
         return None
     return bq, bk
+
+
+def default_blocks(d, window=None):
+    """(block_q, block_k) of head size ``d`` where no tuning record and
+    no caller says otherwise."""
+    if d <= 64 and window is None:
+        return NARROW_HEAD_BLOCK, NARROW_HEAD_BLOCK
+    return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
 
 
 def flash_attention_supported(t, block_q=DEFAULT_BLOCK_Q,
@@ -220,11 +233,16 @@ def _struct(shape, dtype, vma):
 
 def _flash_fwd_bh(q, k, v, scale, causal, block_q, block_k, vma=None,
                   window=None):
-    """Forward over [BH, T, D] operands; returns (out, lse[BH, T])."""
+    """Forward over ``q`` [B * H_q, T, D] and ``k``, ``v`` [B * H_kv, T,
+    D] (``H_q`` a multiple of ``H_kv``: query head ``h`` reads key-value
+    head ``h // (H_q / H_kv)`` through the K/V index map, so no copy of
+    K or V per query head exists in HBM); returns (out, lse[B * H_q,
+    T])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q.shape
+    group = bh // k.shape[0]
     n_q, n_k = t // block_q, t // block_k
     if window is not None and _kband_size(block_q, block_k,
                                           window) >= n_k:
@@ -244,11 +262,12 @@ def _flash_fwd_bh(q, k, v, scale, causal, block_q, block_k, vma=None,
         compact_stats=compact)
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     if window_grid is None:
-        k_index = lambda b, i, j: (b, j, 0)  # noqa: E731
+        k_index = lambda b, i, j: (b // group, j, 0)  # noqa: E731
     else:
         k_index = lambda b, i, j: (  # noqa: E731
-            b, jnp.clip(_kband_start(i, block_q, block_k, window_grid)
-                        + j, 0, n_k - 1), 0)
+            b // group,
+            jnp.clip(_kband_start(i, block_q, block_k, window_grid)
+                     + j, 0, n_k - 1), 0)
     kspec = pl.BlockSpec((1, block_k, d), k_index)
     if compact:
         # one [block_q // 128, 128] slab per Q block, as the LAST TWO
@@ -272,7 +291,7 @@ def _flash_fwd_bh(q, k, v, scale, causal, block_q, block_k, vma=None,
         scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret())(q, k, v)
+        name="gqa_flash_fwd", interpret=_interpret())(q, k, v)
     # contiguous fold back to [BH, T] rows (free: a metadata reshape in
     # the compact layout, a lane slice otherwise)
     return out, (lse.reshape(bh, t) if compact else lse[:, :, 0])
@@ -322,14 +341,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal,
-                block_q, block_k, window=None, window_grid=None,
-                n_q_total=None):
+                block_q, block_k, n_q_inner, window=None,
+                window_grid=None, n_q_total=None):
+    """The K block of one KEY-VALUE head is pinned; the Q blocks of the
+    query heads that read it stream through the innermost grid axis
+    (``n_q_inner`` blocks a head, head after head), so dK and dV are
+    summed over the group in VMEM and written once."""
     from jax.experimental import pallas as pl
 
     jk, j = pl.program_id(1), pl.program_id(2)
     n_inner = pl.num_programs(2)
-    iq = j if window_grid is None else _qband_start(
-        jk, block_q, block_k) + j
+    i = j % n_q_inner
+    iq = i if window_grid is None else _qband_start(
+        jk, block_q, block_k) + i
     visible = (_block_needed(iq, jk, block_q, block_k, window)
                if causal else iq >= 0)
     if window_grid is not None:
@@ -375,13 +399,20 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_bh(q, k, v, out, lse, do, scale, causal, block_q,
                   block_k, vma=None, delta=None, window=None):
-    """lse (and the optional precomputed delta) may arrive either as
-    [BH, T] rows or already lane-broadcast [BH, T, _STAT_LANES] — the
-    ring backward hoists the broadcast out of its per-hop loop."""
+    """``q``, ``out``, ``do`` [B * H_q, T, D]; ``k``, ``v`` [B * H_kv,
+    T, D] -> (dq, dk, dv), dk and dv per KEY-VALUE head: the dk/dv
+    kernel's streamed axis walks the query heads of a group, so their
+    sum is taken in VMEM (nothing is written per query head, no
+    reduction follows).  lse (and the optional precomputed delta) may
+    arrive either as [BH, T] rows or already lane-broadcast [BH, T,
+    _STAT_LANES] — the ring backward hoists the broadcast out of its
+    per-hop loop."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q.shape
+    bh_kv = k.shape[0]
+    group = bh // bh_kv
     n_q, n_k = t // block_q, t // block_k
     if delta is None:
         # delta_i = sum_d do*out — tiny elementwise reduce; XLA fuses it
@@ -409,11 +440,12 @@ def _flash_bwd_bh(q, k, v, out, lse, do, scale, causal, block_q,
     qrow = pl.BlockSpec((1, block_q, _STAT_LANES),
                         lambda b, i, j: (b, i, 0))
     if wg_k is None:
-        dq_k_index = lambda b, i, j: (b, j, 0)  # noqa: E731
+        dq_k_index = lambda b, i, j: (b // group, j, 0)  # noqa: E731
     else:
         dq_k_index = lambda b, i, j: (  # noqa: E731
-            b, jnp.clip(_kband_start(i, block_q, block_k, wg_k) + j,
-                        0, n_k - 1), 0)
+            b // group,
+            jnp.clip(_kband_start(i, block_q, block_k, wg_k) + j,
+                     0, n_k - 1), 0)
     kspec = pl.BlockSpec((1, block_k, d), dq_k_index)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -424,30 +456,35 @@ def _flash_bwd_bh(q, k, v, out, lse, do, scale, causal, block_q,
         out_specs=qspec,
         out_shape=_struct((bh, t, d), q.dtype, vma),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret())(q, k, v, do, lse, delta)
-    # dk/dv pass: K block pinned per middle-grid step, Q streams inner
+        name="gqa_flash_dq", interpret=_interpret())(
+            q, k, v, do, lse, delta)
+    # dk/dv pass: K block pinned per middle-grid step; the inner axis
+    # streams the Q blocks of the group's query heads, head after head
     if wg_q is None:
-        dkv_q_index = lambda b, j, i: (b, i, 0)  # noqa: E731
+        dkv_q_index = lambda b, j, i: (  # noqa: E731
+            b * group + i // nq_inner, i % nq_inner, 0)
     else:
         dkv_q_index = lambda b, j, i: (  # noqa: E731
-            b, jnp.clip(_qband_start(j, block_q, block_k) + i,
-                        0, n_q - 1), 0)
+            b * group + i // nq_inner,
+            jnp.clip(_qband_start(j, block_q, block_k) + i % nq_inner,
+                     0, n_q - 1), 0)
     kq_spec = pl.BlockSpec((1, block_q, d), dkv_q_index)
     kq_row = pl.BlockSpec((1, block_q, _STAT_LANES), dkv_q_index)
     kk_spec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          window=window, window_grid=wg_q,
-                          n_q_total=n_q),
-        grid=(bh, n_k, nq_inner),
+                          n_q_inner=nq_inner, window=window,
+                          window_grid=wg_q, n_q_total=n_q),
+        grid=(bh_kv, n_k, group * nq_inner),
         in_specs=[kq_spec, kk_spec, kk_spec, kq_spec, kq_row, kq_row],
         out_specs=[kk_spec, kk_spec],
-        out_shape=[_struct((bh, t, d), k.dtype, vma),
-                   _struct((bh, t, d), v.dtype, vma)],
+        out_shape=[_struct((bh_kv, t, d), k.dtype, vma),
+                   _struct((bh_kv, t, d), v.dtype, vma)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=_interpret())(q, k, v, do, lse, delta)
+        name="gqa_flash_dkv", interpret=_interpret())(
+            q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
@@ -481,18 +518,26 @@ def _flash_attention(q, k, v, causal, scale, block_q, block_k,
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, window=None):
-    """Flash attention, [B, T, H, D] — drop-in for
+    """Flash attention, ``q`` [B, T, H_q, D], ``k`` and ``v`` [B, T,
+    H_kv, D] with ``H_q`` a multiple of ``H_kv`` — drop-in for
     ``attention_reference`` (falls back to it, with a logged warning,
-    when T can't be tiled).  ``window`` (requires ``causal``):
+    when T can't be tiled).  With fewer key-value heads than query heads
+    (grouped-query attention) query head ``h`` reads key-value head ``h
+    // (H_q / H_kv)``; one head count is the group of 1 of the same
+    kernels.  ``window`` (requires ``causal``):
     sliding-window attention — position i sees keys in
     (i - window, i]; off-band blocks skip their MXU work entirely.
 
     ``block_q``/``block_k`` default to the measured winner for this
     (T, D, device, versions) when a tuning record exists (autotune
     sites ``flash_attention`` / ``window_attention``), else the
-    hand-picked :data:`DEFAULT_BLOCK_Q`/:data:`DEFAULT_BLOCK_K`;
+    hand-picked :func:`default_blocks` of the head size;
     explicit values always win.  Resolution happens at trace time
     (shapes are static), outside the custom-vjp boundary."""
+    if q.shape[2] % k.shape[2] or k.shape != v.shape:
+        raise ValueError("%d query heads cannot share %d key-value heads "
+                         "(k %s, v %s)" % (q.shape[2], k.shape[2],
+                                           k.shape, v.shape))
     if block_q is None or block_k is None:
         from ..autotune import dispatch as _autotune
         site = "window_attention" if window is not None \
@@ -503,8 +548,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         from ..autotune.space import site as _site
         cfg, _ = _autotune.resolve(
             site, _site(site).shape_class(ctx),
-            default={"block_q": DEFAULT_BLOCK_Q,
-                     "block_k": DEFAULT_BLOCK_K})
+            default=dict(zip(("block_q", "block_k"),
+                             default_blocks(q.shape[3], window))))
         block_q = block_q if block_q is not None else int(cfg["block_q"])
         block_k = block_k if block_k is not None else int(cfg["block_k"])
     return _flash_attention(q, k, v, causal, scale, block_q, block_k,
@@ -548,7 +593,9 @@ def _flash_bwd(causal, scale, block_q, block_k, window, res, g):
     dq, dk, dv = _flash_bwd_bh(
         _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(out), lse, _to_bh(g),
         scale, causal, bq, bk, window=window)
-    return (_from_bh(dq, b, h), _from_bh(dk, b, h), _from_bh(dv, b, h))
+    h_kv = k.shape[2]
+    return (_from_bh(dq, b, h), _from_bh(dk, b, h_kv),
+            _from_bh(dv, b, h_kv))
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)

@@ -258,7 +258,8 @@ class FusedTrainStep(Unit, IResultProvider):
         # FLOAT32_PARAMS, tensors the boundary cast leaves float32;
         # ``remat``, jax.checkpoint around its apply, and ``remat_saves``,
         # what that checkpoint keeps; ``apply_stats``, an apply that also
-        # returns counters for the accumulator
+        # returns counters for the accumulator; ``update_buffers``, the
+        # tensors it moves itself each train step, from those counters
         keep_f32 = [getattr(f, "FLOAT32_PARAMS", ()) for f in forwards]
         with_stats = [hasattr(f, "apply_stats") for f in forwards]
         appliers = [applier(f) for f in forwards]
@@ -409,9 +410,20 @@ class FusedTrainStep(Unit, IResultProvider):
             (loss, out), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, x, y, mask, seed)
             new_params, new_opt = [], []
+            counters = out[1]["units"] if loss_kind == "token" else {}
             for i, gd in enumerate(gds):
                 layer_p, layer_o = {}, {}
+                # buffers a forward unit moves itself, from the counters
+                # its own apply_stats returned this step
+                if scopes[i] in counters and hasattr(forwards[i],
+                                                     "update_buffers"):
+                    with jax.named_scope("update/" + scopes[i]):
+                        layer_p = dict(forwards[i].update_buffers(
+                            params[i], counters[scopes[i]]))
+                    layer_o = {name: opt[i][name] for name in layer_p}
                 for name, p in params[i].items():
+                    if name in layer_o:
+                        continue
                     with jax.named_scope("update/" + scopes[i]):
                         g = grads[i][name]
                         decay, l1l2, ortho = gd.decay_for(name)

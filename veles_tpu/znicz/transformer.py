@@ -1,5 +1,6 @@
 """Transformer blocks as Znicz forward units: a token embedding, a
-latent-attention block, a gated-MLP block, an expert block and a
+latent-attention block, a grouped-query attention block, a gated
+short-convolution block, a gated-MLP block, an expert block and a
 normalised head over a vocabulary slice.
 
 Each is a :class:`ForwardBase` with a ``MAPPING``, so a
@@ -7,9 +8,9 @@ Each is a :class:`ForwardBase` with a ``MAPPING``, so a
 fused / epoch-scan trainers chain their pure ``apply(params, x)`` like any
 other layer's.  Pre-norm and residual live INSIDE a block (``x + f(norm(
 x))``), so the chain stays a chain.  The blocks are driven by the keys a
-public ``config.json`` of the DeepSeek-V2/V3 family uses
-(``qk_nope_head_dim``, ``kv_lora_rank``, ``n_routed_experts``, ...) and by
-the share of a deployment this chip holds (``experts_held``,
+public ``config.json`` uses (``qk_nope_head_dim``, ``kv_lora_rank``,
+``n_routed_experts``, ``num_key_value_heads``, ``conv_L_cache``, ...) and
+by the share of a deployment this chip holds (``experts_held``,
 ``experts_offset``), never by a model's name.
 
 Arithmetic under ``--compute-dtype bfloat16``: matrix operands bfloat16,
@@ -49,19 +50,41 @@ def rms_norm(x, weight, eps):
     return (xf * inv * weight.astype(jnp.float32)).astype(x.dtype)
 
 
+def _rope_angles(t, d, theta):
+    """(cos, sin) [T, D/2] float32 of positions 0..T-1: pair ``i`` of a
+    ``D``-wide head turns by ``position * theta ** (-2i / D)``."""
+    import jax.numpy as jnp
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.cos(angle), jnp.sin(angle)
+
+
 def rope_interleaved(x, theta):
     """Rotary embedding over the last axis of ``x`` [..., T, D] with the
     pairs ``(2i, 2i+1)`` (``rope_interleave``), positions 0..T-1, angles
     and rotation in float32, result in ``x``'s dtype."""
     import jax.numpy as jnp
     t, d = x.shape[-2], x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(angle), jnp.sin(angle)          # [T, D/2]
+    cos, sin = _rope_angles(t, d, theta)
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rope_half_split(x, theta, seq_axis=-2):
+    """The same rotation with the pairs ``(i, i + D/2)`` (the
+    ``rotate_half`` convention of the Llama lineage): the first half of a
+    head holds the pairs' first members, the second half their second.
+    ``seq_axis`` is the axis of the positions (-3 for [B, T, H, D])."""
+    import jax.numpy as jnp
+    t, d = x.shape[seq_axis], x.shape[-1]
+    cos, sin = (a.reshape((t,) + (1,) * (-seq_axis - 2) + (d // 2,))
+                for a in _rope_angles(t, d, theta))
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :d // 2], xf[..., d // 2:]
+    out = jnp.concatenate([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.astype(x.dtype)
 
 
 def gated_mlp(h, gate, up, down):
@@ -271,6 +294,133 @@ class LatentAttentionBlock(BlockBase):
         return (x.astype(f32) + y).astype(x.dtype)
 
 
+class GQAAttentionBlock(BlockBase):
+    """``x + Attention(RMSNorm(x))`` with grouped-query attention:
+    ``num_attention_heads`` query heads read ``num_key_value_heads``
+    key-value heads (query head ``h`` reads ``h // group``), queries and
+    keys RMS-normalised over each head's ``head_dim`` with one learned
+    weight each (float32 statistics), rotary embedding over the whole
+    head in the half-split pairing, causal softmax with scale ``1 /
+    sqrt(head_dim)``.  No bias anywhere.  The core runs the plain flash
+    kernels (``flash_attention.py``: K and V stay at their own head
+    count) on a TPU and explicit scores elsewhere."""
+
+    MAPPING = "gqa_attention_block"
+    FLOAT32_PARAMS = ("norm", "q_norm", "k_norm")
+    #: ``flash_attention.SAVED_NAMES``: the kernel's output and row
+    #: statistics, 69 MB a block at 2 x 8,192 tokens and 32 heads of 64,
+    #: so that the backward pass does not rerun the forward kernel
+    remat_saves = ("flash_out", "flash_lse")
+
+    def __init__(self, workflow, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.hidden_size = int(kwargs["hidden_size"])
+        self.heads = int(kwargs["num_attention_heads"])
+        self.kv_heads = int(kwargs.get("num_key_value_heads", self.heads))
+        self.head_dim = int(kwargs.get("head_dim")
+                            or self.hidden_size // self.heads)
+        self.rope_theta = float(kwargs.get("rope_theta", 10000.0))
+        self.use_pallas = kwargs.get("use_pallas")
+        if self.heads % self.kv_heads:
+            raise ValueError("%d query heads cannot share %d key-value "
+                             "heads" % (self.heads, self.kv_heads))
+
+    def tensor_shapes(self):
+        d, k = self.hidden_size, self.head_dim
+        return {
+            "norm": ((d,), "ones"),
+            "wq": ((d, self.heads * k), "matrix"),
+            "wk": ((d, self.kv_heads * k), "matrix"),
+            "wv": ((d, self.kv_heads * k), "matrix"),
+            "q_norm": ((k,), "ones"),
+            "k_norm": ((k,), "ones"),
+            "wo": ((self.heads * k, d), "matrix"),
+        }
+
+    def _core(self, q, k, v):
+        from ..parallel.ring import attention_reference
+        from .flash_attention import flash_attention
+        from .nn_units import resolve_use_pallas
+        if resolve_use_pallas(self.use_pallas, self.device, tpu_auto=True):
+            return flash_attention(q, k, v, causal=True)
+        return attention_reference(q, k, v, causal=True)
+
+    def apply(self, params, x):
+        import jax
+        import jax.numpy as jnp
+        f32 = jnp.float32
+        d, k = self.hidden_size, self.head_dim
+        hn = self._norm(x, params["norm"])
+
+        def heads(name, n):
+            return jnp.einsum("bsd,dhk->bshk", hn, params[name].reshape(
+                d, n, k), preferred_element_type=f32).astype(x.dtype)
+        with jax.named_scope("attn/qkv"):
+            q = rope_half_split(self._norm(heads("wq", self.heads),
+                                           params["q_norm"]),
+                                self.rope_theta, seq_axis=-3)
+            key = rope_half_split(self._norm(heads("wk", self.kv_heads),
+                                             params["k_norm"]),
+                                  self.rope_theta, seq_axis=-3)
+            v = heads("wv", self.kv_heads)
+        with jax.named_scope("attn/core"):
+            out = self._core(q, key, v)                # [B, S, H, k]
+        with jax.named_scope("attn/out"):
+            y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].reshape(
+                self.heads, k, d), preferred_element_type=f32)
+        return (x.astype(f32) + y).astype(x.dtype)
+
+
+class ShortConvBlock(BlockBase):
+    """``x + ((C * conv(B * x~)) W_out)`` with ``[B, C, x~] = split(
+    RMSNorm(x) W_in)``: a gated short convolution (the LFM2 operator).
+    ``conv`` is causal and depthwise over ``conv_L_cache`` taps, ``v[t] =
+    sum_j w[j] * u[t - (L-1) + j]`` with ``u`` zero before the sequence's
+    start, no bias, no activation function: the two gates are the only
+    non-linearity.  The taps are ``L`` shifted multiply-adds summed in
+    float32 (their gradient is shifted sums too, where a 2,048-group
+    ``conv_general_dilated`` would make XLA derive a grouped
+    convolution's).  ``conv`` is stored [L, d]: tap ``j`` of every
+    channel is one row."""
+
+    MAPPING = "short_conv_block"
+
+    def __init__(self, workflow, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.hidden_size = int(kwargs["hidden_size"])
+        self.taps = int(kwargs.get("conv_L_cache", 3))
+
+    def tensor_shapes(self):
+        d = self.hidden_size
+        return {"norm": ((d,), "ones"),
+                "in_proj": ((d, 3 * d), "matrix"),
+                "conv": ((self.taps, d), "matrix"),
+                "out_proj": ((d, d), "matrix")}
+
+    def apply(self, params, x):
+        import jax
+        import jax.numpy as jnp
+        f32 = jnp.float32
+        t, taps = x.shape[1], self.taps
+        hn = self._norm(x, params["norm"])
+        with jax.named_scope("conv/in_proj"):
+            bcx = jnp.dot(hn, params["in_proj"],
+                          preferred_element_type=f32).astype(x.dtype)
+            gate_b, gate_c, xt = jnp.split(bcx, 3, axis=-1)
+        with jax.named_scope("conv/mix"):
+            u = (gate_b.astype(f32) * xt.astype(f32)).astype(x.dtype)
+            # u[t - s] with zeros before the start: s zeros in front,
+            # the first T positions kept
+            padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+            w = params["conv"].astype(x.dtype).astype(f32)
+            v = sum(w[j] * padded[:, j:j + t].astype(f32)
+                    for j in range(taps))
+            g = (gate_c.astype(f32) * v).astype(x.dtype)
+        with jax.named_scope("conv/out_proj"):
+            y = jnp.dot(g, params["out_proj"], preferred_element_type=f32)
+        return (x.astype(f32) + y).astype(x.dtype)
+
+
 class GatedMLPBlock(BlockBase):
     """``x + (silu(h Wg) * h Wu) Wd`` with ``h = RMSNorm(x)``."""
 
@@ -298,12 +448,28 @@ class ExpertBlock(BlockBase):
     float32 sigmoid router over ALL ``n_routed_experts``, the
     ``num_experts_per_tok`` largest ``s + b`` chosen (``b``: the
     ``noaux_tc`` correction bias, used for the choice only), weights
-    ``routed_scaling_factor * s / sum s`` over the chosen.  This chip
+    ``routed_scaling_factor * s / (sum s + norm_topk_eps)`` over the
+    chosen.  This chip
     holds ``experts_held`` experts from ``experts_offset``; tokens routed
     to them are sorted by expert and go through a grouped matrix product
     whose group sizes are the data's (``gemm.grouped_matmul``); what the
     absent experts would add is left out.  No capacity: the row buffer
-    holds every token's every choice, so no token is ever dropped."""
+    holds every token's every choice, so no token is ever dropped.
+
+    ``bias_update_rate`` > 0 turns on the balancing update the bias
+    exists for (DeepSeek-V3, section 2.1.2): after every train step
+    ``b_e += rate * sign(mean load - load_e)``, the load being the
+    step's tokens that chose expert ``e`` among ALL routed experts
+    (counter ``router_load``).  At 0, the default, ``b`` is a buffer
+    nothing touches.
+
+    ``train_router`` False leaves the routing weights out of the
+    backward pass, so the router matrix gets no gradient: what a share
+    of an expert-parallel layer (``experts_held`` < ``n_routed_experts``)
+    needs, because a router's gradient is made of the outputs of ALL the
+    experts a token chose and a share has its own alone: taught by those,
+    it learns that the absent experts add nothing and moves every token
+    onto the held ones."""
 
     MAPPING = "expert_block"
     FLOAT32_PARAMS = ("norm", "router", "router_bias")
@@ -317,6 +483,11 @@ class ExpertBlock(BlockBase):
         self.n_shared = int(kwargs.get("n_shared_experts", 0))
         self.scaling = float(kwargs.get("routed_scaling_factor", 1.0))
         self.norm_topk = bool(kwargs.get("norm_topk_prob", True))
+        #: added to the sum the chosen scores are normalised by: 1e-20
+        #: in the DeepSeek-V3 family's code, 1e-6 in LFM2's
+        self.norm_topk_eps = float(kwargs.get("norm_topk_eps", 1e-20))
+        self.bias_update_rate = float(kwargs.get("bias_update_rate", 0.0))
+        self.train_router = bool(kwargs.get("train_router", True))
         self.held = int(kwargs.get("experts_held", self.n_experts))
         self.offset = int(kwargs.get("experts_offset", 0))
         if not 0 <= self.offset <= self.n_experts - self.held:
@@ -341,8 +512,11 @@ class ExpertBlock(BlockBase):
 
     def stats_shapes(self):
         """The int32 counters ``apply_stats`` returns, by name."""
-        return {"expert_tokens": (self.held,), "moe_rows": (),
-                "moe_routed": ()}
+        shapes = {"expert_tokens": (self.held,), "moe_rows": (),
+                  "moe_routed": ()}
+        if self.bias_update_rate:
+            shapes["router_load"] = (self.n_experts,)
+        return shapes
 
     def route(self, params, h):
         """(expert ids [T, k], weights [T, k] float32) of tokens ``h``
@@ -357,7 +531,9 @@ class ExpertBlock(BlockBase):
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if self.norm_topk:
             weights = weights / (weights.sum(axis=-1, keepdims=True)
-                                 + 1e-20)
+                                 + self.norm_topk_eps)
+        if not self.train_router:
+            weights = jax.lax.stop_gradient(weights)
         return chosen, weights * self.scaling
 
     def apply_stats(self, params, x):
@@ -409,7 +585,22 @@ class ExpertBlock(BlockBase):
         stats = {"expert_tokens": sizes,
                  "moe_rows": sizes.sum(),
                  "moe_routed": here.sum(dtype=jnp.int32)}
+        if self.bias_update_rate:
+            stats["router_load"] = jnp.sum(
+                chosen.reshape(-1, 1) == jnp.arange(self.n_experts)[None, :],
+                axis=0, dtype=jnp.int32)
         return y, stats
+
+    def update_buffers(self, params, stats):
+        """{buffer: its value after a train step whose counters were
+        ``stats``}: the trainer stores these and leaves the named
+        tensors out of the solver's update."""
+        import jax.numpy as jnp
+        if not self.bias_update_rate:
+            return {}
+        load = stats["router_load"].astype(jnp.float32)
+        return {"router_bias": params["router_bias"]
+                + self.bias_update_rate * jnp.sign(load.mean() - load)}
 
     def apply(self, params, x):
         return self.apply_stats(params, x)[0]
@@ -520,6 +711,14 @@ class GDTokenEmbedding(GDBlock):
 
 class GDLatentAttentionBlock(GDBlock):
     MAPPING = "latent_attention_block"
+
+
+class GDGQAAttentionBlock(GDBlock):
+    MAPPING = "gqa_attention_block"
+
+
+class GDShortConvBlock(GDBlock):
+    MAPPING = "short_conv_block"
 
 
 class GDGatedMLPBlock(GDBlock):
