@@ -61,7 +61,7 @@ def test_at_rate_zero_the_bias_is_a_buffer():
     unit, params = expert_block()
     assert unit.bias_update_rate == 0.0
     assert sorted(unit.stats_shapes()) == ["expert_tokens", "moe_routed",
-                                           "moe_rows"]
+                                           "moe_rows", "moe_spilled"]
     _, stats = unit.apply_stats(params, tokens())
     assert "router_load" not in stats
     assert unit.update_buffers(params, stats) == {}

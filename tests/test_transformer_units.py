@@ -2,8 +2,8 @@
 CPU: latent flash attention (query-key and value heads of different
 sizes, a rope key shared by the heads) in interpret mode against
 ``attention_reference``; interleaved RoPE, RMSNorm and AdamW against
-hand arithmetic; the grouped product and the gather pair of the expert
-layer; the head's blocked token loss."""
+hand arithmetic; the grouped product and the dispatch / combine pair of
+the expert layer; the head's blocked token loss."""
 
 import jax
 import jax.numpy as jnp
@@ -165,17 +165,59 @@ def test_grouped_matmul_against_a_loop(sizes):
         assert numpy.allclose(g, w, atol=1e-4)
 
 
-def test_the_gather_pair_has_a_gathers_gradient():
+def a_sort(tokens=6, k=3, held=7):
+    """(src, pos, here) of a sort of ``tokens * k`` choices of which the
+    ``held`` first sorted rows are on a held expert."""
+    src = jax.random.permutation(jax.random.key(4), tokens * k)
+    pos = jnp.argsort(src).reshape(tokens, k)
+    return src, pos, pos < held
+
+
+@pytest.mark.parametrize("rows", [18, 12])
+def test_dispatch_is_a_take_and_its_gradient_a_segment_sum(rows):
+    src, pos, here = a_sort()
     x = jax.random.normal(jax.random.key(3), (6, 5))
-    order = jax.random.permutation(jax.random.key(4), 18)
-    weight = jax.random.normal(jax.random.key(5), (18, 5))
-    for index in (order // 3, jnp.argsort(order)[:18] % 18):
-        source = x if index.max() < 6 else jnp.tile(x, (3, 1))
-        got = jax.grad(lambda a: (transformer._permute(a, index)
-                                  * weight).sum())(source)
-        want = jax.grad(lambda a: (jnp.take(a, index, axis=0)
-                                   * weight).sum())(source)
-        assert numpy.allclose(got, want, atol=1e-5)
+    weight = jax.random.normal(jax.random.key(5), (rows, 5))
+    # the grouped product hands back no cotangent for rows past the last
+    # group: poison them
+    poisoned = jnp.where((jnp.arange(rows) < 7)[:, None], weight, jnp.nan)
+    assert numpy.array_equal(
+        transformer._dispatch(x, src[:rows], pos, here),
+        jnp.take(x, src[:rows] // 3, axis=0))
+    got = jax.grad(lambda a: (transformer._dispatch(
+        a, src[:rows], pos, here) * poisoned).sum())(x)
+    want = jax.ops.segment_sum(weight[:7], src[:7] // 3, num_segments=6)
+    assert numpy.allclose(got, want, atol=1e-5)
+    # and against a plain take's gradient, every row held
+    got = jax.grad(lambda a: (transformer._dispatch(
+        a, src, pos, pos >= 0) * jnp.resize(weight, (18, 5))).sum())(x)
+    want = jax.grad(lambda a: (jnp.take(a, src // 3, axis=0)
+                               * jnp.resize(weight, (18, 5))).sum())(x)
+    assert numpy.allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("rows", [18, 12])
+def test_combine_is_a_weighted_segment_sum_and_its_gradient_a_take(rows):
+    src, pos, here = a_sort()
+    buf = jax.random.normal(jax.random.key(3), (rows, 5))
+    buf = jnp.where((jnp.arange(rows) < 7)[:, None], buf, jnp.nan)
+    w = jax.random.uniform(jax.random.key(6), (6, 3))
+    weight = jax.random.normal(jax.random.key(5), (6, 5))
+
+    def plain(buf, w):
+        scale = jnp.take(w.reshape(-1), src[:7])
+        return jax.ops.segment_sum(buf[:7] * scale[:, None], src[:7] // 3,
+                                   num_segments=6)
+    got = transformer._combine(buf, w, src[:rows], pos, here)
+    assert got.dtype == jnp.float32
+    assert numpy.allclose(got, plain(buf, w), atol=1e-6)
+    got = jax.grad(lambda *a: (transformer._combine(
+        *a, src[:rows], pos, here) * weight).sum(), argnums=(0, 1))(buf, w)
+    want = jax.grad(lambda *a: (plain(*a) * weight).sum(),
+                    argnums=(0, 1))(jnp.nan_to_num(buf), w)
+    assert numpy.allclose(got[0][:7], want[0][:7], atol=1e-5)
+    assert numpy.allclose(got[1], want[1], atol=1e-5)
+    assert numpy.array_equal(got[1] != 0, here)
 
 
 def test_blocked_token_loss_is_the_plain_one():

@@ -32,6 +32,8 @@ accumulator sums; ``token_loss`` (the head) folds the vocabulary
 projection and the loss over blocks of tokens.
 """
 
+import collections
+import functools
 import math
 
 import numpy
@@ -443,6 +445,20 @@ class GatedMLPBlock(BlockBase):
         return (x.astype(jnp.float32) + y).astype(x.dtype)
 
 
+#: the expert block's row buffer holds this many times a chip's share of
+#: evenly spread choices (the records: a taught router reads 1.0-1.14 x
+#: its share over a window, a balanced one 0.96-1.07 x; PERF.md section
+#: 6, PR 34).  Too small costs time, never rows.
+BUFFER_SHARES = 2
+#: the grouped product's largest row tile (``gemm.grouped_matmul``)
+ROW_TILE = 512
+#: a step's routing, made once in the forward pass: ``order`` [T * k,
+#: padded to whole blocks], the choices (token * k + j) sorted by held
+#: expert, absent experts' last; ``pos`` [T, k], its inverse; ``here``
+#: [T, k], the choices on a held expert; ``sizes`` [held], rows an expert
+_Plan = collections.namedtuple("_Plan", "order pos here sizes")
+
+
 class ExpertBlock(BlockBase):
     """``x + sum_e w_e E_e(h) + Shared(h)``, ``h = RMSNorm(x)``: a
     float32 sigmoid router over ALL ``n_routed_experts``, the
@@ -453,8 +469,14 @@ class ExpertBlock(BlockBase):
     holds ``experts_held`` experts from ``experts_offset``; tokens routed
     to them are sorted by expert and go through a grouped matrix product
     whose group sizes are the data's (``gemm.grouped_matmul``); what the
-    absent experts would add is left out.  No capacity: the row buffer
-    holds every token's every choice, so no token is ever dropped.
+    absent experts would add is left out.
+
+    No capacity, and no token is ever dropped.  The row buffer holds
+    ``buffer_rows`` rows: ``BUFFER_SHARES`` times this chip's share of
+    every token's every choice, and all of them where the chip holds
+    every expert.  A step whose routed rows exceed the buffer runs the
+    same routine over as many such buffers as hold rows, up to the full
+    bound (counter ``moe_spilled``): slower, the same result.
 
     ``bias_update_rate`` > 0 turns on the balancing update the bias
     exists for (DeepSeek-V3, section 2.1.2): after every train step
@@ -513,7 +535,7 @@ class ExpertBlock(BlockBase):
     def stats_shapes(self):
         """The int32 counters ``apply_stats`` returns, by name."""
         shapes = {"expert_tokens": (self.held,), "moe_rows": (),
-                  "moe_routed": ()}
+                  "moe_routed": (), "moe_spilled": ()}
         if self.bias_update_rate:
             shapes["router_load"] = (self.n_experts,)
         return shapes
@@ -536,44 +558,120 @@ class ExpertBlock(BlockBase):
             weights = jax.lax.stop_gradient(weights)
         return chosen, weights * self.scaling
 
-    def apply_stats(self, params, x):
+    def buffer_rows(self, tokens):
+        """The row buffer's length for ``tokens`` tokens: ``BUFFER_SHARES``
+        times this chip's share of evenly spread choices, in whole row
+        tiles of the grouped product, and never more than every token's
+        every choice (what a block that holds all its experts gets)."""
+        full = tokens * self.top_k
+        share = BUFFER_SHARES * full * self.held
+        tiles = -(-share // (self.n_experts * ROW_TILE))
+        return min(full, tiles * ROW_TILE)
+
+    def _experts(self, rows, plan, block, hn, weights, gate_up, down):
+        """What the sorted rows ``[block * rows, (block + 1) * rows)``
+        add to ``sum_e w_e E_e(hn)`` [T, d] float32: dispatch, the two
+        grouped products over the part of each group inside the block,
+        combine.  Rows past the last group are never written by the
+        grouped product, in either pass; nothing reads them
+        (``_combine`` and ``_dispatch``'s backward take the held
+        choices alone)."""
         import jax
         import jax.numpy as jnp
         from . import gemm
+        lo = block * rows
+        src = jax.lax.dynamic_slice_in_dim(plan.order, lo, rows)
+        ends = jnp.cumsum(plan.sizes)
+        sizes = jnp.clip(ends, lo, lo + rows) \
+            - jnp.clip(ends - plan.sizes, lo, lo + rows)
+        pos = plan.pos - lo
+        here = plan.here & (pos >= 0) & (pos < rows)
+        with jax.named_scope("moe/dispatch"):
+            buf = _dispatch(hn, src, pos, here)
+        with jax.named_scope("moe/experts"):
+            gu = gemm.grouped_matmul(buf, gate_up, sizes)
+            a = (jax.nn.silu(gu[:, :self.width].astype(jnp.float32))
+                 * gu[:, self.width:].astype(jnp.float32)).astype(hn.dtype)
+            out = gemm.grouped_matmul(a, down, sizes)
+        with jax.named_scope("moe/combine"):
+            return _combine(out, weights, src, pos, here)
+
+    def _routed(self, plan, hn, weights, gate_up, down):
+        """``sum_e w_e E_e(hn)`` [T, d] float32 over the held experts:
+        ``_experts`` over as many blocks of ``buffer_rows`` sorted rows
+        as hold rows, one in a step that does not spill.  A loop whose
+        trip count is the data's, so the program holds ONE instance of
+        the routine (a ``lax.cond`` between a compact and a full-bound
+        instance lowers every kernel twice: 15 % of a cell's set-up,
+        PERF.md section 6, PR 34); such a loop has no derivative, so
+        both passes are written out under one ``custom_vjp``, and the
+        backward pass recomputes each block as the unit's checkpoint
+        would.  In a step that spills, sums over a token's choices and
+        over an expert's rows are sums of per-block sums, each rounded
+        to its tensor's dtype."""
+        import jax
+        import jax.numpy as jnp
+        rows = self.buffer_rows(hn.shape[0])
+
+        def over_blocks(fn, zeros, plan):
+            if rows == plan.pos.size:
+                return fn(0)
+            return jax.lax.fori_loop(
+                0, -(-plan.sizes.sum() // rows),
+                lambda i, acc: jax.tree.map(jnp.add, acc, fn(i)), zeros)
+
+        @jax.custom_vjp
+        def routed(plan, *tensors):
+            return over_blocks(
+                lambda i: self._experts(rows, plan, i, *tensors),
+                jnp.zeros(tensors[0].shape, jnp.float32), plan)
+
+        def fwd(*args):
+            return routed(*args), args
+
+        def bwd(args, g):
+            plan, *tensors = args
+
+            def experts(block, hn, weights, gate_up, down):
+                if not self.train_router:
+                    # or the weights' gradient is computed in every
+                    # block for ``route`` to throw away
+                    weights = jax.lax.stop_gradient(weights)
+                return self._experts(rows, plan, block, hn, weights,
+                                     gate_up, down)
+            grads = over_blocks(
+                lambda i: jax.vjp(functools.partial(experts, i),
+                                  *tensors)[1](g),
+                tuple(jnp.zeros_like(t) for t in tensors), plan)
+            return (None, *grads)
+
+        routed.defvjp(fwd, bwd)
+        return routed(plan, hn, weights, gate_up, down)
+
+    def apply_stats(self, params, x):
+        import jax
+        import jax.numpy as jnp
         b, s, d = x.shape
         k, held = self.top_k, self.held
+        rows = self.buffer_rows(b * s)
         hn = self._norm(x, params["norm"]).reshape(b * s, d)
         with jax.named_scope("moe/router"):
             chosen, weights = self.route(params, hn)
-        with jax.named_scope("moe/dispatch"):
-            local = chosen.reshape(-1) - self.offset           # [T * k]
+        with jax.named_scope("moe/plan"):
+            local = chosen - self.offset                       # [T, k]
             here = (local >= 0) & (local < held)
             # rows of absent experts sort behind every group and are
             # never multiplied
-            order = jnp.argsort(jnp.where(here, local, held), stable=True)
+            order = jnp.argsort(jnp.where(here, local, held).reshape(-1),
+                                stable=True)
             sizes = jnp.sum(
-                local[:, None] == jnp.arange(held)[None, :], axis=0,
+                local.reshape(-1, 1) == jnp.arange(held)[None, :], axis=0,
                 dtype=jnp.int32)
-            # rows past the last group hold nothing: zeros going in and,
-            # through where's transpose, zeros coming back (the grouped
-            # product leaves them unwritten in both directions)
-            filled = jnp.arange(b * s * k) < sizes.sum()
-            rows = jnp.where(filled[:, None], _permute(hn, order // k), 0)
-        with jax.named_scope("moe/experts"):
-            gate_up = gemm.grouped_matmul(rows, params["experts_gate_up"],
-                                          sizes)
-            a = (jax.nn.silu(gate_up[:, :self.width].astype(jnp.float32))
-                 * gate_up[:, self.width:].astype(jnp.float32)
-                 ).astype(x.dtype)
-            out = gemm.grouped_matmul(a, params["experts_down"], sizes)
-        with jax.named_scope("moe/combine"):
-            back = _permute(out, jnp.argsort(order)).reshape(b * s, k, d)
-            # where BEFORE the product, not times zero: rows past the
-            # last group are not written by the grouped product, and a
-            # product's transpose would multiply by them
-            back = jnp.where(here.reshape(b * s, k, 1),
-                             back.astype(jnp.float32), 0.0)
-            y = jnp.sum(back * weights[..., None], axis=1)
+            # whole blocks to slice; nothing points at the padding
+            plan = _Plan(jnp.pad(order, (0, -order.size % rows)),
+                         jnp.argsort(order).reshape(b * s, k), here, sizes)
+        y = self._routed(plan, hn, weights, params["experts_gate_up"],
+                         params["experts_down"])
         if self.n_shared:
             with jax.named_scope("moe/shared"):
                 y = y + gated_mlp(hn, params["shared_gate"],
@@ -584,7 +682,8 @@ class ExpertBlock(BlockBase):
         # the grouped product was told to compute: equal iff dropless
         stats = {"expert_tokens": sizes,
                  "moe_rows": sizes.sum(),
-                 "moe_routed": here.sum(dtype=jnp.int32)}
+                 "moe_routed": here.sum(dtype=jnp.int32),
+                 "moe_spilled": (sizes.sum() > rows).astype(jnp.int32)}
         if self.bias_update_rate:
             stats["router_load"] = jnp.sum(
                 chosen.reshape(-1, 1) == jnp.arange(self.n_experts)[None, :],
@@ -606,34 +705,81 @@ class ExpertBlock(BlockBase):
         return self.apply_stats(params, x)[0]
 
 
-def _permute(x, index):
-    """``x[index]`` for a row gather whose backward is a gather too.
-    ``index`` [M] reads each row of ``x`` [N, d] a FIXED number of times
-    ``M / N`` (a token's k routes) or is a permutation; the cotangent of
-    row n is the sum of the cotangents of the rows that read it, which
-    ``argsort(index)`` lines up — no scatter-add, which a TPU
-    serialises."""
+def _spread(tok, src, k):
+    """Tokens to sorted rows: ``rows[r] = tok[src[r] // k]`` for the
+    ``len(src)`` first rows of the sort, ``src[r]`` being the choice
+    (token * k + j) that sorted row ``r`` serves."""
+    import jax.numpy as jnp
+    return jnp.take(tok, src // k, axis=0, mode="clip")
+
+
+def _gather_sum(buf, pos, here, w=None):
+    """Sorted rows to tokens: ``y[t] = sum_j w[t, j] * buf[pos[t, j]]``
+    in float32 over the choices that are ``here``; the others read
+    nothing.  A gather of T * k rows and a sum over k, no scatter-add,
+    which a TPU serialises.  ``where``, not times zero: rows past the
+    last group are not written by the grouped product.  The rows are
+    read choice by choice and summed slab by slab: a token's k rows
+    side by side ([T, k, d]) would be tiles of 8 sublanes holding k,
+    which the v5e lays out again at the cost of a second gather."""
+    import jax.numpy as jnp
+    t, k = pos.shape
+    rows = jnp.take(buf, pos.T.reshape(-1), axis=0, mode="clip")
+    y = 0.0
+    for j in range(k):
+        slab = jnp.where(here[:, j, None],
+                         rows[j * t:(j + 1) * t].astype(jnp.float32), 0.0)
+        y = y + (slab if w is None else slab * w[:, j, None])
+    return y
+
+
+def _dispatch(tok, src, pos, here):
+    """``_spread(tok, src)`` whose backward pass is ``_gather_sum`` over
+    the sort the forward pass made (``pos``, the inverse of ``src``):
+    the cotangent of a token is the float32 sum of its held rows'."""
+    import jax
+
+    @jax.custom_vjp
+    def dispatch(tok, src, pos, here):
+        return _spread(tok, src, pos.shape[1])
+
+    def fwd(tok, src, pos, here):
+        return dispatch(tok, src, pos, here), (pos, here)
+
+    def bwd(res, g):
+        return _gather_sum(g, *res).astype(g.dtype), None, None, None
+
+    dispatch.defvjp(fwd, bwd)
+    return dispatch(tok, src, pos, here)
+
+
+def _combine(buf, w, src, pos, here):
+    """``_gather_sum(buf, pos, here, w)`` whose backward pass is
+    ``_spread``: a row's cotangent is its weight times its token's, and
+    a weight's the product of its row with its token's cotangent, taken
+    over the sorted rows and unsorted as scalars; no [T, k, d]."""
     import jax
     import jax.numpy as jnp
 
     @jax.custom_vjp
-    def take(x, index):
-        return jnp.take(x, index, axis=0)
+    def combine(buf, w, src, pos, here):
+        return _gather_sum(buf, pos, here, w)
 
-    def fwd(x, index):
-        return take(x, index), (index, x.shape[0])
+    def fwd(buf, w, src, pos, here):
+        return combine(buf, w, src, pos, here), (buf, w, src, pos, here)
 
     def bwd(res, g):
-        index, n = res
-        reads = index.shape[0] // n
-        readers = jnp.argsort(index, stable=True)      # [N * reads]
-        grad = jnp.take(g, readers, axis=0).reshape(
-            (n, reads) + g.shape[1:])
-        return (grad.astype(jnp.float32).sum(axis=1).astype(g.dtype),
+        buf, w, src, pos, here = res
+        g_rows = _spread(g, src, pos.shape[1]).astype(jnp.float32)
+        g_buf = g_rows * jnp.take(w.reshape(-1), src,
+                                  mode="clip")[:, None]
+        dots = jnp.sum(buf.astype(jnp.float32) * g_rows, axis=-1)
+        g_w = jnp.where(here, jnp.take(dots, pos, mode="clip"), 0.0)
+        return (g_buf.astype(buf.dtype), g_w.astype(w.dtype), None, None,
                 None)
 
-    take.defvjp(fwd, bwd)
-    return take(x, index)
+    combine.defvjp(fwd, bwd)
+    return combine(buf, w, src, pos, here)
 
 
 class NormHead(BlockBase):
