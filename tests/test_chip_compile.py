@@ -212,6 +212,33 @@ def test_grouped_query_flash_compiles_for_v5e(v5e, kv_heads, grad):
     assert "bf16[%d,8192,64]" % (2 * kv_heads) in text
 
 
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_wide_grouped_heads_compile_for_v5e(v5e, window):
+    """The same kernels as a depth of window layers beside full ones
+    calls them: 32 query heads of 128 on 4 key-value heads (groups of
+    8), two sequences of 8,192, bfloat16, forward and backward, inside a
+    window of 1,024 on the banded grids and without one, at the block
+    sizes the kernel layer picks; a window's calls under their own
+    names."""
+
+    def struct(heads):
+        return jax.ShapeDtypeStruct((2, 8192, heads, 128), jnp.bfloat16,
+                                    sharding=v5e)
+
+    def attend(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window)
+    text = _compile(jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), struct(32), struct(4), struct(4))
+    family = "gqa_window_flash_" if window else "gqa_flash_"
+    other = "gqa_flash_" if window else "gqa_window_flash_"
+    for kernel in ("fwd", "dq", "dkv"):
+        assert family + kernel in text, kernel
+    assert other not in text
+    # dK and dV leave the kernel per KEY-VALUE head
+    assert "bf16[8,8192,128]" in text
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
 @pytest.mark.parametrize("shared", [True, False], ids=["shared", "copied"])
 def test_latent_flash_attention_compiles_for_v5e(v5e, shared, grad):

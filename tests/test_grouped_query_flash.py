@@ -56,6 +56,39 @@ def test_forward_and_gradients_against_repeated_heads(h_kv, causal, window):
                                       err_msg="d" + name)
 
 
+# 16 query heads in groups of 1, of 8 and of 16 = H_q, inside a window
+# that is no multiple of either block (and one narrower than a block):
+# the K band of the forward and dq grids and the Q band that the dk/dv
+# grid walks head after head within a group
+@pytest.mark.parametrize("h_kv", [16, 2, 1], ids=["group1", "group8",
+                                                  "groupHq"])
+@pytest.mark.parametrize("window,bq,bk", [(40, 32, 32), (24, 64, 32),
+                                          (72, 32, 64)])
+def test_a_window_over_grouped_heads(h_kv, window, bq, bk):
+    rng = numpy.random.RandomState(4)
+
+    def draw(h):
+        return jnp.asarray(0.5 * rng.standard_normal((1, 256, h, D)),
+                           jnp.float32)
+    q, k, v = draw(16), draw(h_kv), draw(h_kv)
+    # the grids are banded at these sizes, in both directions
+    assert fa._kband_size(bq, bk, window) < 256 // bk
+    assert fa._qband_size(bq, bk, window) < 256 // bq
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, True, None, bq, bk, window)
+    want = repeated(q, k, v, True, window)
+    numpy.testing.assert_allclose(flash(q, k, v), want, rtol=2e-5,
+                                  atol=2e-5)
+    got = jax.grad(weighed(flash), argnums=(0, 1, 2))(q, k, v)
+    ref = jax.grad(weighed(lambda q, k, v: repeated(
+        q, k, v, True, window)), argnums=(0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, ref, "qkv"):
+        assert g.shape == w.shape, name    # dk, dv per KEY-VALUE head
+        numpy.testing.assert_allclose(g, w, rtol=5e-4, atol=5e-4,
+                                      err_msg="d" + name)
+
+
 @pytest.mark.parametrize("h_kv", [2, 1])
 def test_the_oracle_reads_grouped_heads_itself(h_kv):
     """``attention_reference`` given K and V at their own head count is
@@ -118,15 +151,38 @@ def test_the_three_calls_carry_names_a_trace_can_find():
     assert names == ["gqa_flash_dkv", "gqa_flash_dq", "gqa_flash_fwd"]
     # neither family's reader matches the other's events
     assert not any("mla_flash" in n for n in names)
+    # the same three kernels called with a window carry names of their
+    # own, which hold none of the names above and which none of them hold
+    jaxpr = jax.make_jaxpr(jax.grad(weighed(lambda q, k, v:
+                           fa.flash_attention(q, k, v, True, None, 64, 64,
+                                              40)),
+                           argnums=(0, 1, 2)))(q, k, v)
+    windowed = sorted(eqn.params["name"]
+                      for eqn in fused.jaxpr_equations(jaxpr.jaxpr)
+                      if eqn.primitive.name == "pallas_call")
+    assert windowed == ["gqa_window_flash_dkv", "gqa_window_flash_dq",
+                        "gqa_window_flash_fwd"]
+    assert not any(a in b or b in a for a in names for b in windowed)
 
 
 def test_narrow_heads_take_larger_blocks_by_default():
     """Where no caller and no tuning record says otherwise: the measured
-    winner for heads of 64 and narrower, the old pair for wider heads
-    and for a window."""
+    winner for heads of 128 and narrower, and for heads of 128 inside a
+    window of 1,024 or wider; the old pair for wider heads and for the
+    windows nobody measured."""
+    old = (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K)
     assert fa.default_blocks(64) == (1024, 1024) == fa.default_blocks(16)
-    assert fa.default_blocks(128) == (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K)
-    assert fa.default_blocks(64, window=512) == (256, 256)
+    assert fa.default_blocks(128) == (1024, 1024)
+    assert fa.default_blocks(128, window=1024) == (1024, 1024) \
+        == fa.default_blocks(128, window=4096)
+    assert fa.default_blocks(256) == old == fa.default_blocks(256, 1024)
+    # float32 heads of 128 do not fit the dk/dv kernel at such blocks
+    assert fa.default_blocks(128, itemsize=4) == old \
+        == fa.default_blocks(128, 1024, itemsize=4)
+    assert fa.default_blocks(64, itemsize=4) == (1024, 1024)
+    assert fa.default_blocks(128, window=512) == old
+    assert fa.default_blocks(64, window=512) == old \
+        == fa.default_blocks(64, window=1024)
     # a short sequence is one block
     q, k, v = operands(2)
     numpy.testing.assert_allclose(

@@ -47,11 +47,13 @@ def _interpret():
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
-#: both blocks for heads of 64 and narrower without a window, measured:
-#: [2, 8192, 32 on 8, 64] bfloat16, causal, forward + backward on the
-#: v5e take 123.3 ms at 256, 58.8 at 512, 44.2 at 1,024 (PERF.md section
-#: 6, PR 33)
-NARROW_HEAD_BLOCK = 1024
+#: both blocks where a measurement chose them: bfloat16, causal, forward
+#: + backward on the v5e, ms at both blocks 256 / 512 / 1,024.  [2, 8192,
+#: 32 on 8, 64]: 123.3 / 58.8 / 44.2 (PERF.md section 6, PR 33).  [2,
+#: 8192, 32 on 4, 128]: 116.6 / 57.6 / 44.4, and inside a window of 1,024
+#: on the banded grids 34.4 / 22.2 / 21.8, with (512, 1024) at 21.9 and
+#: every mixed pair behind (PERF.md section 6, PR 35)
+MEASURED_BLOCK = 1024
 _MIN_BLOCK = 32         # >= f32 sublane tile; smallest worthwhile tile
 _STAT_LANES = 128       # per-row stats (lse, delta) ride a full lane
                         # dim INSIDE the kernels: Mosaic requires block
@@ -95,11 +97,18 @@ def _blocks(t, block_q, block_k):
     return bq, bk
 
 
-def default_blocks(d, window=None):
-    """(block_q, block_k) of head size ``d`` where no tuning record and
-    no caller says otherwise."""
-    if d <= 64 and window is None:
-        return NARROW_HEAD_BLOCK, NARROW_HEAD_BLOCK
+def default_blocks(d, window=None, itemsize=2):
+    """(block_q, block_k) of head size ``d`` and operands of ``itemsize``
+    bytes where no tuning record and no caller says otherwise: the
+    measured winner for rows of 256 bytes and shorter (heads of 128 in
+    bfloat16, of 64 in float32) without a window, and for heads of 128
+    inside a window of a block or wider (a band of narrower windows is
+    mostly masked at such blocks: not measured); the old pair
+    elsewhere.  Float32 heads of 128 at the measured blocks pass the
+    dk/dv kernel's 16 MiB of VMEM by 92 KB."""
+    if d * itemsize <= 256 and (
+            window is None or (d > 64 and window >= MEASURED_BLOCK)):
+        return MEASURED_BLOCK, MEASURED_BLOCK
     return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
 
 
@@ -222,6 +231,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             lse_ref[0] = jnp.broadcast_to(lse, (block_q, _STAT_LANES))
 
 
+def _call_name(kernel, window):
+    """The ``pallas_call`` name, which is its HLO instruction's and so
+    a device trace's: ``gqa_flash_<kernel>`` of a call without a window,
+    ``gqa_window_flash_<kernel>`` of one with, so that a reader tells a
+    window layer's events from a full layer's (neither name holds the
+    other)."""
+    return "gqa_%sflash_%s" % ("" if window is None else "window_", kernel)
+
+
 def _struct(shape, dtype, vma):
     """ShapeDtypeStruct, with mesh-variance declared when the kernel
     runs inside a shard_map (ring flash attention) — check_vma requires
@@ -291,7 +309,7 @@ def _flash_fwd_bh(q, k, v, scale, causal, block_q, block_k, vma=None,
         scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
-        name="gqa_flash_fwd", interpret=_interpret())(q, k, v)
+        name=_call_name("fwd", window), interpret=_interpret())(q, k, v)
     # contiguous fold back to [BH, T] rows (free: a metadata reshape in
     # the compact layout, a lane slice otherwise)
     return out, (lse.reshape(bh, t) if compact else lse[:, :, 0])
@@ -456,7 +474,7 @@ def _flash_bwd_bh(q, k, v, out, lse, do, scale, causal, block_q,
         out_specs=qspec,
         out_shape=_struct((bh, t, d), q.dtype, vma),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        name="gqa_flash_dq", interpret=_interpret())(
+        name=_call_name("dq", window), interpret=_interpret())(
             q, k, v, do, lse, delta)
     # dk/dv pass: K block pinned per middle-grid step; the inner axis
     # streams the Q blocks of the group's query heads, head after head
@@ -483,7 +501,7 @@ def _flash_bwd_bh(q, k, v, out, lse, do, scale, causal, block_q,
                    _struct((bh_kv, t, d), v.dtype, vma)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        name="gqa_flash_dkv", interpret=_interpret())(
+        name=_call_name("dkv", window), interpret=_interpret())(
             q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -549,7 +567,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         cfg, _ = _autotune.resolve(
             site, _site(site).shape_class(ctx),
             default=dict(zip(("block_q", "block_k"),
-                             default_blocks(q.shape[3], window))))
+                             default_blocks(q.shape[3], window,
+                                            q.dtype.itemsize))))
         block_q = block_q if block_q is not None else int(cfg["block_q"])
         block_k = block_k if block_k is not None else int(cfg["block_k"])
     return _flash_attention(q, k, v, causal, scale, block_q, block_k,

@@ -550,8 +550,10 @@ class FusedTrainStep(Unit, IResultProvider):
         ``jax.checkpoint`` (``units``, by scope), what each keeps across
         it (``saves``: ``scope:name+name``) and the bytes a train step
         holds for that from forward to backward (``bytes``; the span's
-        length is the trace that found them).  A chain with no
-        checkpointed unit files nothing."""
+        length is the trace that found them), and what a unit says
+        tells it from its neighbours of the same class (``notes``:
+        ``scope:remat_note``, an attention block's window and rotary
+        type).  A chain with no checkpointed unit files nothing."""
         under = [(scope, fwd, keep) for scope, fwd, keep in zip(
             scopes, self.forwards, keep_f32) if getattr(fwd, "remat", False)]
         if not under:
@@ -561,7 +563,10 @@ class FusedTrainStep(Unit, IResultProvider):
         with events.timed(
                 "step.remat", units=",".join(scope for scope, _, _ in under),
                 saves=" ".join("%s:%s" % (scope, "+".join(fwd.remat_saves))
-                               for scope, fwd, _ in saving)) as span:
+                               for scope, fwd, _ in saving),
+                notes=" ".join("%s:%s" % (scope, fwd.remat_note)
+                               for scope, fwd, _ in under
+                               if hasattr(fwd, "remat_note"))) as span:
             span.count(bytes=sum(saved_bytes(fwd, keep, cdtype)
                                  for _, fwd, keep in saving))
 

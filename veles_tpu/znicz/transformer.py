@@ -9,8 +9,9 @@ fused / epoch-scan trainers chain their pure ``apply(params, x)`` like any
 other layer's.  Pre-norm and residual live INSIDE a block (``x + f(norm(
 x))``), so the chain stays a chain.  The blocks are driven by the keys a
 public ``config.json`` uses (``qk_nope_head_dim``, ``kv_lora_rank``,
-``n_routed_experts``, ``num_key_value_heads``, ``conv_L_cache``, ...) and
-by the share of a deployment this chip holds (``experts_held``,
+``n_routed_experts``, ``num_key_value_heads``, ``conv_L_cache``,
+``sliding_window``, ``rope_parameters``, ``scoring_func``, ...) and by
+the share of a deployment this chip holds (``experts_held``,
 ``experts_offset``), never by a model's name.
 
 Arithmetic under ``--compute-dtype bfloat16``: matrix operands bfloat16,
@@ -52,13 +53,52 @@ def rms_norm(x, weight, eps):
     return (xf * inv * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rope_angles(t, d, theta):
+def yarn_band(d, theta, original_max, beta_fast, beta_slow):
+    """(low, high): the pairs of a ``d``-wide head between which YaRN
+    blends the published frequency into the stretched one.  A pair that
+    turns ``r`` times over ``original_max`` positions has the index ``d
+    ln(original_max / (2 pi r)) / (2 ln theta)``: pairs up to ``low``
+    (``beta_fast`` turns and more) stay, pairs from ``high``
+    (``beta_slow`` and fewer) stretch."""
+    def pair(turns):
+        return d * math.log(original_max / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+    return (max(math.floor(pair(beta_fast)), 0),
+            min(math.ceil(pair(beta_slow)), d - 1))
+
+
+def _rope_angles(t, d, theta, scaling=None):
     """(cos, sin) [T, D/2] float32 of positions 0..T-1: pair ``i`` of a
-    ``D``-wide head turns by ``position * theta ** (-2i / D)``."""
+    ``D``-wide head turns by ``position * theta ** (-2i / D)``.
+
+    ``scaling``: a ``rope_parameters`` group of ``rope_type`` ``yarn``
+    (Peng et al., arXiv:2309.00071): pair ``i`` turns at ``1 - ramp_i *
+    (1 - 1 / factor)`` of its frequency, ``ramp`` rising from 0 at
+    ``low`` to 1 at ``high`` (:func:`yarn_band`: ``factor`` times slower
+    from there), and cos and sin are multiplied by
+    ``attention_factor`` (``0.1 ln(factor) + 1`` where the group gives
+    none), so that the scores carry its square.  At every length, not
+    only past ``original_max_position_embeddings``.  ``factor`` 1 is the
+    default's angles bit for bit."""
     import jax.numpy as jnp
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if scaling is not None:
+        factor = float(scaling["factor"])
+        low, high = yarn_band(
+            d, theta, scaling["original_max_position_embeddings"],
+            scaling.get("beta_fast", 32), scaling.get("beta_slow", 1))
+        ramp = jnp.clip(
+            (jnp.arange(d // 2, dtype=jnp.float32) - low)
+            / (high - low if high > low else 0.001), 0.0, 1.0)
+        # interp * ramp + extrap * (1 - ramp), interp = extrap / factor
+        inv_freq = inv_freq + (inv_freq / factor - inv_freq) * ramp
+        gain = scaling.get("attention_factor")
+        if gain is None:
+            gain = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    return jnp.cos(angle), jnp.sin(angle)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    # the default type's program stays what it was: no product by one
+    return (cos, sin) if scaling is None else (cos * gain, sin * gain)
 
 
 def rope_interleaved(x, theta):
@@ -74,15 +114,16 @@ def rope_interleaved(x, theta):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def rope_half_split(x, theta, seq_axis=-2):
+def rope_half_split(x, theta, seq_axis=-2, scaling=None):
     """The same rotation with the pairs ``(i, i + D/2)`` (the
     ``rotate_half`` convention of the Llama lineage): the first half of a
     head holds the pairs' first members, the second half their second.
-    ``seq_axis`` is the axis of the positions (-3 for [B, T, H, D])."""
+    ``seq_axis`` is the axis of the positions (-3 for [B, T, H, D]);
+    ``scaling`` as :func:`_rope_angles` reads it."""
     import jax.numpy as jnp
     t, d = x.shape[seq_axis], x.shape[-1]
     cos, sin = (a.reshape((t,) + (1,) * (-seq_axis - 2) + (d // 2,))
-                for a in _rope_angles(t, d, theta))
+                for a in _rope_angles(t, d, theta, scaling))
     xf = x.astype(jnp.float32)
     a, b = xf[..., :d // 2], xf[..., d // 2:]
     out = jnp.concatenate([a * cos - b * sin, a * sin + b * cos], axis=-1)
@@ -305,7 +346,17 @@ class GQAAttentionBlock(BlockBase):
     head in the half-split pairing, causal softmax with scale ``1 /
     sqrt(head_dim)``.  No bias anywhere.  The core runs the plain flash
     kernels (``flash_attention.py``: K and V stay at their own head
-    count) on a TPU and explicit scores elsewhere."""
+    count) on a TPU and explicit scores elsewhere.
+
+    Two arguments make the kinds of layer one depth mixes
+    (``layer_types``) out of the one unit.  ``sliding_window`` (absent:
+    a full layer): position ``i`` sees the keys in ``(i -
+    sliding_window, i]``, on the kernels' banded grids.
+    ``rope_parameters``: the layer kind's own group of a
+    ``config.json`` (``rope_theta``, ``rope_type`` ``default`` or
+    ``yarn`` with its ``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow``, ``attention_factor``); a bare
+    ``rope_theta`` is the default type's."""
 
     MAPPING = "gqa_attention_block"
     FLOAT32_PARAMS = ("norm", "q_norm", "k_norm")
@@ -321,11 +372,27 @@ class GQAAttentionBlock(BlockBase):
         self.kv_heads = int(kwargs.get("num_key_value_heads", self.heads))
         self.head_dim = int(kwargs.get("head_dim")
                             or self.hidden_size // self.heads)
-        self.rope_theta = float(kwargs.get("rope_theta", 10000.0))
+        rope = dict(kwargs.get("rope_parameters") or {})
+        self.rope_theta = float(rope.get(
+            "rope_theta", kwargs.get("rope_theta", 10000.0)))
+        self.rope_type = rope.get("rope_type", "default")
+        if self.rope_type not in ("default", "yarn"):
+            raise ValueError("no rotary angles of rope_type %r"
+                             % (self.rope_type,))
+        #: what ``_rope_angles`` reads of a scaled type; None: default
+        self.rope_scaling = rope if self.rope_type == "yarn" else None
+        window = kwargs.get("sliding_window")
+        self.sliding_window = None if window is None else int(window)
         self.use_pallas = kwargs.get("use_pallas")
         if self.heads % self.kv_heads:
             raise ValueError("%d query heads cannot share %d key-value "
                              "heads" % (self.heads, self.kv_heads))
+
+    @property
+    def remat_note(self):
+        """What tells this unit from its neighbour of the same class, for
+        the step's ``step.remat`` record."""
+        return "window=%s,rope=%s" % (self.sliding_window, self.rope_type)
 
     def tensor_shapes(self):
         d, k = self.hidden_size, self.head_dim
@@ -344,8 +411,10 @@ class GQAAttentionBlock(BlockBase):
         from .flash_attention import flash_attention
         from .nn_units import resolve_use_pallas
         if resolve_use_pallas(self.use_pallas, self.device, tpu_auto=True):
-            return flash_attention(q, k, v, causal=True)
-        return attention_reference(q, k, v, causal=True)
+            return flash_attention(q, k, v, causal=True,
+                                   window=self.sliding_window)
+        return attention_reference(q, k, v, causal=True,
+                                   window=self.sliding_window)
 
     def apply(self, params, x):
         import jax
@@ -357,13 +426,14 @@ class GQAAttentionBlock(BlockBase):
         def heads(name, n):
             return jnp.einsum("bsd,dhk->bshk", hn, params[name].reshape(
                 d, n, k), preferred_element_type=f32).astype(x.dtype)
+
+        def turned(name, n, norm):
+            return rope_half_split(
+                self._norm(heads(name, n), params[norm]), self.rope_theta,
+                seq_axis=-3, scaling=self.rope_scaling)
         with jax.named_scope("attn/qkv"):
-            q = rope_half_split(self._norm(heads("wq", self.heads),
-                                           params["q_norm"]),
-                                self.rope_theta, seq_axis=-3)
-            key = rope_half_split(self._norm(heads("wk", self.kv_heads),
-                                             params["k_norm"]),
-                                  self.rope_theta, seq_axis=-3)
+            q = turned("wq", self.heads, "q_norm")
+            key = turned("wk", self.kv_heads, "k_norm")
             v = heads("wv", self.kv_heads)
         with jax.named_scope("attn/core"):
             out = self._core(q, key, v)                # [B, S, H, k]
@@ -461,7 +531,9 @@ _Plan = collections.namedtuple("_Plan", "order pos here sizes")
 
 class ExpertBlock(BlockBase):
     """``x + sum_e w_e E_e(h) + Shared(h)``, ``h = RMSNorm(x)``: a
-    float32 sigmoid router over ALL ``n_routed_experts``, the
+    float32 router over ALL ``n_routed_experts`` whose scores ``s`` are
+    ``scoring_func`` of its logits (``sigmoid``, each expert alone, the
+    default; ``softmax`` over all of them), the
     ``num_experts_per_tok`` largest ``s + b`` chosen (``b``: the
     ``noaux_tc`` correction bias, used for the choice only), weights
     ``routed_scaling_factor * s / (sum s + norm_topk_eps)`` over the
@@ -483,7 +555,13 @@ class ExpertBlock(BlockBase):
     ``b_e += rate * sign(mean load - load_e)``, the load being the
     step's tokens that chose expert ``e`` among ALL routed experts
     (counter ``router_load``).  At 0, the default, ``b`` is a buffer
-    nothing touches.
+    nothing touches.  ``bias_update_rule`` ``proportional`` takes the
+    error itself for its sign, ``b_e += rate * (mean load - load_e) /
+    mean load`` (the other form Wang et al., arXiv:2408.15664, give the
+    rule): a bias that stays bounded then holds every expert's load AVERAGED
+    over the steps at the mean, whatever moves together, where the sign
+    holds the median step there (a lump of tokens that flips between
+    two experts leaves each at half the lump).
 
     ``train_router`` False leaves the routing weights out of the
     backward pass, so the router matrix gets no gradient: what a share
@@ -504,11 +582,19 @@ class ExpertBlock(BlockBase):
         self.top_k = int(kwargs["num_experts_per_tok"])
         self.n_shared = int(kwargs.get("n_shared_experts", 0))
         self.scaling = float(kwargs.get("routed_scaling_factor", 1.0))
+        self.scoring_func = kwargs.get("scoring_func", "sigmoid")
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError("no router scores by %r"
+                             % (self.scoring_func,))
         self.norm_topk = bool(kwargs.get("norm_topk_prob", True))
         #: added to the sum the chosen scores are normalised by: 1e-20
         #: in the DeepSeek-V3 family's code, 1e-6 in LFM2's
         self.norm_topk_eps = float(kwargs.get("norm_topk_eps", 1e-20))
         self.bias_update_rate = float(kwargs.get("bias_update_rate", 0.0))
+        self.bias_update_rule = kwargs.get("bias_update_rule", "sign")
+        if self.bias_update_rule not in ("sign", "proportional"):
+            raise ValueError("no balancing update by %r"
+                             % (self.bias_update_rule,))
         self.train_router = bool(kwargs.get("train_router", True))
         self.held = int(kwargs.get("experts_held", self.n_experts))
         self.offset = int(kwargs.get("experts_offset", 0))
@@ -545,7 +631,9 @@ class ExpertBlock(BlockBase):
         [T, d]."""
         import jax
         import jax.numpy as jnp
-        scores = jax.nn.sigmoid(jnp.dot(
+        score = jax.nn.sigmoid if self.scoring_func == "sigmoid" \
+            else jax.nn.softmax         # over the last axis: all experts
+        scores = score(jnp.dot(
             h.astype(jnp.float32), params["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
         _, chosen = jax.lax.top_k(
@@ -698,8 +786,11 @@ class ExpertBlock(BlockBase):
         if not self.bias_update_rate:
             return {}
         load = stats["router_load"].astype(jnp.float32)
+        error = load.mean() - load
+        error = jnp.sign(error) if self.bias_update_rule == "sign" \
+            else error / load.mean()
         return {"router_bias": params["router_bias"]
-                + self.bias_update_rate * jnp.sign(load.mean() - load)}
+                + self.bias_update_rate * error}
 
     def apply(self, params, x):
         return self.apply_stats(params, x)[0]
