@@ -230,8 +230,8 @@ def test_window_banded_backward_geometry():
     32x32 blocks, window=40 -> k-band 4 of 8, q-band 4 of 8."""
     from veles_tpu.znicz.flash_attention import (_kband_size,
                                                  _qband_size)
-    assert _kband_size(32, 32, 40) < 256 // 32
-    assert _qband_size(32, 32, 40) < 256 // 32
+    assert _kband_size(256, 32, 32, 40) < 256 // 32
+    assert _qband_size(256, 32, 32, 40) < 256 // 32
     q, k, v = _mk(1, 256, 2, 8, seed=8)
 
     got = jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash_attention(

@@ -72,8 +72,8 @@ def test_a_window_over_grouped_heads(h_kv, window, bq, bk):
                            jnp.float32)
     q, k, v = draw(16), draw(h_kv), draw(h_kv)
     # the grids are banded at these sizes, in both directions
-    assert fa._kband_size(bq, bk, window) < 256 // bk
-    assert fa._qband_size(bq, bk, window) < 256 // bq
+    assert fa._kband_size(256, bq, bk, window) < 256 // bk
+    assert fa._qband_size(256, bq, bk, window) < 256 // bq
 
     def flash(q, k, v):
         return fa.flash_attention(q, k, v, True, None, bq, bk, window)

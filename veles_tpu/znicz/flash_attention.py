@@ -32,13 +32,16 @@ to the oracle with a logged warning, so the knob is always safe.
 import functools
 import logging
 import math
+import typing
 
 import jax
 import jax.numpy as jnp
+import numpy
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import backends
+from ..logger import events
 
 
 def _interpret():
@@ -53,6 +56,9 @@ DEFAULT_BLOCK_K = 256
 #: 8192, 32 on 4, 128]: 116.6 / 57.6 / 44.4, and inside a window of 1,024
 #: on the banded grids 34.4 / 22.2 / 21.8, with (512, 1024) at 21.9 and
 #: every mixed pair behind (PERF.md section 6, PR 35)
+#: and, on the exact bands that skip fetches and masks, (512, 512) at 19.7
+#: against 20.0 at (1,024, 1,024), the pairs between them slower (PERF.md
+#: section 6)
 MEASURED_BLOCK = 1024
 _MIN_BLOCK = 32         # >= f32 sublane tile; smallest worthwhile tile
 _STAT_LANES = 128       # per-row stats (lse, delta) ride a full lane
@@ -117,19 +123,6 @@ def flash_attention_supported(t, block_q=DEFAULT_BLOCK_Q,
     return _blocks(t, block_q, block_k) is not None
 
 
-def _block_needed(iq, jk, block_q, block_k, window=None):
-    """Causal: does Q block iq see any of K block jk?  (first key pos
-    <= last query pos; with a sliding ``window``, also last key pos
-    inside the band of the first query pos)"""
-    vis = jk * block_k <= iq * block_q + block_q - 1
-    if window is not None:
-        # query i sees keys in (i - window, i]: block visible iff its
-        # LAST key > FIRST query - window
-        vis = jnp.logical_and(
-            vis, jk * block_k + block_k - 1 > iq * block_q - window)
-    return vis
-
-
 def _mask_causal(s, iq, jk, block_q, block_k, window=None):
     rows = iq * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
@@ -141,47 +134,268 @@ def _mask_causal(s, iq, jk, block_q, block_k, window=None):
     return jnp.where(mask, _NEG_INF, s)
 
 
-# -- sliding-window band geometry --------------------------------------------
+# -- which blocks a grid step needs ------------------------------------------
 #
-# With a window the kernels run a BANDED grid: the streamed axis only
-# visits the blocks a pinned block can actually see, so compute AND
-# block DMA are O(T * window) instead of O(T^2).  The streamed grid
-# index j maps to a logical block via the band start; index_maps clip
-# into range and the in-kernel predicate skips any overshoot.
+# Every grid step pins one block and streams another.  From the static
+# geometry a step is CLEAR (every (query, key) pair of the block is
+# visible: no mask), EDGE (the causal diagonal or the window's edge
+# crosses it: masked) or INVISIBLE (nothing computed).  The blocks a
+# pinned block needs are one contiguous run, so an invisible step's
+# index map re-references the nearest of them: a block index that does
+# not change is not fetched again, and a skipped step fetches nothing.
+# With a window the grids are BANDED: the streamed axis visits only as
+# many blocks as the widest run, so compute and block DMA are O(T *
+# window) instead of O(T^2); the streamed grid index j maps to a
+# logical block from the run's start.
 
 
-def _kband_start(iq, block_q, block_k, window):
-    """First K block visible to Q block iq (keys > iq*bq - window)."""
-    return jnp.maximum(0, (iq * block_q - window + 1) // block_k)
+class _Scalar:
+    """Arithmetic on traced block indices as single primitives, where
+    jnp's adds promotion and, for ``//``, a sign fix: an index map is
+    traced for every call and each operation costs its trace.  Every
+    quotient taken here is of a non-negative number or clamped at 0
+    after, where truncation and floor agree."""
+    maximum = staticmethod(lax.max)
+    minimum = staticmethod(lax.min)
+    floor_divide = staticmethod(lax.div)
 
 
-def _kband_size(block_q, block_k, window):
-    """K blocks any single Q block can see, worst case over phases."""
-    return (block_q + window - 2) // block_k + 2
+class _Grid(typing.NamedTuple):
+    """The static geometry of one call.  Its methods take traced scalars
+    in the kernels and index maps (``xp`` :class:`_Scalar`) and numpy
+    arrays in :func:`block_census` (``xp`` numpy)."""
+    t: int
+    block_q: int
+    block_k: int
+    window: typing.Optional[int]
+    causal: bool
+
+    @property
+    def n_q(self):
+        return self.t // self.block_q
+
+    @property
+    def n_k(self):
+        return self.t // self.block_k
+
+    def k_first(self, iq, xp=_Scalar):
+        """First key block query block ``iq`` sees: keys > its first
+        query - window."""
+        if self.window is None:
+            return 0
+        return xp.maximum(0, xp.floor_divide(
+            iq * self.block_q + (1 - self.window), self.block_k))
+
+    def k_last(self, iq, xp=_Scalar):
+        """Last key block query block ``iq`` sees: the diagonal's."""
+        if not self.causal:
+            return self.n_k - 1
+        return xp.floor_divide(iq * self.block_q + (self.block_q - 1),
+                               self.block_k)
+
+    def q_first(self, jk, xp=_Scalar):
+        """First query block that sees key block ``jk``: queries >=
+        keys."""
+        if not self.causal:
+            return 0
+        return xp.floor_divide(jk * self.block_k, self.block_q)
+
+    def q_last(self, jk, xp=_Scalar):
+        """Last query block that sees key block ``jk``: queries < its
+        last key + window."""
+        if self.window is None:
+            return self.n_q - 1
+        return xp.minimum(self.n_q - 1, xp.floor_divide(
+            jk * self.block_k + (self.block_k + self.window - 2),
+            self.block_q))
+
+    @property
+    def k_inner(self):
+        """The fwd / dq grids' streamed axis: the band where a window
+        makes it narrower than the key blocks, else all of them."""
+        if self.window is None:
+            return self.n_k
+        return min(self.n_k, _kband_size(self.t, self.block_q,
+                                         self.block_k, self.window))
+
+    @property
+    def q_inner(self):
+        """The dk/dv grid's streamed axis, a head's part of it."""
+        if self.window is None:
+            return self.n_q
+        return min(self.n_q, _qband_size(self.t, self.block_q,
+                                         self.block_k, self.window))
+
+    def key(self, iq, j, xp=_Scalar):
+        """Key block of the fwd / dq grids' step (``iq``, ``j``)."""
+        return j if self.k_inner == self.n_k else self.k_first(iq, xp) + j
+
+    def key_fetched(self, iq, j, xp=_Scalar):
+        """The key block that step fetches: its own where query block
+        ``iq`` needs it, else the nearest one that it needs; each map
+        holds only the bounds it can cross."""
+        if not self.causal:
+            return j
+        if self.k_inner < self.n_k:     # a band starts at the first needed
+            jk = self.k_first(iq, xp) + j
+        elif self.window is not None:
+            jk = xp.maximum(j, self.k_first(iq, xp))
+        else:
+            jk = j
+        return xp.minimum(jk, self.k_last(iq, xp))
+
+    def query(self, jk, i, xp=_Scalar):
+        """Query block of the dk/dv grid's step (``jk``, ``i``), ``i``
+        counted within a head."""
+        return i if self.q_inner == self.n_q else self.q_first(jk, xp) + i
+
+    def query_fetched(self, jk, i, xp=_Scalar):
+        """The query block that step fetches, as :meth:`key_fetched`."""
+        if not self.causal:
+            return i
+        if self.q_inner < self.n_q:
+            iq = self.q_first(jk, xp) + i
+        else:
+            iq = xp.maximum(i, self.q_first(jk, xp))
+        if self.window is None:
+            return iq
+        return xp.minimum(iq, self.q_last(jk, xp))
+
+    def step_class(self, iq, jk):
+        """(needed, clear) of the step that pairs query block ``iq`` with
+        key block ``jk``; a step past the last query block (a band's
+        overshoot) is neither."""
+        if not self.causal:
+            in_range = iq < self.n_q
+            return in_range, in_range
+        # query row r sees key c iff 0 <= r - c < window; over the block
+        # r - c runs from d - block_k + 1 to d + block_q - 1
+        d = iq * self.block_q - jk * self.block_k
+        needed = d > -self.block_q
+        clear = d >= self.block_k - 1
+        if self.window is not None:
+            needed = needed & (d < self.window + self.block_k - 1)
+            clear = clear & (d <= self.window - self.block_q)
+        if self.q_inner < self.n_q:
+            in_range = iq < self.n_q
+            needed, clear = needed & in_range, clear & in_range
+        return needed, clear
+
+    @property
+    def classes(self):
+        """Whether any (query block, key block) pair is clear, and whether
+        any is an edge.  A kernel holds a body only for a class that
+        occurs (inside a window as wide as the blocks no block is clear):
+        each body costs the program's lowering and compile time."""
+        iq, jk = numpy.meshgrid(numpy.arange(self.n_q),
+                                numpy.arange(self.n_k), indexing="ij")
+        needed, clear = (numpy.broadcast_to(c, iq.shape)
+                         for c in self.step_class(iq, jk))
+        return bool(clear.any()), bool((needed & ~clear).any())
 
 
-def _qband_start(jk, block_q, block_k):
-    """First Q block that sees K block jk (causal: queries >= keys)."""
-    return (jk * block_k) // block_q
+def _kband_size(t, block_q, block_k, window):
+    """Key blocks the widest query block's run holds: the most any query
+    block of the ``t`` rows needs."""
+    grid = _Grid(t, block_q, block_k, window, True)
+    iq = numpy.arange(grid.n_q)
+    return int(numpy.max(grid.k_last(iq, numpy) - grid.k_first(iq, numpy)
+                         + 1))
 
 
-def _qband_size(block_q, block_k, window):
-    """Q blocks any single K block is visible to, worst case."""
-    return (block_k + window - 2) // block_q + 2
+def _qband_size(t, block_q, block_k, window):
+    """Query blocks the widest key block's run holds."""
+    grid = _Grid(t, block_q, block_k, window, True)
+    jk = numpy.arange(grid.n_k)
+    return int(numpy.max(grid.q_last(jk, numpy) - grid.q_first(jk, numpy)
+                         + 1))
+
+
+def _run_step(step, grid, iq, jk):
+    """``step(masked)`` on the blocks the step needs: unmasked on a clear
+    block, masked on an edge block, not at all on an invisible one.  A
+    call without causality runs every step under a traced ``when`` too:
+    interpret mode inside a ``shard_map`` (the ring) cannot read the
+    blocks outside one."""
+    from jax.experimental import pallas as pl
+
+    any_clear, any_edge = grid.classes
+    needed, clear = grid.step_class(iq, jk)
+    if any_clear:
+        pl.when(clear)(lambda: step(False))
+    if any_edge:
+        pl.when(jnp.logical_and(needed, jnp.logical_not(clear)))(
+            lambda: step(True))
+
+
+def block_census(t, block_q, block_k, window=None, causal=True, group=1):
+    """What the three grids of a call do for one key-value head and its
+    ``group`` query heads: for ``fwd``, ``dq`` and ``dkv`` the grid's
+    ``steps``, its ``clear`` and ``edge`` steps (the others compute
+    nothing), the block ``fetches`` of its streamed operands (a step
+    whose block index is its predecessor's fetches nothing) and the
+    ``wasted`` ones, whose run of steps on that index holds no clear or
+    edge step.  Pure: the kernels' own index maps and step classes,
+    evaluated in numpy over the grid in its order."""
+    if t % block_q or t % block_k:
+        raise ValueError("blocks (%d, %d) do not tile %d rows"
+                         % (block_q, block_k, t))
+    grid = _Grid(t, block_q, block_k, window, causal)
+
+    def census(iq, jk, head, block):
+        needed, clear = (numpy.broadcast_to(c, iq.shape)
+                         for c in grid.step_class(iq, jk))
+        fetch = numpy.ones(iq.shape, bool)
+        fetch[1:] = (head[1:] != head[:-1]) | (block[1:] != block[:-1])
+        run = numpy.cumsum(fetch) - 1
+        used = numpy.bincount(run, weights=needed) > 0
+        return {"steps": iq.size, "clear": int(clear.sum()),
+                "edge": int((needed & ~clear).sum()),
+                "fetches": int(fetch.sum()), "wasted": int((~used).sum())}
+
+    # fwd / dq: (query head, query block, streamed key step); the K and V
+    # blocks of the one key-value head
+    _, iq, j = (a.ravel() for a in numpy.meshgrid(
+        numpy.arange(group), numpy.arange(grid.n_q),
+        numpy.arange(grid.k_inner), indexing="ij"))
+    rows = census(iq, grid.key(iq, j, numpy), numpy.zeros_like(iq),
+                  grid.key_fetched(iq, j, numpy))
+    # dk/dv: (key block, streamed query step of the heads in turn)
+    jk, i = (a.ravel() for a in numpy.meshgrid(
+        numpy.arange(grid.n_k), numpy.arange(group * grid.q_inner),
+        indexing="ij"))
+    i_head = i % grid.q_inner
+    cols = census(grid.query(jk, i_head, numpy), jk, i // grid.q_inner,
+                  grid.query_fetched(jk, i_head, numpy))
+    return {"fwd": rows, "dq": dict(rows), "dkv": cols}
+
+
+def _file_census(grid, kernels, group, kv_heads):
+    """The census of a call's ``kernels``, filed as it is traced (a train
+    step pays nothing for it): one span ``flash.grid`` a kernel, named
+    by its call, with the geometry and the counts of all ``kv_heads``
+    key-value heads of the call."""
+    census = block_census(grid.t, grid.block_q, grid.block_k, grid.window,
+                          grid.causal, group)
+    geometry = dict(t=grid.t, block_q=grid.block_q, block_k=grid.block_k,
+                    causal=int(grid.causal), group=group,
+                    kv_heads=kv_heads)
+    if grid.window is not None:
+        geometry["window"] = grid.window
+    for kernel in kernels:
+        with events.timed("flash.grid", call=_call_name(kernel, grid.window),
+                          **geometry) as span:
+            span.count(**{name: n * kv_heads
+                          for name, n in census[kernel].items()})
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                acc_scr, *, scale, causal, block_q, block_k,
-                window=None, window_grid=None, compact_stats=False):
+                acc_scr, *, scale, grid, compact_stats=False):
     from jax.experimental import pallas as pl
 
     iq, j = pl.program_id(1), pl.program_id(2)
     n_inner = pl.num_programs(2)
-    # banded grid (window_grid set): j is an offset into the band;
-    # window alone may also be set with a DENSE grid (band >= n_k),
-    # where the mask enforces it
-    jk = j if window_grid is None else _kband_start(
-        iq, block_q, block_k, window_grid) + j
+    jk = grid.key(iq, j)
 
     @pl.when(j == 0)
     def _init():
@@ -189,30 +403,34 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(_block_needed(iq, jk, block_q, block_k, window)
-             if causal else jk >= 0)
-    def _step():
+    def step(masked):
         q = q_ref[0].astype(jnp.float32) * scale       # [BQ, D]
         kb = k_ref[0].astype(jnp.float32)              # [BK, D]
         vb = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # [BQ, BK]
-        if causal:
-            s = _mask_causal(s, iq, jk, block_q, block_k, window)
+        if masked:
+            s = _mask_causal(s, iq, jk, grid.block_q, grid.block_k,
+                             grid.window)
         m = m_scr[...]
         new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # a fully-masked row keeps m at -inf: exp(-inf - -inf) must be
-        # 0, not nan (same guard as parallel/ring.py:77)
+        # 0, not nan (same guard as parallel/ring.py:77); a row's first
+        # block may be a clear one, so m is guarded on both paths
         safe_m = jnp.where(jnp.isneginf(new_m), 0.0, new_m)
         alpha = jnp.where(jnp.isneginf(m), 0.0, jnp.exp(m - safe_m))
-        p = jnp.where(jnp.isneginf(s), 0.0, jnp.exp(s - safe_m))
+        p = jnp.exp(s - safe_m)
+        if masked:
+            p = jnp.where(jnp.isneginf(s), 0.0, p)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1,
                                                   keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p, vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = new_m
+
+    _run_step(step, grid, iq, jk)
 
     @pl.when(j == n_inner - 1)
     def _finish():
@@ -225,10 +443,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             # per query row in HBM instead of a 128x lane broadcast (a
             # single in-VMEM relayout per Q block — negligible next to
             # the saved HBM write traffic)
-            lse_ref[0, 0] = lse.reshape(block_q // _STAT_LANES,
+            lse_ref[0, 0] = lse.reshape(grid.block_q // _STAT_LANES,
                                         _STAT_LANES)
         else:
-            lse_ref[0] = jnp.broadcast_to(lse, (block_q, _STAT_LANES))
+            lse_ref[0] = jnp.broadcast_to(lse, (grid.block_q,
+                                                _STAT_LANES))
 
 
 def _call_name(kernel, window):
@@ -261,32 +480,17 @@ def _flash_fwd_bh(q, k, v, scale, causal, block_q, block_k, vma=None,
 
     bh, t, d = q.shape
     group = bh // k.shape[0]
-    n_q, n_k = t // block_q, t // block_k
-    if window is not None and _kband_size(block_q, block_k,
-                                          window) >= n_k:
-        window_grid = None  # band covers everything: dense grid,
-        n_inner = n_k       # window enforced by the mask alone
-    else:
-        window_grid = window
-        n_inner = n_k if window is None else _kband_size(
-            block_q, block_k, window)
+    grid = _Grid(t, block_q, block_k, window, causal)
+    _file_census(grid, ("fwd",), group, k.shape[0])
     # compact stats layout whenever each Q block covers whole 128-lane
     # rows (default 256/128 blocks do; the 32/64 fallbacks keep the
     # lane-broadcast layout) — see the _STAT_LANES note
     compact = block_q % _STAT_LANES == 0
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, window=window, window_grid=window_grid,
-        compact_stats=compact)
+    kernel = functools.partial(_fwd_kernel, scale=scale, grid=grid,
+                               compact_stats=compact)
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    if window_grid is None:
-        k_index = lambda b, i, j: (b // group, j, 0)  # noqa: E731
-    else:
-        k_index = lambda b, i, j: (  # noqa: E731
-            b // group,
-            jnp.clip(_kband_start(i, block_q, block_k, window_grid)
-                     + j, 0, n_k - 1), 0)
-    kspec = pl.BlockSpec((1, block_k, d), k_index)
+    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (
+        b // group, grid.key_fetched(i, j), 0))
     if compact:
         # one [block_q // 128, 128] slab per Q block, as the LAST TWO
         # dims of a 4-D array: a block must either tile (8, 128) or
@@ -295,13 +499,13 @@ def _flash_fwd_bh(q, k, v, scale, causal, block_q, block_k, vma=None,
         rows = block_q // _STAT_LANES
         lse_spec = pl.BlockSpec((1, 1, rows, _STAT_LANES),
                                 lambda b, i, j: (b, i, 0, 0))
-        lse_shape = (bh, n_q, rows, _STAT_LANES)
+        lse_shape = (bh, grid.n_q, rows, _STAT_LANES)
     else:
         lse_spec = pl.BlockSpec((1, block_q, _STAT_LANES),
                                 lambda b, i, j: (b, i, 0))
         lse_shape = (bh, t, _STAT_LANES)
     out, lse = pl.pallas_call(
-        kernel, grid=(bh, n_q, n_inner),
+        kernel, grid=(bh, grid.n_q, grid.k_inner),
         in_specs=[qspec, kspec, kspec],
         out_specs=[qspec, lse_spec],
         out_shape=[_struct((bh, t, d), q.dtype, vma),
@@ -316,22 +520,18 @@ def _flash_fwd_bh(q, k, v, scale, causal, block_q, block_k, vma=None,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, scale, causal, block_q, block_k,
-               window=None, window_grid=None):
+               dq_scr, *, scale, grid):
     from jax.experimental import pallas as pl
 
     iq, j = pl.program_id(1), pl.program_id(2)
     n_inner = pl.num_programs(2)
-    jk = j if window_grid is None else _kband_start(
-        iq, block_q, block_k, window_grid) + j
+    jk = grid.key(iq, j)
 
     @pl.when(j == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(_block_needed(iq, jk, block_q, block_k, window)
-             if causal else jk >= 0)
-    def _step():
+    def step(masked):
         q = q_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0, :, 0:1]
@@ -341,9 +541,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _mask_causal(s, iq, jk, block_q, block_k, window)
-        p = jnp.where(jnp.isneginf(s), 0.0, jnp.exp(s - lse))
+        if masked:
+            s = _mask_causal(s, iq, jk, grid.block_q, grid.block_k,
+                             grid.window)
+        p = jnp.exp(s - lse)
+        if masked:
+            p = jnp.where(jnp.isneginf(s), 0.0, p)
         dov = jax.lax.dot_general(
             do, vb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # [BQ, BK]
@@ -352,40 +555,31 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             ds, kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
+    _run_step(step, grid, iq, jk)
+
     @pl.when(j == n_inner - 1)
     def _finish():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal,
-                block_q, block_k, n_q_inner, window=None,
-                window_grid=None, n_q_total=None):
+                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, grid):
     """The K block of one KEY-VALUE head is pinned; the Q blocks of the
     query heads that read it stream through the innermost grid axis
-    (``n_q_inner`` blocks a head, head after head), so dK and dV are
+    (``grid.q_inner`` blocks a head, head after head), so dK and dV are
     summed over the group in VMEM and written once."""
     from jax.experimental import pallas as pl
 
     jk, j = pl.program_id(1), pl.program_id(2)
     n_inner = pl.num_programs(2)
-    i = j % n_q_inner
-    iq = i if window_grid is None else _qband_start(
-        jk, block_q, block_k) + i
-    visible = (_block_needed(iq, jk, block_q, block_k, window)
-               if causal else iq >= 0)
-    if window_grid is not None:
-        # the q band's top is NOT capped by causality (unlike the
-        # fwd/dq k band): exclude overshoot past the last Q block
-        visible = jnp.logical_and(visible, iq <= n_q_total - 1)
+    iq = grid.query(jk, j % grid.q_inner)
 
     @pl.when(j == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(visible)
-    def _step():
+    def step(masked):
         kb = k_ref[0].astype(jnp.float32)
         vb = v_ref[0].astype(jnp.float32)
         q = q_ref[0].astype(jnp.float32)
@@ -395,9 +589,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _mask_causal(s, iq, jk, block_q, block_k, window)
-        p = jnp.where(jnp.isneginf(s), 0.0, jnp.exp(s - lse))
+        if masked:
+            s = _mask_causal(s, iq, jk, grid.block_q, grid.block_k,
+                             grid.window)
+        p = jnp.exp(s - lse)
+        if masked:
+            p = jnp.where(jnp.isneginf(s), 0.0, p)
         dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -408,6 +605,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
+
+    _run_step(step, grid, iq, jk)
 
     @pl.when(j == n_inner - 1)
     def _finish():
@@ -431,7 +630,8 @@ def _flash_bwd_bh(q, k, v, out, lse, do, scale, causal, block_q,
     bh, t, d = q.shape
     bh_kv = k.shape[0]
     group = bh // bh_kv
-    n_q, n_k = t // block_q, t // block_k
+    grid = _Grid(t, block_q, block_k, window, causal)
+    _file_census(grid, ("dq", "dkv"), group, bh_kv)
     if delta is None:
         # delta_i = sum_d do*out — tiny elementwise reduce; XLA fuses it
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
@@ -442,34 +642,14 @@ def _flash_bwd_bh(q, k, v, out, lse, do, scale, causal, block_q,
                                  (bh, t, _STAT_LANES))
     if lse.ndim == 2:
         lse = jnp.broadcast_to(lse[..., None], (bh, t, _STAT_LANES))
-    # band geometry mirrors _flash_fwd_bh: banded grids only when they
-    # actually shrink the streamed axis
-    if window is not None and _kband_size(block_q, block_k,
-                                          window) < n_k:
-        wg_k, nk_inner = window, _kband_size(block_q, block_k, window)
-    else:
-        wg_k, nk_inner = None, n_k
-    if window is not None and _qband_size(block_q, block_k,
-                                          window) < n_q:
-        wg_q, nq_inner = window, _qband_size(block_q, block_k, window)
-    else:
-        wg_q, nq_inner = None, n_q
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     qrow = pl.BlockSpec((1, block_q, _STAT_LANES),
                         lambda b, i, j: (b, i, 0))
-    if wg_k is None:
-        dq_k_index = lambda b, i, j: (b // group, j, 0)  # noqa: E731
-    else:
-        dq_k_index = lambda b, i, j: (  # noqa: E731
-            b // group,
-            jnp.clip(_kband_start(i, block_q, block_k, wg_k) + j,
-                     0, n_k - 1), 0)
-    kspec = pl.BlockSpec((1, block_k, d), dq_k_index)
+    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (
+        b // group, grid.key_fetched(i, j), 0))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          window=window, window_grid=wg_k),
-        grid=(bh, n_q, nk_inner),
+        functools.partial(_dq_kernel, scale=scale, grid=grid),
+        grid=(bh, grid.n_q, grid.k_inner),
         in_specs=[qspec, kspec, kspec, qspec, qrow, qrow],
         out_specs=qspec,
         out_shape=_struct((bh, t, d), q.dtype, vma),
@@ -478,23 +658,17 @@ def _flash_bwd_bh(q, k, v, out, lse, do, scale, causal, block_q,
             q, k, v, do, lse, delta)
     # dk/dv pass: K block pinned per middle-grid step; the inner axis
     # streams the Q blocks of the group's query heads, head after head
-    if wg_q is None:
-        dkv_q_index = lambda b, j, i: (  # noqa: E731
-            b * group + i // nq_inner, i % nq_inner, 0)
-    else:
-        dkv_q_index = lambda b, j, i: (  # noqa: E731
-            b * group + i // nq_inner,
-            jnp.clip(_qband_start(j, block_q, block_k) + i % nq_inner,
-                     0, n_q - 1), 0)
+    nq_inner = grid.q_inner
+
+    def dkv_q_index(b, j, i):
+        return (b * group + i // nq_inner,
+                grid.query_fetched(j, i % nq_inner), 0)
     kq_spec = pl.BlockSpec((1, block_q, d), dkv_q_index)
     kq_row = pl.BlockSpec((1, block_q, _STAT_LANES), dkv_q_index)
     kk_spec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          n_q_inner=nq_inner, window=window,
-                          window_grid=wg_q, n_q_total=n_q),
-        grid=(bh_kv, n_k, group * nq_inner),
+        functools.partial(_dkv_kernel, scale=scale, grid=grid),
+        grid=(bh_kv, grid.n_k, group * nq_inner),
         in_specs=[kq_spec, kk_spec, kk_spec, kq_spec, kq_row, kq_row],
         out_specs=[kk_spec, kk_spec],
         out_shape=[_struct((bh_kv, t, d), k.dtype, vma),
