@@ -666,8 +666,8 @@ def run(backend=BACKEND, multichip=False):
     from veles_tpu.backends import (apply_compilation_cache_config,
                                     cache_root)
     from veles_tpu.config import root
-    from veles_tpu.observability.compiles import CompileMonitor
-    monitor = CompileMonitor()
+    from veles_tpu.observability import compiles
+    monitor = compiles.monitor()
     # the one cache directory: $JAX_COMPILATION_CACHE_DIR when the machine
     # sets it (JAX is already there), else the checkout's .cache/
     root.common.engine.compilation_cache_dir = cache_root()

@@ -12,7 +12,7 @@ import numpy
 import pytest
 
 from veles_tpu.logger import events
-from veles_tpu.observability.compiles import CompileMonitor
+from veles_tpu.observability import compiles
 from veles_tpu.parallel.mesh import make_mesh
 
 from test_spans import named
@@ -30,14 +30,13 @@ def data4():
 @pytest.fixture(scope="module")
 def backend_compiles():
     """The wall-clock instants at which JAX's backend compiled something
-    (the event ``CompileMonitor`` counts).  JAX keeps a listener for the
-    life of the process, so this one only appends to its list: a second
-    ``CompileMonitor`` would double the ``veles.compile`` instants that
-    ``test_spans.py`` counts in the same worker."""
+    (the event the compile monitor files as ``compile.xla`` or
+    ``compile.cache_load``).  JAX keeps a listener for the life of the
+    process, so this one only appends to its list."""
     instants = []
 
     def listener(name, seconds, **_):
-        if name == CompileMonitor._DURATIONS[2]:
+        if name == compiles.BACKEND_COMPILE:
             instants.append(time.time_ns())
     jax.monitoring.register_event_duration_secs_listener(listener)
     return instants

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 import numpy
 import pytest
 
-from veles_tpu.logger import events
 from veles_tpu.parallel.ring import attention_reference
 from veles_tpu.znicz import flash_attention as fa
 
@@ -154,27 +153,3 @@ def test_outputs_and_gradients_over_the_sweep(case):
     for g, w, name in zip(got, want, "qkv"):
         numpy.testing.assert_allclose(g, w, rtol=5e-4, atol=5e-4,
                                       err_msg="d" + name)
-
-
-def test_a_traced_call_files_its_census():
-    """One ``flash.grid`` span a kernel as the call is traced, named by
-    the call, with the counts of all its key-value heads."""
-    q = jnp.zeros((2, 256, 8, 8), jnp.float32)
-    k = jnp.zeros((2, 256, 2, 8), jnp.float32)
-    seen = max((s.seq for s in events.spans()), default=-1)
-    jax.make_jaxpr(jax.grad(weighed(lambda q, k, v: fa.flash_attention(
-        q, k, v, True, None, 64, 32, 40)), argnums=(0, 1, 2)))(q, k, k)
-    spans = [s for s in events.spans()
-             if s.seq > seen and s.name == "veles.flash.grid"]
-    assert sorted(s.info["call"] for s in spans) == [
-        "gqa_window_flash_dkv", "gqa_window_flash_dq",
-        "gqa_window_flash_fwd"]
-    census = fa.block_census(256, 64, 32, 40, True, 4)
-    for s in spans:
-        kernel = s.info["call"].rsplit("_", 1)[1]
-        assert (s.info["t"], s.info["block_q"], s.info["block_k"],
-                s.info["window"], s.info["group"], s.info["kv_heads"]) == (
-                    256, 64, 32, 40, 4, 4)
-        for name, n in census[kernel].items():
-            assert s.info[name] == 4 * n, (kernel, name)
-        assert s.info["wasted"] == 0

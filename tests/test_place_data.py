@@ -26,7 +26,7 @@ from veles_tpu.backends import Device
 from veles_tpu.loader.fullbatch import FullBatchLoaderMSE
 from veles_tpu.logger import events
 from veles_tpu.memory import Array
-from veles_tpu.observability.compiles import CompileMonitor
+from veles_tpu.observability.compiles import BACKEND_COMPILE
 from veles_tpu.prng import RandomGenerator
 from veles_tpu.znicz import transformer  # noqa: F401 — registers the units
 from veles_tpu.znicz.standard_workflow import StandardWorkflow
@@ -129,12 +129,12 @@ def asking_for(monkeypatch):
 @pytest.fixture()
 def compiles():
     """[(instant, jitted function's name)] of the backend's compiles (the
-    event ``CompileMonitor`` counts), appended for the life of the
+    event the compile monitor files), appended for the life of the
     process: JAX keeps its listeners."""
     seen = []
 
     def listener(name, seconds, fun_name="?", **_):
-        if name == CompileMonitor._DURATIONS[2]:
+        if name == BACKEND_COMPILE:
             seen.append((time.time_ns(), fun_name))
     jax.monitoring.register_event_duration_secs_listener(listener)
     return seen

@@ -32,6 +32,12 @@ def named(spans, name):
     return [s for s in by_seq(spans) if s.name == SPAN_PREFIX + name]
 
 
+def compiled(span):
+    """A span the compile monitor filed (``compile.trace``, ``.lower``,
+    ``.xla``, ``.cache_load``)."""
+    return span.name.startswith(SPAN_PREFIX + "compile.")
+
+
 # -- the primitive ------------------------------------------------------------
 
 def test_nesting_gives_the_parent():
@@ -126,13 +132,19 @@ def test_span_reported_after_the_fact_feeds_ring_and_totals():
     assert legacy.start_ns + legacy.duration_ns \
         <= outer.start_ns + outer.duration_ns + 1_000_000
     assert log.totals()["veles.legacy"]["seconds"] == 0.5
+    # a site that knows when its region started, and counted inside it
+    log.span("legacy", 0.25, start_ns=123_456, counts={"nested": 4})
+    late = log.spans()[-1]
+    assert (late.start_ns, late.duration_ns) == (123_456, 250_000_000)
+    assert late.info == {"nested": 4} and late.parent is None
+    assert log.totals()["veles.legacy"]["nested"] == 4
 
 
 def test_instant_has_no_length():
     log = EventLog()
-    log.instant("compile", seconds=1.5)
+    log.instant("probe.mark", seconds=1.5)
     (span,) = log.spans()
-    assert span.name == "veles.compile" and span.duration_ns == 0
+    assert span.name == "veles.probe.mark" and span.duration_ns == 0
     assert span.info == {"seconds": 1.5}
 
 
@@ -251,19 +263,47 @@ def test_spans_are_on_the_profiler_s_clock(tmp_path):
         assert abs(int(event.duration_ns) - span.duration_ns) < 500_000
 
 
-def test_compile_monitor_leaves_an_instant_per_backend_compile():
+def test_compile_monitor_leaves_an_instant_per_backend_compile(tmp_path):
+    """Each phase of a compile is a span of the ring with its real start
+    and length, under the span open on the thread, and, while a profile
+    is being taken, an instant on its timeline."""
     import jax
     import numpy
-    from veles_tpu.observability.compiles import CompileMonitor
-    monitor = CompileMonitor()
-    before = len(named(events.spans(), "compile"))
-    jax.jit(lambda x: x * 3 + 1.25)(
-        numpy.ones(7, numpy.float32)).block_until_ready()
-    after = named(events.spans(), "compile")
-    assert len(after) == before + 1
-    assert after[-1].duration_ns == 0 and after[-1].info["seconds"] > 0
-    assert monitor.compile_seconds >= after[-1].info["seconds"]
-    assert (monitor.cache_hits, monitor.cache_misses) >= (0, 0)
+    from veles_tpu.observability import compiles
+    monitor = compiles.monitor()
+    seen = max((s.seq for s in events.spans()), default=0)
+    seconds = monitor.compile_seconds
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with events.timed("probe.compiling") as outer:
+            jax.jit(lambda x: x * 3 + 1.25)(
+                numpy.ones(7, numpy.float32)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    filed = [s for s in by_seq(events.spans())
+             if s.seq > seen and compiled(s)]
+    assert [s.name for s in filed] == ["veles.compile.trace",
+                                       "veles.compile.lower",
+                                       "veles.compile.xla"]
+    assert all(s.parent == outer.seq and s.duration_ns > 0 for s in filed)
+    assert filed[0].info["fun"] == "<lambda>"
+    assert filed[2].info["module"] == filed[1].info["module"]
+    assert filed[2].info["cache"] in ("miss", "off")
+    for before, after in zip(filed, filed[1:]):
+        assert before.start_ns + before.duration_ns <= after.start_ns
+    assert outer.start_ns <= filed[0].start_ns
+    assert filed[-1].start_ns + filed[-1].duration_ns \
+        <= outer.start_ns + outer.duration_ns
+    assert monitor.compile_seconds - seconds == pytest.approx(
+        sum(s.seconds for s in filed))
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    marks = {e.name for plane in data.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("veles.compile.")}
+    assert marks == {s.name for s in filed}
 
 
 def test_one_compile_monitor_outside_the_benchmark():
@@ -307,10 +347,9 @@ def check_step_spans(wf, children):
             == type(wf.fused_step).__name__
         assert run.work == run.info["epoch"]
         assert by_number[run.parent].work == run.work
-        # (a CompileMonitor made by an earlier test of this process adds
-        # an instant wherever something compiled)
+        # (the compile monitor files a span wherever something compiled)
         below = [s.name[len(SPAN_PREFIX):] for s in by_seq(spans)
-                 if s.parent == run.seq and s.name != "veles.compile"]
+                 if s.parent == run.seq and not compiled(s)]
         assert below == children(run), (run.info, below)
         assert all(s.work == run.work for s in spans
                    if s.parent == run.seq)
@@ -344,7 +383,7 @@ def check_class_ends(spans, runs):
         ends += 1
         sync, flush = below["step.sync_weights"], below["step.flush_metrics"]
         (read,) = [s for s in spans if s.parent == flush.seq
-                   and s.name != "veles.compile"]
+                   and not compiled(s)]
         assert read.name == "veles.step.read_metrics"
         assert below["step.dispatch"].seq < sync.seq < flush.seq < read.seq
         assert sync.start_ns + sync.duration_ns <= flush.start_ns \
@@ -395,7 +434,7 @@ def test_train_epochs_is_one_step_run():
     assert run.info["epochs"] == 3 and run.info["steps"] == 12
     assert run.info["images"] == wf.loader.samples_served == 600
     below = [s.name for s in by_seq(events.spans())
-             if s.parent == run.seq and s.name != "veles.compile"]
+             if s.parent == run.seq and not compiled(s)]
     assert below.count("veles.step.index_matrix") == 3
     assert below.count("veles.step.shuffle") == 2
     assert below[-3:] == ["veles.step.dispatch", "veles.step.sync_weights",

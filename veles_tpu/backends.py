@@ -109,7 +109,12 @@ def apply_compilation_cache_config():
     cache (veles_tpu/compilecache/) doesn't own.  Unset = untouched
     (exact default behavior).  Where ``$JAX_COMPILATION_CACHE_DIR`` is
     set JAX already keeps its cache there and nothing else is applied.
-    Returns the directory in use or None."""
+    Installs the process's compile monitor, which files what JAX traces,
+    lowers, compiles and loads from a cache as the program's spans
+    (``observability/compiles.py``).  Returns the directory in use or
+    None."""
+    from .observability import compiles
+    compiles.monitor()
     if os.environ.get(CACHE_DIR_ENV):
         return cache_root()
     directory = root.common.engine.get("compilation_cache_dir", None)

@@ -7,8 +7,12 @@ executable (``jax.experimental.serialize_executable`` — milliseconds)
 or **compiled** fresh and persisted for the next process.  Every
 outcome is observable: ``veles_compile_cache_{hits,misses,bytes,
 seconds_saved}_total`` in the process-global MetricsRegistry and
-``veles.compile.cache_hit`` / ``veles.compile.miss`` spans
-(``events.timed``: in the ring, the event file and a running profile).
+the span ``veles.compile.cache_load`` with ``cache="veles"`` for a load
+off the store (``events.timed``: in the ring, the event file and a
+running profile).  A fresh compile is filed by JAX's own events, as
+``veles.compile.xla`` or, where JAX's persistent cache served the
+module, ``veles.compile.cache_load`` with ``cache="jax"``
+(``observability/compiles.py``).
 
 Failure policy — the cache may only ever cost a recompile, never a
 crash or a wrong result: a truncated/undeserializable entry is
@@ -28,6 +32,7 @@ could hide a program the device refused.
 import logging
 import os
 import pickle
+import time
 
 from ..config import root
 from ..logger import events
@@ -91,18 +96,18 @@ class CompileCache:
         loaded = self._try_load(key, name)
         if loaded is not None:
             return loaded, True
-        with events.timed("compile.miss", fn=str(name),
-                          key=key[:16]) as span:
-            compiled = lowered.compile()
+        t0 = time.perf_counter()
+        compiled = lowered.compile()
         self._c_misses.inc()
-        self._persist(key, compiled, span.seconds, name)
+        self._persist(key, compiled, time.perf_counter() - t0, name)
         return compiled, False
 
     def _try_load(self, key, name):
         blob = self.store.get(key)
         if blob is None:
             return None
-        with events.timed("compile.cache_hit", fn=str(name), key=key[:16],
+        with events.timed("compile.cache_load", cache="veles",
+                          module=str(name), key=key[:16],
                           bytes=len(blob)) as span:
             try:
                 entry = pickle.loads(blob)

@@ -251,15 +251,20 @@ class EventLog:
         closes the span and propagates."""
         return Span(self, name, info)
 
-    def span(self, name, seconds, **info):
-        """Complete span ending now, lasting ``seconds``: for a site that
-        timed itself.  It is in the ring, the totals, the file and the
-        sink, but not on a profiler's timeline (that cannot be told
-        afterwards)."""
+    def span(self, name, seconds, start_ns=None, counts=None, **info):
+        """Complete span lasting ``seconds``, from ``start_ns`` on the
+        wall clock (``time.time_ns()``) or else ending now: for a site
+        that timed itself.  ``counts`` are summed in the totals as
+        :meth:`Span.count` sums them.  It is in the ring, the totals, the
+        file and the sink, but not on a profiler's timeline (that cannot
+        be told afterwards)."""
         done = Span(self, name, info)
         done._begin()
+        if counts:
+            done.count(**counts)
         done.duration_ns = int(seconds * 1e9)
-        done.start_ns = time.time_ns() - done.duration_ns
+        done.start_ns = time.time_ns() - done.duration_ns \
+            if start_ns is None else start_ns
         self._close(done)
 
     def instant(self, name, **info):

@@ -16,9 +16,10 @@ plus web status server, rebuilt TPU-native):
   ``trace_id`` and merge into a single Perfetto timeline
   (``tools/merge_traces.py``).
 
-- :mod:`~veles_tpu.observability.compiles` — :class:`CompileMonitor`:
-  what JAX compiled, from its own monitoring events, each backend
-  compile an instant ``veles.compile`` among the spans.
+- :mod:`~veles_tpu.observability.compiles` — :func:`compiles.monitor`,
+  the process's one :class:`CompileMonitor`: JAX's compile phases from
+  its own monitoring events, filed among the spans as
+  ``veles.compile.trace`` / ``.lower`` / ``.xla`` / ``.cache_load``.
 
 The spans themselves (``events.timed``: ring, totals, the profiler's
 timeline) live in :mod:`veles_tpu.logger`.

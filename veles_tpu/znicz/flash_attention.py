@@ -41,7 +41,6 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import backends
-from ..logger import events
 
 
 def _interpret():
@@ -370,25 +369,6 @@ def block_census(t, block_q, block_k, window=None, causal=True, group=1):
     return {"fwd": rows, "dq": dict(rows), "dkv": cols}
 
 
-def _file_census(grid, kernels, group, kv_heads):
-    """The census of a call's ``kernels``, filed as it is traced (a train
-    step pays nothing for it): one span ``flash.grid`` a kernel, named
-    by its call, with the geometry and the counts of all ``kv_heads``
-    key-value heads of the call."""
-    census = block_census(grid.t, grid.block_q, grid.block_k, grid.window,
-                          grid.causal, group)
-    geometry = dict(t=grid.t, block_q=grid.block_q, block_k=grid.block_k,
-                    causal=int(grid.causal), group=group,
-                    kv_heads=kv_heads)
-    if grid.window is not None:
-        geometry["window"] = grid.window
-    for kernel in kernels:
-        with events.timed("flash.grid", call=_call_name(kernel, grid.window),
-                          **geometry) as span:
-            span.count(**{name: n * kv_heads
-                          for name, n in census[kernel].items()})
-
-
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                 acc_scr, *, scale, grid, compact_stats=False):
     from jax.experimental import pallas as pl
@@ -481,7 +461,6 @@ def _flash_fwd_bh(q, k, v, scale, causal, block_q, block_k, vma=None,
     bh, t, d = q.shape
     group = bh // k.shape[0]
     grid = _Grid(t, block_q, block_k, window, causal)
-    _file_census(grid, ("fwd",), group, k.shape[0])
     # compact stats layout whenever each Q block covers whole 128-lane
     # rows (default 256/128 blocks do; the 32/64 fallbacks keep the
     # lane-broadcast layout) — see the _STAT_LANES note
@@ -631,7 +610,6 @@ def _flash_bwd_bh(q, k, v, out, lse, do, scale, causal, block_q,
     bh_kv = k.shape[0]
     group = bh // bh_kv
     grid = _Grid(t, block_q, block_k, window, causal)
-    _file_census(grid, ("dq", "dkv"), group, bh_kv)
     if delta is None:
         # delta_i = sum_d do*out — tiny elementwise reduce; XLA fuses it
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
