@@ -264,20 +264,51 @@ def test_latent_flash_attention_compiles_for_v5e(v5e, shared, grad):
         assert kernel in text, kernel
 
 
-@pytest.mark.parametrize("shape", [(2048, 1536), (768, 2048)],
-                         ids=["gate_up", "down"])
+#: (rows in the row buffer, held experts, K, N) of the decoder cells'
+#: grouped products: Kanana's (``gate_up``, ``down``), LFM2's, Mellum's
+_GROUPED = {"gate_up": (24576, 16, 2048, 1536), "down": (24576, 16, 768, 2048),
+            "lfm2_gate_up": (16384, 8, 2048, 3072),
+            "lfm2_down": (16384, 8, 1536, 2048),
+            "mellum_gate_up": (65536, 16, 2304, 1792),
+            "mellum_down": (65536, 16, 896, 2304)}
+
+
+def _pallas_blocks(jaxpr):
+    """(block shape, array shape) of every operand of every Pallas call
+    in ``jaxpr`` and the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            for mapping in eqn.params["grid_mapping"].block_mappings:
+                yield mapping.block_shape, mapping.array_aval.shape
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_blocks(inner)
+
+
+@pytest.mark.parametrize("shape", list(_GROUPED.values()),
+                         ids=list(_GROUPED))
 def test_grouped_matmul_compiles_for_v5e(v5e, shape):
-    """The expert layer's grouped products at the row bound of 16,384
-    tokens x 6 choices and 16 held experts, forward and gradients."""
-    k, n = shape
-    lhs = jax.ShapeDtypeStruct((16384 * 6, k), jnp.bfloat16, sharding=v5e)
-    rhs = jax.ShapeDtypeStruct((16, k, n), jnp.bfloat16, sharding=v5e)
-    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=v5e)
-    text = _compile(jax.grad(
+    """The expert layer's grouped products of the three decoder cells at
+    their row buffers, forward and gradients: each kernel's tiles fit
+    the chip's VMEM, and every tile divides its dimension, so no kernel
+    runs a padded, masked remainder tile."""
+    m, g, k, n = shape
+    lhs = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=v5e)
+    rhs = jax.ShapeDtypeStruct((g, k, n), jnp.bfloat16, sharding=v5e)
+    sizes = jax.ShapeDtypeStruct((g,), jnp.int32, sharding=v5e)
+    step = jax.value_and_grad(
         lambda a, b, s: gemm.grouped_matmul(a, b, s).astype(
-            jnp.float32).sum(), argnums=(0, 1)), lhs, rhs, sizes)
+            jnp.float32).sum(), argnums=(0, 1))
+    text = _compile(step, lhs, rhs, sizes)
     assert "gmm" in text and "tgmm" in text
-    assert text.count("tpu_custom_call") >= 2     # the two gradients
+    assert text.count("tpu_custom_call") >= 3     # forward, two gradients
+    blocks = list(_pallas_blocks(jax.make_jaxpr(step)(lhs, rhs,
+                                                       sizes).jaxpr))
+    assert len(blocks) >= 9                       # three operands a kernel
+    for block, dims in blocks:
+        for tile, dim in zip(block, dims):
+            assert dim % getattr(tile, "block_size", dim) == 0, (block, dims)
 
 
 # -- where the resident data set lies for the per-step programs ---------------

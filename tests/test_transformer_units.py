@@ -137,23 +137,35 @@ def test_adamw_by_hand(xp):
     assert not numpy.asarray(delta).any()
 
 
-@pytest.mark.parametrize("sizes", [[5, 0, 9, 2], [0, 0, 0, 0], [16, 0, 0, 0],
-                                   [3, 3, 3, 3]])
-def test_grouped_matmul_against_a_loop(sizes):
-    m, k, n = 16, 24, 40
-    lhs = jax.random.normal(jax.random.key(1), (m, k))
+@pytest.mark.parametrize("sizes, shape", [
+    pytest.param([5, 0, 9, 2], (16, 24, 40), id="sizes0"),
+    pytest.param([0, 0, 0, 0], (16, 24, 40), id="sizes1"),
+    pytest.param([16, 0, 0, 0], (16, 24, 40), id="sizes2"),
+    pytest.param([3, 3, 3, 3], (16, 24, 40), id="sizes3"),
+    # wide enough that every kernel runs several tiles of K or N
+    # (test_grouped_matmul_tiles_split_exactly), over two row tiles
+    pytest.param([300, 0, 500, 100], (1024, 1024, 2048), id="tiled"),
+    pytest.param([1024, 0, 0, 0], (1024, 1024, 2048), id="tiled-one")])
+def test_grouped_matmul_against_a_loop(sizes, shape):
+    m, k, n = shape
+    lhs = jax.random.normal(jax.random.key(1), (m, k)) * (24 / k) ** 0.5
     rhs = jax.random.normal(jax.random.key(2), (len(sizes), k, n))
     sizes = jnp.asarray(sizes, jnp.int32)
     filled = (jnp.arange(m) < sizes.sum())[:, None]
+    group = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(m),
+                             side="right").clip(0, len(sizes) - 1)
 
     def loop(lhs, rhs):
-        group = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(m),
-                                 side="right").clip(0, len(sizes) - 1)
-        return jnp.where(filled, jnp.einsum("mk,mkn->mn", lhs, rhs[group],
-                                            precision="highest"), 0)
+        return sum(jnp.where(filled & (group == g)[:, None],
+                             jnp.dot(lhs, rhs[g], precision="highest"), 0)
+                   for g in range(len(sizes)))
 
     def ours(lhs, rhs):
         return jnp.where(filled, gemm.grouped_matmul(lhs, rhs, sizes), 0)
+    if m > 512:
+        for kk, nn in ((k, n), (n, k)):
+            _, tk, tn = gemm.grouped_matmul_tiles(m, kk, nn, itemsize=4)
+            assert tk < kk or tn < nn
     assert numpy.allclose(ours(lhs, rhs), loop(lhs, rhs), atol=1e-4)
     weight = jnp.sin(jnp.arange(m * n, dtype=jnp.float32)).reshape(m, n)
     got = jax.grad(lambda a, b: (ours(a, b) * weight).sum(),
@@ -163,6 +175,44 @@ def test_grouped_matmul_against_a_loop(sizes):
     for g, w in zip(got, want):
         g = jnp.where(filled, g, 0) if g.shape == lhs.shape else g
         assert numpy.allclose(g, w, atol=1e-4)
+
+
+#: the decoder cells' grouped products: (rows in the row buffer, K, N)
+#: and the tiles (tk, tn) of the forward ``gmm`` and ``tgmm``, and of the
+#: rows' gradient, which contracts N into K
+_GROUPED = {"kanana_gate_up": ((24576, 2048, 1536), (1024, 768), (768, 1024)),
+            "kanana_down": ((24576, 768, 2048), (768, 1024), (1024, 768)),
+            "lfm2_gate_up": ((16384, 2048, 3072), (1024, 1024), (1024, 1024)),
+            "lfm2_down": ((16384, 1536, 2048), (768, 1024), (1024, 768)),
+            "mellum_gate_up": ((65536, 2304, 1792), (1152, 896), (896, 1152)),
+            "mellum_down": ((65536, 896, 2304), (896, 1152), (1152, 896))}
+
+
+@pytest.mark.parametrize("kernel", ["gmm", "gmm_transposed", "tgmm"])
+@pytest.mark.parametrize("product", list(_GROUPED))
+def test_grouped_matmul_tiles_split_exactly(product, kernel):
+    """The tiles megablox gets for each of a product's three kernels:
+    the forward ``gmm`` and ``tgmm`` (the weights' gradient) see the
+    product's (m, K, N), the rows' gradient (``gmm`` over the transposed
+    weights) sees (m, N, K).  Every tile divides its dimension, so no
+    kernel runs a masked remainder tile; it is a multiple of 128 or the
+    whole dimension; the VMEM of both kernels that could receive the
+    triple, double-buffered bfloat16 operands and output and the float32
+    accumulator, is within the budget; and they are the tiles measured
+    on the chip (PERF.md section 6)."""
+    (m, k, n), forward, transposed = _GROUPED[product]
+    if kernel == "gmm_transposed":
+        k, n = n, k
+    tm, tk, tn = gemm.grouped_matmul_tiles(m, k, n)
+    for tile, dim in ((tm, m), (tk, k), (tn, n)):
+        assert dim % tile == 0
+        assert tile % 128 == 0 or tile == dim
+    assert tm == transformer.ROW_TILE
+    gmm = 2 * 2 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+    tgmm = 2 * 2 * (tm * tk + tm * tn + tk * tn) + 4 * tk * tn
+    assert max(gmm, tgmm) <= gemm.GROUPED_VMEM_BUDGET
+    assert (tk, tn) == (transposed if kernel == "gmm_transposed"
+                        else forward)
 
 
 def a_sort(tokens=6, k=3, held=7):

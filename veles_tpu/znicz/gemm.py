@@ -332,6 +332,50 @@ def _pm_bwd(level, interpret, res, g):
 precise_matmul.defvjp(_pm_fwd, _pm_bwd)
 
 
+#: VMEM that one grouped-product kernel's tiles may take
+#: (``grouped_matmul_tiles``); the rest of the v5e's 16 MiB scoped default
+#: is Mosaic's own scratch, which some tilings estimated at 14 MiB overran
+GROUPED_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _tile_widths(x):
+    """The tiles that split a dimension of ``x`` exactly: the multiples
+    of 128 that divide it, else ``x`` whole."""
+    return [t for t in range(x - x % 128, 0, -128) if x % t == 0] or [x]
+
+
+def grouped_matmul_tiles(m, k, n, itemsize=2):
+    """megablox's ``(tm, tk, tn)`` for a kernel over ``m`` rows that
+    contracts ``k`` into ``n`` output columns.  ``tm`` is the largest of
+    512, 256, ... that divides ``m``.  ``tk`` and ``tn`` split their
+    dimensions exactly and minimise ``1 / tk + 1 / tn`` (the wider
+    ``tn`` on a tie): the rows are read once per output-column tile and
+    the float32 accumulator once per contraction tile, so each is
+    overhead in proportion to one over its tile.  Both fit
+    ``GROUPED_VMEM_BUDGET`` in ``gmm`` and in ``tgmm``, which receive
+    the same triple: two buffers of each operand and of the output,
+    ``itemsize`` bytes an element, and the accumulator of ``(tm, tn)``
+    or ``(tk, tn)``."""
+    tm = next(t for t in (512, 256, 128, 64, 32, 16, 8, 1) if m % t == 0)
+
+    def vmem(tk, tn):
+        return (2 * itemsize * (tm * tk + tk * tn + tm * tn)
+                + 4 * tn * max(tm, tk))
+    pairs = [(tk, tn) for tk in _tile_widths(k) for tn in _tile_widths(n)]
+    fits = [p for p in pairs if vmem(*p) <= GROUPED_VMEM_BUDGET] \
+        or [min(pairs, key=lambda p: vmem(*p))]
+    tk, tn = min(fits, key=lambda p: (1 / p[0] + 1 / p[1], -p[1]))
+    return tm, tk, tn
+
+
+@functools.lru_cache(maxsize=None)
+def _tiling(itemsize):
+    """``grouped_matmul_tiles`` for operands of ``itemsize`` bytes, one
+    function an itemsize: megablox calls it with each kernel's own
+    triple, and keys its compiled kernels by it."""
+    return functools.partial(grouped_matmul_tiles, itemsize=itemsize)
+
+
 def grouped_matmul(lhs, rhs, group_sizes):
     """``out[r] = lhs[r] @ rhs[g(r)]`` for rows sorted by group: ``lhs``
     [M, K], ``rhs`` [G, K, N], ``group_sizes`` [G] int32 whose sum may be
@@ -345,12 +389,17 @@ def grouped_matmul(lhs, rhs, group_sizes):
     however uneven.  Against ``lax.ragged_dot`` at the expert layer's
     shapes on the v5e they were 2-8 % faster on the 2048 x 1536 product
     and 10-25 % on the 768 x 2048 one, forward and backward (PERF.md
-    section 6, PR 28), so they ship and nothing chooses."""
+    section 6), so they ship and nothing chooses.
+
+    Each of the three kernels takes its tiles from its own shape
+    (``grouped_matmul_tiles``; the rows' gradient contracts N into K).
+    A tile that does not divide its dimension leaves a remainder tile
+    that the kernel pads, masks and still multiplies, and every
+    output-column tile reads all the rows again; one tuple for the three,
+    chosen from the forward's shape, did both at the decoders' widths:
+    Mellum2's six calls took 15.9 ms under it and 9.3 ms under the rule
+    on one v5e (PERF.md section 6)."""
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-    m, k = lhs.shape
-    n = rhs.shape[-1]
-    tm = next(t for t in (512, 256, 128, 64, 32, 16, 8, 1) if m % t == 0)
-    tn = next((t for t in (1024, 512, 256, 128) if n % t == 0), n)
     return megablox.gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype,
-                        (tm, min(k, 1024), tn),
+                        _tiling(jnp.result_type(lhs, rhs).itemsize),
                         interpret=_interpret_default())
